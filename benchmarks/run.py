@@ -66,6 +66,9 @@ def main(argv=None) -> None:
     )
     args = parser.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from .common import ARTIFACTS, calibration_us, captured_metrics, captured_plans, start_capture
 
     if args.smoke:

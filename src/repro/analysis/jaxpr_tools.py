@@ -26,6 +26,7 @@ import math
 from typing import Any, Dict, List, NamedTuple, Optional, Tuple
 
 import jax
+from jax.extend import core as jcore
 import jax.numpy as jnp
 
 __all__ = [
@@ -84,9 +85,9 @@ def _sub_jaxprs(params: Any) -> List[Any]:
     out: List[Any] = []
 
     def visit(v):
-        if isinstance(v, jax.core.ClosedJaxpr):
+        if isinstance(v, jcore.ClosedJaxpr):
             out.append(v.jaxpr)
-        elif isinstance(v, jax.core.Jaxpr):
+        elif isinstance(v, jcore.Jaxpr):
             out.append(v)
         elif isinstance(v, (tuple, list)):
             for item in v:
@@ -136,7 +137,7 @@ def _dot_general_macs(eqn) -> int:
 
 def count_ops_by_dtype(jaxpr, _scale: int = 1) -> Dict[str, int]:
     """Float element-op counts per dtype name for a (Closed)Jaxpr."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     counts: Dict[str, int] = {}
 
@@ -192,7 +193,7 @@ class Conversion(NamedTuple):
 def conversions(jaxpr) -> List[Conversion]:
     """Every float->float conversion in the trace (recursing into sub-jaxprs),
     def-use-linked one step back for double-rounding detection."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     out: List[Conversion] = []
     produced_by_convert: Dict[Any, str] = {}  # outvar -> src dtype name
@@ -216,7 +217,7 @@ def conversions(jaxpr) -> List[Conversion]:
     return out
 
 
-def make_jaxpr_of(fn, *avals) -> jax.core.ClosedJaxpr:
+def make_jaxpr_of(fn, *avals) -> jcore.ClosedJaxpr:
     """``jax.make_jaxpr`` over ShapeDtypeStructs — tracing only, no execution."""
     return jax.make_jaxpr(fn)(*avals)
 

@@ -14,7 +14,9 @@ needed) and each ``pallas_call`` equation's ``GridMapping`` is checked:
     (``2 x sum(block bytes)``) against a configurable budget
     (``REPRO_ANALYSIS_VMEM_MB``, default 16 MB/core).  Interpret-mode
     traces are exempt — the interpreter has no VMEM ceiling and its tile
-    table is deliberately huge;
+    table is deliberately huge.  Compiled, only the vector kernels run
+    (``lanczos_update``, ``mixed_dot``); the SpMV kernels are swept in
+    interpret mode, the one mode the engine runs them in;
   * **K004** — a grid-pinned accumulator output (an output block mapping
     that is constant along some grid dim, like the (1,)-block alpha of the
     fused SpMV) may only be pinned along *sequentially executed* dims.
@@ -32,6 +34,7 @@ from typing import Dict, FrozenSet, Iterable, List, Optional, Sequence, Tuple
 import numpy as np
 
 import jax
+from jax.extend import core as jcore
 import jax.numpy as jnp
 
 from ..configs import env as envcfg
@@ -84,7 +87,7 @@ def vmem_budget_bytes(override_mb: Optional[float] = None) -> int:
 
 def pallas_eqns(jaxpr) -> List:
     """Every pallas_call eqn reachable from a (Closed)Jaxpr."""
-    if isinstance(jaxpr, jax.core.ClosedJaxpr):
+    if isinstance(jaxpr, jcore.ClosedJaxpr):
         jaxpr = jaxpr.jaxpr
     out = []
     for eqn in jaxpr.eqns:
@@ -92,22 +95,20 @@ def pallas_eqns(jaxpr) -> List:
             out.append(eqn)
             continue
         for p in eqn.params.values():
-            if isinstance(p, jax.core.ClosedJaxpr):
+            if isinstance(p, jcore.ClosedJaxpr):
                 out.extend(pallas_eqns(p.jaxpr))
-            elif isinstance(p, jax.core.Jaxpr):
+            elif isinstance(p, jcore.Jaxpr):
                 out.extend(pallas_eqns(p))
             elif isinstance(p, (tuple, list)):
                 for item in p:
-                    if isinstance(item, (jax.core.ClosedJaxpr, jax.core.Jaxpr)):
+                    if isinstance(item, (jcore.ClosedJaxpr, jcore.Jaxpr)):
                         out.extend(pallas_eqns(item))
     return out
 
 
 def _eval_index_map(bm, grid_point: Sequence[int]) -> Tuple[int, ...]:
     cj = bm.index_map_jaxpr
-    out = jax.core.eval_jaxpr(
-        cj.jaxpr, cj.consts, *(np.int32(g) for g in grid_point)
-    )
+    out = jcore.jaxpr_as_fun(cj)(*(np.int32(g) for g in grid_point))
     return tuple(int(v) for v in out)
 
 
@@ -116,8 +117,9 @@ def _grid_corners(grid: Sequence[int]) -> Iterable[Tuple[int, ...]]:
 
 
 def _block_dims(bm) -> Tuple[int, ...]:
-    # Mapped (None) dims carry no block extent; treat as 1.
-    return tuple(1 if b is None else int(b) for b in bm.block_shape)
+    # Block dims are Blocked(n)/Element(n) records; squeezed (None) dims
+    # carry no block extent and count as 1.
+    return tuple(int(getattr(b, "block_size", None) or 1) for b in bm.block_shape)
 
 
 def check_pallas_eqn(
@@ -140,7 +142,7 @@ def check_pallas_eqn(
 
     vmem = 0
     for pos, bm in enumerate(gm.block_mappings):
-        arr = bm.array_shape_dtype
+        arr = bm.array_aval
         block = _block_dims(bm)
         vmem += math.prod(block) * jnp.dtype(arr.dtype).itemsize
         # K001: blocks divide the padded operand dims
@@ -244,10 +246,12 @@ def _ell_tile_universe(dtype, rows: int, width: int):
     """Every (block_r, block_w, rows_pad, width_pad, interpret) the engine
     can actually run: the static-table prior plus the autotuner's candidate
     grid, clamped by ``_fit_tile`` against the layout the conversions build
-    — exactly what ``ell_matvec`` does at runtime."""
-    from ..kernels.engine import _candidate_tiles, _fit_tile, select_tiles
+    — exactly what ``ell_matvec`` does at runtime.  The SpMV kernels run
+    only under the interpreter (``kernels.engine.spmv_runs_pallas``), so that
+    is the one execution mode the universe holds."""
+    from ..kernels.engine import _candidate_tiles, _fit_tile, select_tiles, spmv_runs_pallas
 
-    for interpret in (False, True):
+    for interpret in filter(spmv_runs_pallas, (False, True)):
         prior = select_tiles(rows, width, dtype, interpret=interpret)
         width_pad = _pad_to(width, 128)  # slot_tile in make_operator
         rows_pad = _pad_to(rows, prior.block_r)
@@ -316,7 +320,7 @@ def run(
             findings.extend(
                 check_kernel_trace(
                     lambda a, c, xx: spmv_bsr_kernel_call(
-                        a, c, xx, accum_dtype=f32, interpret=False
+                        a, c, xx, accum_dtype=f32, interpret=True
                     ),
                     (val, bcol, x), "spmv_bsr", vmem_budget=budget,
                     context=f"spmv_bsr/{dname}/bs{bs}",
@@ -345,31 +349,24 @@ def run(
                 pbase = jax.ShapeDtypeStruct((rpadp, 1), i32)
                 pdcol = jax.ShapeDtypeStruct((rpadp, wpadp), idt)
                 px = jax.ShapeDtypeStruct((rows,), f32)
-                for interp in (False, True):
-                    mode = "interp" if interp else "compiled"
-                    findings.extend(
-                        check_kernel_trace(
-                            lambda a, s, b, d, xx, _br=br, _i=interp: (
-                                spmv_ell_packed_kernel_call(
-                                    a, s, b, d, xx, block_r=_br,
-                                    accum_dtype=f32, interpret=_i,
-                                )
-                            ),
-                            (pval, pscale, pbase, pdcol, px),
-                            "spmv_ell_packed", vmem_budget=budget,
-                            context=(
-                                f"spmv_ell_packed/{pmode}/"
-                                f"{jnp.dtype(idt).name}/r{br}/{mode}"
-                            ),
-                        )
+                # SpMV kernels run only under the interpreter.
+                findings.extend(
+                    check_kernel_trace(
+                        lambda a, s, b, d, xx, _br=br: spmv_ell_packed_kernel_call(
+                            a, s, b, d, xx, block_r=_br, accum_dtype=f32, interpret=True
+                        ),
+                        (pval, pscale, pbase, pdcol, px),
+                        "spmv_ell_packed", vmem_budget=budget,
+                        context=f"spmv_ell_packed/{pmode}/{jnp.dtype(idt).name}/r{br}/interp",
                     )
+                )
 
-    # Vector kernels: lengths that exercise the block clamp and the padding
-    # wrappers (8000 is NOT a multiple of the 4096 default block — the ops.py
-    # wrappers must pad).
+    # Vector kernels — the ones the TPU path runs compiled — at lengths that
+    # exercise the block clamp and the padding wrappers, up to the paper's
+    # Wikipedia matrix (n = 3,566,907, not a multiple of any block).
     from ..kernels import ops as kops
 
-    for n in (960, 4096, 8000, 8192):
+    for n in (960, 4096, 8000, 8192, 3_566_907):
         a = jax.ShapeDtypeStruct((n,), f32)
         s = jax.ShapeDtypeStruct((), f32)
         findings.extend(

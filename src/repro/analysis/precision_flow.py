@@ -25,7 +25,7 @@ executes) and checks:
 The measured counts are also what ``REPRO_PRECISION_MEASURE=1`` surfaces as
 ``partition["spmv"]["precision"]["ops_by_dtype_measured"]``.
 
-The whole pass runs under ``jax.experimental.enable_x64`` so f64 rungs trace
+The whole pass runs under ``jax.enable_x64`` so f64 rungs trace
 as real f64 regardless of the process default, without flipping global
 state for the rest of the process.
 """
@@ -408,7 +408,7 @@ def _build_traces(
 def trace_phases(policy, engine="single", *, fused=False, n=64, m=8, k=4,
                  reorth="full", jacobi="host"):
     """Public: {phase: jaxpr} for one engine config (for tests/inspection)."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         phases, _, _, _, _, _ = _build_traces(
             POLICIES.get(policy, policy) if isinstance(policy, str) else policy,
             engine, fused=fused, n=n, m=m, k=k, reorth=reorth, jacobi=jacobi,
@@ -428,7 +428,7 @@ def measure_ops_by_dtype(
     jacobi: str = "host",
 ) -> Dict[str, int]:
     """Jaxpr-measured element ops per dtype for one traced solve."""
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         _, full, ritz_traces, step_scale, _, _ = _build_traces(
             policy, engine, fused=fused, n=n, m=m, k=k, reorth=reorth, jacobi=jacobi
         )
@@ -456,7 +456,7 @@ def check_policy(
     """
     pol = policy.effective() if not isinstance(policy, str) else POLICIES[policy]
     findings: List[Finding] = []
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         pol = (POLICIES[policy] if isinstance(policy, str) else policy).effective()
         ctx = f"{pol.name}/{engine}/{'fused' if fused else 'unfused'}"
         phases, full, ritz_traces, step_scale, n_model, nnz_exec = _build_traces(

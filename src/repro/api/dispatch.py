@@ -11,10 +11,13 @@ that decision as an explicit, testable function of the input:
   2. ``"distributed"`` — an explicit sparse matrix and >1 visible device:
      the paper's nnz-balanced multi-GPU partition (its headline mode).
   3. ``"chunked"``     — an explicit sparse matrix too large to keep
-     device-resident: the paper's out-of-core unified-memory mode.  Triggered
-     above ``CHUNKED_NNZ_THRESHOLD`` non-zeros (~25M nnz ≈ 300 MB of COO
-     triplets at f32 values) or when the estimated device working set
-     exceeds half the free host RAM (this CPU container stands in for HBM).
+     device-resident: the paper's out-of-core unified-memory mode.  Where
+     the device reports its memory (``memory_stats()["bytes_limit"]``: TPU,
+     GPU), triggered when the estimated device working set (12 B per
+     non-zero: COO triplets at f32 values) exceeds half of it.  The CPU
+     reports no such figure, so there host RAM stands in for it: above
+     ``CHUNKED_NNZ_THRESHOLD`` non-zeros (~25M nnz ≈ 300 MB) or a working
+     set over half the free host RAM.
   4. ``"single"``      — everything else: the paper's single-device pipeline.
 
 Explicit ``backend=`` requests skip the policy but are validated (the
@@ -28,7 +31,13 @@ from typing import Optional
 
 from ..configs import env as envcfg
 
-__all__ = ["BACKENDS", "CHUNKED_NNZ_THRESHOLD", "select_backend", "host_available_bytes"]
+__all__ = [
+    "BACKENDS",
+    "CHUNKED_NNZ_THRESHOLD",
+    "select_backend",
+    "host_available_bytes",
+    "device_memory_bytes",
+]
 
 BACKENDS = ("single", "distributed", "restarted", "chunked")
 
@@ -47,6 +56,15 @@ def host_available_bytes() -> Optional[int]:
         return None
 
 
+def device_memory_bytes() -> Optional[int]:
+    """Memory of the default device where its backend reports one (TPU,
+    GPU), else None (the CPU backend reports no ``memory_stats``)."""
+    import jax
+
+    stats = jax.devices()[0].memory_stats()
+    return int(stats["bytes_limit"]) if stats and "bytes_limit" in stats else None
+
+
 def select_backend(
     requested: str,
     *,
@@ -57,6 +75,7 @@ def select_backend(
     free_bytes: Optional[int] = None,
     mesh_given: bool = False,
     disk_bytes: Optional[int] = None,
+    device_bytes: Optional[int] = None,
 ) -> str:
     """Resolve ``backend="auto"`` (or validate an explicit request).
 
@@ -75,6 +94,9 @@ def select_backend(
         None for in-RAM inputs.  Under "auto", a disk matrix whose payload
         exceeds half the free host memory MUST stream: every other backend
         would materialize it.
+      device_bytes: device memory to judge residency against; defaults to
+        the live :func:`device_memory_bytes` (None on the CPU, where the
+        host-memory rule applies instead).
     """
     if requested != "auto":
         if requested not in BACKENDS:
@@ -116,6 +138,9 @@ def select_backend(
     if has_matrix and device_count > 1:
         return "distributed"
     if has_matrix:
+        device = device_bytes if device_bytes is not None else device_memory_bytes()
+        if device is not None:
+            return "chunked" if nnz * 12 > device // 2 else "single"
         if nnz >= CHUNKED_NNZ_THRESHOLD:
             return "chunked"
         free = free_bytes if free_bytes is not None else host_available_bytes()

@@ -55,7 +55,12 @@ import numpy as np
 from ..configs import env as envcfg
 from ..core.distributed import PreparedShards, prepare_sharded, solve_sharded
 from ..core.eigensolver import ritz_decompose, ritz_extract, solve_fixed
-from ..core.lanczos import LanczosResult, NumericalBreakdown, lanczos_tridiag_multi
+from ..core.lanczos import (
+    LanczosResult,
+    NumericalBreakdown,
+    lanczos_tridiag_multi,
+    resolve_update_mode,
+)
 from ..core.operators import (
     ChunkedOperator,
     DenseOperator,
@@ -63,9 +68,16 @@ from ..core.operators import (
     SparseOperator,
     make_operator,
 )
-from ..core.precision import PrecisionPolicy, auto_ladder, phase_op_counts
+from ..core.precision import PrecisionPolicy, auto_ladder, exact_matmuls, phase_op_counts
 from ..core.restarted import solve_restarted
-from ..kernels.engine import FORMATS, SpmvEngine, make_engine, tuner_probe_count
+from ..kernels.engine import (
+    FORMATS,
+    SpmvEngine,
+    make_engine,
+    phase_executors,
+    tuner_probe_count,
+)
+from ..kernels.ops import default_interpret
 from ..sparse.diskcsr import DiskCSR, is_diskcsr
 from ..sparse.formats import CSR, conversion_count
 from .coerce import CoercedInput, coerce_input, matrix_fingerprint
@@ -340,7 +352,7 @@ class _Prepared:
     ops_cache: Dict[tuple, Any] = dataclasses.field(default_factory=dict)
 
     def ops_for(self, pol: PrecisionPolicy, fused: Optional[bool] = None):
-        from ..core.lanczos import ops_for_operator, resolve_update_mode
+        from ..core.lanczos import ops_for_operator
 
         eng = getattr(self.operator, "engine", None)
         plan = getattr(eng, "iteration_plan", None)
@@ -663,6 +675,7 @@ class EigenSession:
         )
         return self.eigsh_many([q])[0]
 
+    @exact_matmuls
     def eigsh_many(self, queries, defaults: Optional[SolverConfig] = None) -> List[EigenResult]:
         """Batched execute: many ``(k, policy, tol, ...)`` queries, one matrix.
 
@@ -1194,12 +1207,20 @@ class EigenSession:
         # plus the update mode this query's policy actually allows — the
         # policy gate can demote a fused plan (compensated / phase splits).
         iter_plan = getattr(prep.engine, "iteration_plan", None)
+        effective = resolve_update_mode(q.pol, plan=iter_plan)
         if spmv.get("iteration_plan") or iter_plan is not None:
-            from ..core.lanczos import resolve_update_mode
-
             rec = dict(spmv.get("iteration_plan") or iter_plan.as_dict())
-            rec["effective"] = resolve_update_mode(q.pol, plan=iter_plan)
+            rec["effective"] = effective
             spmv["iteration_plan"] = rec
+        # Which phases ran as Mosaic kernels and which as XLA (the restarted
+        # engine's update is its own jitted jnp, never the fused kernel).
+        eng = prep.engine
+        spmv["kernels"] = phase_executors(
+            eng.format if eng is not None else "coo",
+            eng.interpret if eng is not None else default_interpret(),
+            "unfused" if q.backend == "restarted" else effective,
+            q.pol.compute,
+        )
         # Per-phase precision audit: the phase map this solve executed and a
         # model-based count of element ops per dtype (how the "this split
         # reduced f64 work" claim is verified — see precision.phase_op_counts).
@@ -1562,7 +1583,7 @@ class EigenSession:
 def _export_operator(op) -> Optional[Tuple[str, Dict[str, np.ndarray]]]:
     """(container type, host arrays) of a single-placement operator, or None
     when the operator is not persistable (matrix-free / unknown)."""
-    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
+    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHub, DeviceHybrid
 
     if isinstance(op, DenseOperator):
         return "dense", {"a": np.asarray(op.a)}
@@ -1579,6 +1600,14 @@ def _export_operator(op) -> Optional[Tuple[str, Dict[str, np.ndarray]]]:
         return "ell", {"val": np.asarray(m.val), "col": np.asarray(m.col)}
     if isinstance(m, DeviceBSR):
         return "bsr", {"val": np.asarray(m.val), "bcol": np.asarray(m.bcol)}
+    if isinstance(m, DeviceHub):
+        return "hub", {
+            "dense": np.asarray(m.dense),
+            "hubs": np.asarray(m.hubs),
+            "row": np.asarray(m.row),
+            "col": np.asarray(m.col),
+            "val": np.asarray(m.val),
+        }
     if isinstance(m, DeviceHybrid):
         return "hybrid", {
             "ell_val": np.asarray(m.ell_val),
@@ -1596,7 +1625,7 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
     converters — so the ``conversion_count()`` audit stays untouched; the
     persisted tiles ride into the engine, so no tuner probes either."""
     from ..kernels.engine import TileConfig
-    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
+    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHub, DeviceHybrid
 
     dtypes = plan.get("dtypes", {})
 
@@ -1627,7 +1656,9 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
             format=ecfg["format"],
             accum_dtype=jnp.dtype(ecfg["accum_dtype"]),
             tiles=tiles,
-            interpret=bool(ecfg["interpret"]),
+            # The execution mode belongs to this process's backend, not to
+            # the one that exported the plan.
+            interpret=default_interpret(),
             requested=ecfg.get("requested", ecfg["format"]),
             stats=None,
             tiles_from=ecfg.get("tiles_from", "override"),
@@ -1643,6 +1674,10 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
             mat = DeviceELL(arr("val"), arr("col"), n, n)
         elif ctype == "bsr":
             mat = DeviceBSR(arr("val"), arr("bcol"), n, n)
+        elif ctype == "hub":
+            mat = DeviceHub(
+                arr("dense"), arr("hubs"), arr("row"), arr("col"), arr("val"), n, n
+            )
         elif ctype == "hybrid":
             mat = DeviceHybrid(
                 arr("ell_val"),

@@ -179,12 +179,6 @@ _DECLARED: Iterable[EnvKnob] = (
         None,
         "Fault-injection spec 'kind[@iter=N][,...]' armed for the next solve (CI robustness legs).",
     ),
-    _k(
-        "REPRO_PALLAS_LOWER_CHECK",
-        "bool",
-        False,
-        "Make tests/test_lowering.py compile every Pallas entrypoint (canary CI legs).",
-    ),
     # --- Static analysis / verification ------------------------------------
     _k(
         "REPRO_PRECISION_MEASURE",

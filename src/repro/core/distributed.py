@@ -23,7 +23,8 @@ communication analysis.
 Per-shard SpMV runs through the :class:`~repro.kernels.engine.SpmvEngine`
 layer: each shard's COO slice is converted host-side to ELL, blocked-ELL,
 or the hybrid hub split (``sparse.formats.shard_to_*``) and the Lanczos hot
-loop calls the Pallas kernels (interpret mode off-TPU).  ``spmv_format=
+loop calls the Pallas kernels in interpret mode off-TPU, and XLA gathers over
+the same layouts on TPU (``kernels.engine.spmv_runs_pallas``).  ``spmv_format=
 "auto"`` picks ELL vs BSR vs hybrid from per-shard statistics — hybrid keeps
 power-law shards on the kernel path by capping the ELL width and spilling
 hub overflow to a small ``segment_sum`` tail; plain COO remains only as an
@@ -41,8 +42,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..kernels.engine import SpmvEngine, make_engine, shard_stats
-from ..sparse.formats import CSR, shard_to_blocked_ell, shard_to_ell, shard_to_hybrid
+from ..kernels.engine import SpmvEngine, make_engine, shard_stats, spmv_runs_pallas
+from ..sparse.formats import CSR, row_sums, shard_to_blocked_ell, shard_to_ell, shard_to_hybrid
 from .eigensolver import EigResult
 from .jacobi import jacobi_eigh_host, tridiag_to_dense
 from ..testing import faults as _faults
@@ -54,7 +55,7 @@ from .lanczos import (
     resolve_update_mode,
 )
 from .partition import PartitionedMatrix, nnz_balanced_splits, partition_matrix
-from .precision import PrecisionPolicy, FDF, compensated_sum
+from .precision import PrecisionPolicy, FDF, basis_matmul, compensated_sum, exact_matmuls
 
 __all__ = [
     "DISTRIBUTED_FORMATS",
@@ -71,15 +72,9 @@ __all__ = [
 # split, so it still counts).  "coo" stays available as an explicit request.
 DISTRIBUTED_FORMATS = ("ell", "bsr", "hybrid")
 
-# jax.shard_map is top-level (with check_vma) only on newer jax; fall back to
-# the jax.experimental spelling (check_rep) so the engine runs on both.
-if hasattr(jax, "shard_map"):
-    _shard_map = jax.shard_map
-    _SHARD_MAP_KW = {"check_vma": False}
-else:  # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    _SHARD_MAP_KW = {"check_rep": False}
+# check_vma off: the replication rule for pallas_call is unimplemented.
+_shard_map = jax.shard_map
+_SHARD_MAP_KW = {"check_vma": False}
 
 
 def _make_sharded_ops(
@@ -110,7 +105,7 @@ def _make_sharded_ops(
             return y.astype(cdt)
         row, col, val = mats
         prod = val.astype(sdt_spmv) * jnp.take(x_full, col).astype(sdt_spmv)
-        return jax.ops.segment_sum(prod, row, num_segments=n_pad).astype(cdt)
+        return row_sums(prod, row, n_pad).astype(cdt)
 
     def dot(a, b):
         prods = a.astype(abdt) * b.astype(abdt)
@@ -118,21 +113,26 @@ def _make_sharded_ops(
         return jax.lax.psum(local, axis).astype(cdt)  # sync point A / B
 
     def gram(vs, u):
-        local = vs.astype(rdt) @ u.astype(rdt)
+        local = basis_matmul(vs.astype(rdt), u.astype(rdt))
         return jax.lax.psum(local, axis).astype(cdt)  # sync point C
 
     def project_out(vs, u, mask):
         vs_c = vs.astype(rdt) * mask.astype(rdt)[:, None]  # ONE (m, n_pad) cast
         # u rounds through the storage dtype first — legacy gram-path policy
         # semantics (see make_local_ops.project_out).
-        local = vs_c @ u.astype(policy.storage).astype(rdt)
+        local = basis_matmul(vs_c, u.astype(policy.storage).astype(rdt))
         coeffs = jax.lax.psum(local, axis)  # sync point C
-        return (u.astype(rdt) - coeffs @ vs_c).astype(cdt)
+        return (u.astype(rdt) - basis_matmul(coeffs, vs_c)).astype(cdt)
 
     plan = getattr(engine, "iteration_plan", None) if engine is not None else None
     mode = resolve_update_mode(policy, plan=plan)
     fused_iteration = None
-    if mode == "fused_spmv" and fmt == "ell" and jnp.dtype(sdt_spmv) == jnp.dtype(cdt):
+    if (
+        mode == "fused_spmv"
+        and fmt == "ell"
+        and spmv_runs_pallas(engine.interpret)
+        and jnp.dtype(sdt_spmv) == jnp.dtype(cdt)
+    ):
         from ..kernels import ops as kops
         from ..kernels.engine import _fit_tile
         from ..kernels.lanczos_fused import spmv_ell_alpha_kernel_call
@@ -160,7 +160,8 @@ def _make_sharded_ops(
             alpha = jax.lax.psum(a_loc[0], axis).astype(cdt)
             t = w[:n_pad].astype(cdt) - beta * v_prev
             u, nrm_sq = kops.lanczos_update(
-                t, v, v, alpha, jnp.zeros((), cdt), accum_dtype=cdt
+                t, v, v, alpha, jnp.zeros((), cdt), accum_dtype=cdt,
+                interpret=engine.interpret,
             )
             if need_norm:
                 nrm_sq = jax.lax.psum(nrm_sq, axis)  # sync point B
@@ -171,8 +172,10 @@ def _make_sharded_ops(
     if fused_iteration is None and mode in ("fused", "fused_spmv"):
         from ..kernels import ops as kops
 
+        kw = {} if engine is None else {"interpret": engine.interpret}
+
         def fused_update(w, v, v_prev, alpha, beta, need_norm=True):
-            u, nrm_sq = kops.lanczos_update(w, v, v_prev, alpha, beta, accum_dtype=cdt)
+            u, nrm_sq = kops.lanczos_update(w, v, v_prev, alpha, beta, accum_dtype=cdt, **kw)
             # Only pay the collective when the caller will use the norm
             # (under reorth the loop recomputes beta post-projection, and an
             # extra psum per iteration would break the paper's sync budget).
@@ -336,6 +339,7 @@ class ShardedSolveOutput(NamedTuple):
     spmv_format: tuple = ()  # per-shard executed SpMV format
 
 
+@exact_matmuls
 def solve_sharded(
     csr: CSR,
     k: int,

@@ -22,7 +22,7 @@ import numpy as np
 from .jacobi import jacobi_eigh, jacobi_eigh_host, tridiag_to_dense
 from .lanczos import LanczosResult, check_tridiag_health, lanczos_tridiag, ops_for_operator
 from .operators import LinearOperator
-from .precision import FDF, PrecisionPolicy
+from .precision import FDF, PrecisionPolicy, basis_matmul, exact_matmuls
 
 __all__ = [
     "EigResult",
@@ -55,6 +55,7 @@ class FixedSolveOutput(NamedTuple):
     timings: dict  # seconds: lanczos / jacobi / project / total
 
 
+@exact_matmuls
 def ritz_decompose(lres: LanczosResult, policy: PrecisionPolicy, jacobi: str = "host"):
     """Phase 2: eigen-decompose the Lanczos tridiagonal.
 
@@ -92,6 +93,7 @@ def ritz_decompose(lres: LanczosResult, policy: PrecisionPolicy, jacobi: str = "
     return evals, w, np.asarray(evals_f64, dtype=np.float64), w_f64, beta_m
 
 
+@exact_matmuls
 def ritz_extract(
     lres: LanczosResult,
     evals,
@@ -110,12 +112,16 @@ def ritz_extract(
     m = int(w_f64.shape[0])
     evals_k = evals[:k]
     w_k = w[:, :k].astype(policy.phase_dtype("ritz"))
-    x = (lres.basis.astype(policy.phase_dtype("ritz")).T @ w_k).astype(policy.output)
+    # (k, m) @ (m, n), then transpose: the long axis stays minor (see
+    # restarted.ritz_rows for what the other orientation costs a TPU).
+    x = basis_matmul(w_k.T, lres.basis.astype(policy.phase_dtype("ritz")))
+    x = x.astype(policy.output).T
     # Classical Ritz residual bound: ||A x_i - theta_i x_i|| = |beta_m W[m-1,i]|.
     residuals = np.abs(beta_m * w_f64[m - 1, :k])
     return evals_k.astype(policy.output), x, residuals
 
 
+@exact_matmuls
 def solve_fixed(
     op: LinearOperator,
     k: int,

@@ -55,7 +55,7 @@ import jax.numpy as jnp
 
 from ..configs import env as envcfg
 from ..testing import faults as _faults
-from .precision import PrecisionPolicy, compensated_sum
+from .precision import PrecisionPolicy, basis_matmul, compensated_sum, exact_matmuls
 
 __all__ = [
     "LanczosResult",
@@ -240,15 +240,20 @@ def _make_fused_iteration(operator, policy: PrecisionPolicy) -> Optional[Callabl
     """Whole-iteration fused step for an ELL-backed operator, or None.
 
     Requires the operator to expose its :class:`DeviceELL` container and
-    engine (``SparseOperator`` does), and the spmv-phase accumulation dtype
-    to match the carried compute dtype — the kernel's in-pass alpha replaces
-    ``dot(v, w)``, so a phase split there would change what alpha means.
+    engine (``SparseOperator`` does), an engine that runs the SpMV kernels
+    (interpret mode: compiled TPU runs the SpMV as XLA, see
+    ``kernels.engine.spmv_runs_pallas``), and the spmv-phase accumulation
+    dtype to match the carried compute dtype — the kernel's in-pass alpha
+    replaces ``dot(v, w)``, so a phase split there would change what alpha
+    means.
     """
+    from ..kernels.engine import spmv_runs_pallas  # lazy: core sits below kernels
+
     eng = getattr(operator, "engine", None)
     mat = getattr(operator, "mat", None)
-    if eng is None or mat is None or eng.format != "ell":
+    if eng is None or mat is None or eng.format != "ell" or not spmv_runs_pallas(eng.interpret):
         return None
-    from ..kernels import ops as kops  # lazy: core sits below kernels
+    from ..kernels import ops as kops
     from ..sparse.formats import DeviceELL
 
     if not isinstance(mat, DeviceELL):
@@ -307,15 +312,15 @@ def make_local_ops(
         return _local_reduce(a.astype(abdt) * b.astype(abdt), policy, abdt).astype(cdt)
 
     def gram(vs, u):
-        return (vs.astype(rdt) @ u.astype(rdt)).astype(cdt)
+        return basis_matmul(vs.astype(rdt), u.astype(rdt)).astype(cdt)
 
     def project_out(basis, u, mask):
         basis_c = basis.astype(rdt) * mask.astype(rdt)[:, None]  # ONE (m, n) cast
         # u rounds through the storage dtype before the coefficient dot —
         # the same policy semantics the legacy gram path applied (the
         # fig4 precision ablation measures exactly this rounding).
-        coeffs = basis_c @ u.astype(policy.storage).astype(rdt)
-        return (u.astype(rdt) - coeffs @ basis_c).astype(cdt)
+        coeffs = basis_matmul(basis_c, u.astype(policy.storage).astype(rdt))
+        return (u.astype(rdt) - basis_matmul(coeffs, basis_c)).astype(cdt)
 
     mode = resolve_update_mode(policy, plan=plan, fused=fused)
     fused_iteration = None
@@ -327,8 +332,12 @@ def make_local_ops(
     if mode in ("fused", "fused_spmv") and fused_iteration is None:
         from ..kernels import ops as kops  # lazy: core sits below kernels
 
+        # The operator's engine decides interpret vs compiled, like its SpMV.
+        eng = getattr(operator, "engine", None)
+        kw = {} if eng is None else {"interpret": eng.interpret}
+
         def fused_update(w, v, v_prev, alpha, beta, need_norm=True):
-            return kops.lanczos_update(w, v, v_prev, alpha, beta, accum_dtype=cdt)
+            return kops.lanczos_update(w, v, v_prev, alpha, beta, accum_dtype=cdt, **kw)
 
     return Ops(
         matvec=matvec, dot=dot, gram=gram, project_out=project_out,
@@ -439,7 +448,7 @@ def _lanczos_loop(
                     u = ops.project_out(basis, u, mask)
                 else:
                     coeffs = ops.gram(basis, u.astype(sdt)) * mask  # (m,)
-                    u = u - coeffs @ basis.astype(cdt)
+                    u = u - basis_matmul(coeffs, basis.astype(cdt))
         # --- beta (line 6, next iteration): sync point B ---
         if nrm_sq is not None:
             beta = jnp.sqrt(jnp.maximum(nrm_sq.astype(cdt), 0.0))
@@ -555,6 +564,7 @@ def _lanczos_loop(
     )
 
 
+@exact_matmuls
 def lanczos_tridiag(
     matvec: Callable,
     v1: jax.Array,
@@ -591,6 +601,7 @@ def _lanczos_vmap(v1s, ops: Ops, num_iters: int, policy: PrecisionPolicy, reorth
     return jax.vmap(lambda v: _lanczos_loop(v, ops, num_iters, policy, reorth))(v1s)
 
 
+@exact_matmuls
 def lanczos_tridiag_multi(
     matvec: Callable,
     v1s: jax.Array,

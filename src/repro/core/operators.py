@@ -30,9 +30,11 @@ from ..sparse.formats import (
     DeviceCOO,
     DeviceELL,
     count_conversions,
+    row_sums,
     to_device_bsr,
     to_device_coo,
     to_device_ell,
+    to_device_hub,
     to_device_hybrid,
 )
 from ..testing import faults as _faults
@@ -284,7 +286,7 @@ class ChunkedOperator(LinearOperator):
         @partial(jax.jit, static_argnames=("acc",))
         def _partial_spmv(row, col, val, x, y, *, acc):
             prod = val.astype(acc) * jnp.take(x, col).astype(acc)
-            return y + jax.ops.segment_sum(prod, row, num_segments=self.n)
+            return y + row_sums(prod, row, self.n)
 
         self._partial_spmv = _partial_spmv
 
@@ -565,8 +567,7 @@ class ChunkedOperator(LinearOperator):
         if key not in cache:
             from jax.sharding import PartitionSpec as P
 
-            # lazy: avoids an import cycle (check_vma/check_rep off: the
-            # replicated-x rule for pallas_call is unimplemented upstream)
+            # lazy: avoids an import cycle
             from .distributed import _SHARD_MAP_KW, _shard_map
 
             ax = self._axis
@@ -594,6 +595,9 @@ class ChunkedOperator(LinearOperator):
         if self.mesh is None:
             return self._partial_ell(val, col, x, y, r0, eng=eng)
         yk = self._shard_fn(eng, packed=False)(val, col, x).astype(y.dtype)
+        # Gather the row-sharded partial onto the accumulator's placement: a
+        # mesh with explicit axes refuses to mix the two shardings.
+        yk = jax.device_put(yk, y.sharding)
         seg = jax.lax.dynamic_slice(y, (r0,), (yk.shape[0],))
         return jax.lax.dynamic_update_slice(y, seg + yk, (r0,))
 
@@ -601,6 +605,9 @@ class ChunkedOperator(LinearOperator):
         if self.mesh is None:
             return self._partial_ell_packed(val, scale, base, dcol, x, y, r0, eng=eng)
         yk = self._shard_fn(eng, packed=True)(val, scale, base, dcol, x).astype(y.dtype)
+        # Gather the row-sharded partial onto the accumulator's placement: a
+        # mesh with explicit axes refuses to mix the two shardings.
+        yk = jax.device_put(yk, y.sharding)
         seg = jax.lax.dynamic_slice(y, (r0,), (yk.shape[0],))
         return jax.lax.dynamic_update_slice(y, seg + yk, (r0,))
 
@@ -703,6 +710,8 @@ def make_operator(
             mat = to_device_hybrid(
                 csr, dtype=dtype, width_cap=cap, row_tile=engine.tiles.block_r
             )
+        elif engine.format == "hub":
+            mat = to_device_hub(csr, dtype=dtype)
         else:
             mat = to_device_coo(csr, dtype=dtype)
         return SparseOperator(mat, impl="engine", engine=engine)

@@ -31,6 +31,7 @@ audit surfaced in ``EigenResult.partition["spmv"]["precision"]``.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict
 
 import jax
@@ -51,6 +52,8 @@ __all__ = [
     "auto_ladder",
     "phase_op_counts",
     "assert_phase_count_parity",
+    "exact_matmuls",
+    "basis_matmul",
 ]
 
 # The four compute phases of one Lanczos-based solve, in hot-loop order:
@@ -200,6 +203,44 @@ FCF = PrecisionPolicy("FCF", jnp.float32, jnp.float32, jnp.float32, compensated=
 BCF = PrecisionPolicy("BCF", jnp.bfloat16, jnp.float32, jnp.float32, compensated=True)
 
 POLICIES = {p.name: p for p in (FFF, FDF, DDD, BFF, HFF, FCF, BCF)}
+
+
+def exact_matmuls(fn):
+    """Run ``fn`` with matmuls at the precision of their dtype.
+
+    A policy names the dtype each phase computes in.  On a TPU an f32 matmul
+    at JAX's default precision is one bf16 pass (~3 significant digits), so
+    every solve traces its basis products, projections and Ritz extractions
+    at ``"highest"``; the CPU computes f32 exactly either way.
+    """
+
+    @functools.wraps(fn)
+    def wrapper(*args, **kwargs):
+        with jax.default_matmul_precision("highest"):
+            return fn(*args, **kwargs)
+
+    return wrapper
+
+
+@jax.jit
+def basis_matmul(a: jax.Array, b: jax.Array) -> jax.Array:
+    """``a @ b`` for the Krylov-basis products (projections, Ritz vectors).
+
+    A TPU has no f64 matrix unit, and XLA emulates an f64 dot through f32
+    pieces with large temporaries (13 GB for one 32 x 3.57M basis product).
+    Written as multiply + reduce, the f64 product fuses and needs none —
+    under ``jit`` only: run eagerly, the broadcast product would be
+    materialised (8.2 GB for the FDF Ritz rows on WK).  Other dtypes keep
+    the dot.
+    """
+    if jnp.result_type(a.dtype, b.dtype) != jnp.dtype(jnp.float64):
+        return a @ b
+    a2 = a if a.ndim == 2 else a[None, :]
+    b2 = b if b.ndim == 2 else b[:, None]
+    out = jnp.sum(a2[:, :, None] * b2[None, :, :], axis=1)
+    if b.ndim == 1:
+        out = out[:, 0]
+    return out[0] if a.ndim == 1 else out
 
 
 def compensated_sum(x: jax.Array, dtype) -> jax.Array:

@@ -41,12 +41,13 @@ from .eigensolver import EigResult
 from .jacobi import jacobi_eigh_host
 from .lanczos import LanczosResult, NumericalBreakdown
 from .operators import LinearOperator
-from .precision import FDF, PrecisionPolicy
+from .precision import FDF, PrecisionPolicy, basis_matmul, exact_matmuls
 
 __all__ = [
     "RestartedSolveOutput",
     "restart_kernels",
     "ritz_project",
+    "ritz_rows",
     "solve_restarted",
     "topk_eigs_restarted",
 ]
@@ -71,21 +72,32 @@ def restart_kernels(policy: PrecisionPolicy):
 
     @jax.jit
     def orth(u, basis, nvalid_mask):
-        coeffs = (basis.astype(rdt) @ u.astype(rdt)) * nvalid_mask.astype(rdt)
-        return (u.astype(rdt) - coeffs @ basis.astype(rdt)).astype(cdt)
+        b = basis.astype(rdt)
+        coeffs = basis_matmul(b, u.astype(rdt)) * nvalid_mask.astype(rdt)
+        return (u.astype(rdt) - basis_matmul(coeffs, b)).astype(cdt)
 
     return dot, orth
 
 
-def ritz_project(basis: jax.Array, wk: jax.Array, policy: PrecisionPolicy, out_dtype=None):
-    """Ritz back-projection ``V^T @ W_k`` in the policy's ritz phase dtype.
-
-    Shared by the restart compression, the final eigenvector assembly, and
-    the precision-flow verifier's ritz-phase trace.
-    """
+def ritz_rows(basis: jax.Array, wk: jax.Array, policy: PrecisionPolicy, out_dtype=None):
+    """Ritz vectors as rows, ``W_k^T @ V`` -> (k, n), in the policy's ritz
+    phase dtype.  The long axis stays minor: a TPU lays an (n, 32) operand
+    out in 128-lane tiles, and f64 emulation multiplies that 4x padding by
+    its split into f32 pieces (an FDF restart on WK asked for 24 GB)."""
     rzdt = policy.phase_dtype("ritz")
-    x = basis.astype(rzdt).T @ wk.astype(rzdt)
+    x = basis_matmul(wk.astype(rzdt).T, basis.astype(rzdt))
     return x.astype(out_dtype if out_dtype is not None else policy.output)
+
+
+def ritz_project(basis: jax.Array, wk: jax.Array, policy: PrecisionPolicy, out_dtype=None):
+    """Ritz back-projection ``V^T @ W_k`` -> (n, k) in the policy's ritz
+    phase dtype.
+
+    Shared by the final eigenvector assembly and the precision-flow
+    verifier's ritz-phase trace (the restart compression uses
+    :func:`ritz_rows`).
+    """
+    return ritz_rows(basis, wk, policy, out_dtype).T
 
 
 class RestartedSolveOutput(NamedTuple):
@@ -101,6 +113,7 @@ class RestartedSolveOutput(NamedTuple):
     timings: dict
 
 
+@exact_matmuls
 def solve_restarted(
     op: LinearOperator,
     k: int,
@@ -194,7 +207,7 @@ def solve_restarted(
             u = u - alpha * v - beta_prev * v_prev
             if i == nkeep and nkeep > 0:
                 # arrowhead coupling to the kept Ritz block
-                u = u - jnp.asarray(s_border, cdt) @ basis[:nkeep].astype(cdt)
+                u = u - basis_matmul(jnp.asarray(s_border, cdt), basis[:nkeep].astype(cdt))
                 t_hat[i, :nkeep] = s_border
                 t_hat[:nkeep, i] = s_border
             # full re-orthogonalization (stability: see EXPERIMENTS §Reorth)
@@ -232,7 +245,7 @@ def solve_restarted(
         # --- thick restart: compress to top-k Ritz vectors + residual dir ---
         restarts += 1
         wk = jnp.asarray(w[:, :k], dtype=rzdt)
-        ritz = ritz_project(basis, wk, policy, out_dtype=rzdt).T  # (k, n)
+        ritz = ritz_rows(basis, wk, policy, out_dtype=rzdt)  # (k, n)
         new_basis = jnp.zeros((m, n), sdt)
         new_basis = new_basis.at[:k].set(ritz.astype(sdt))
         basis = new_basis

@@ -52,11 +52,22 @@ unfused; compiled Mosaic defaults to fused).  Every persisted entry carries
 a grid fingerprint (:func:`grid_fingerprint`) hashing the candidate-space
 definition, so autotuner or kernel-grid changes auto-invalidate stale
 entries instead of requiring a manual CI cache-key bump.
+
+**What runs compiled on TPU** (:func:`spmv_runs_pallas`).  The SpMV kernels
+(``spmv_ell``, ``spmv_ell_alpha``, ``spmv_ell_packed``, ``spmv_bsr``) hold
+the whole ``x`` in VMEM and gather from it: Mosaic refuses their 1-D gather,
+and the 16 MiB scoped VMEM caps a double-buffered f32 ``x`` near 2M entries.
+So compiled (TPU) execution runs the SpMV as XLA gathers over the same ELL /
+BSR / hybrid layouts, and only the vector kernels (``lanczos_update``,
+``mixed_dot``) run as Mosaic kernels.  Interpret mode still runs every
+kernel, which is how the CPU tests cover them.  ``partition["spmv"]
+["kernels"]`` reports the split per phase (:func:`phase_executors`).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import hashlib
 import json
 import os
@@ -86,12 +97,14 @@ __all__ = [
     "tuned_tiles",
     "resolve_iteration_plan",
     "table_update_mode",
+    "spmv_runs_pallas",
+    "phase_executors",
     "get_tuner",
     "tuner_probe_count",
     "make_engine",
 ]
 
-FORMATS = ("coo", "ell", "bsr", "hybrid")
+FORMATS = ("coo", "ell", "bsr", "hybrid", "hub")
 
 # ELL accepted while padded slots <= ELL_MAX_OVERHEAD * nnz.
 ELL_MAX_OVERHEAD = 3.0
@@ -103,6 +116,9 @@ HYBRID_QUANTILE = 0.95
 # ...and accept while the spilled tail stays a minority of the nnz (the
 # kernel must do the bulk of the work for the split to beat plain COO).
 HYBRID_MAX_TAIL = 0.6
+# Dense-hub split: accepted once the dense hub rows (and, by symmetry, hub
+# columns) cover at least this share of the nnz.
+HUB_MIN_SHARE = 0.5
 
 
 def _env_float(name: str, default: float) -> float:
@@ -507,7 +523,13 @@ def tuned_tiles(
     if envcfg.get_str("REPRO_SPMV_TILES"):
         return select_tiles(n_rows, width, dtype, block_size, interpret), "override"
     prior = select_tiles(n_rows, width, dtype, block_size, interpret)
-    if not tune_enabled() or format not in _TUNABLE_FORMATS or n_rows <= 0 or width <= 0:
+    if (
+        not tune_enabled()
+        or not spmv_runs_pallas(interpret)  # no kernel whose tiles could be timed
+        or format not in _TUNABLE_FORMATS
+        or n_rows <= 0
+        or width <= 0
+    ):
         return prior, "table"
     tuner = get_tuner()
     key = _tune_key(format, dtype, n_rows, width, interpret)
@@ -529,13 +551,34 @@ def tuned_tiles(
 # --------------------------- whole-iteration tuner ---------------------------
 
 
+def spmv_runs_pallas(interpret: bool) -> bool:
+    """Whether the SpMV goes through its Pallas kernels: only under the
+    interpreter.  Compiled (TPU) SpMV runs as XLA gathers over the same
+    layouts — see the module docstring for why Mosaic cannot take them."""
+    return interpret
+
+
+def phase_executors(fmt: str, interpret: bool, update: str, compute_dtype) -> dict:
+    """What runs each per-iteration phase: ``"mosaic"`` (a compiled Pallas
+    kernel), ``"pallas_interpret"`` or ``"xla"``.  ``fmt`` is the SpMV format
+    ("coo" also stands for dense and matrix-free operators; "coo" and "hub"
+    have no kernel), ``update`` the effective update mode; f64 compute keeps
+    the jnp update."""
+    pallas = "pallas_interpret" if interpret else "mosaic"
+    spmv = pallas if fmt not in ("coo", "hub") and spmv_runs_pallas(interpret) else "xla"
+    fused = update != "unfused" and jnp.dtype(compute_dtype) != jnp.dtype(jnp.float64)
+    return {"spmv": spmv, "update": pallas if fused else "xla"}
+
+
 def table_update_mode(interpret: bool) -> str:
     """Static update-mode prior when no measured plan exists.
 
     Interpret mode (CPU validation) pays ~ms of interpreter overhead per
     Pallas grid step, so the fused kernels *lose* there — the smoke baseline
     measured the fused update ~9x slower than XLA's unfused expressions.
-    Compiled Mosaic is the memory-bound regime the fusion targets.
+    Compiled Mosaic is the memory-bound regime the fusion targets; there
+    the ``lanczos_update`` kernel runs compiled (``fused_spmv`` needs the
+    SpMV kernels, which compiled mode does not run).
     """
     return "unfused" if interpret else "fused"
 
@@ -548,8 +591,12 @@ def _iter_candidates(
     ELL probes the fully-fused pass and one taller tile variant; BSR probes
     block edges (a re-conversion decision — the layout changes with the
     edge); COO/hybrid only choose fused-vs-unfused update (their SpMV is
-    identical across update modes).
+    identical across update modes).  Compiled mode runs the SpMV as XLA
+    (:func:`spmv_runs_pallas`), so there every format chooses only the
+    update.
     """
+    if not spmv_runs_pallas(interpret):
+        return tuple((mode, tiles) for mode in ("unfused", "fused"))
     if fmt == "bsr":
         return tuple(
             (mode, dataclasses.replace(tiles, block_size=bs))
@@ -584,7 +631,7 @@ def _measure_iteration(
     probe; the result is a relative ranking, not an absolute projection.
     """
     from .lanczos_fused import spmv_ell_alpha_kernel_call
-    from .lanczos_update import lanczos_update_kernel_call
+    from .ops import lanczos_update
     from .spmv_bsr import spmv_bsr_kernel_call
     from .spmv_ell import spmv_ell_kernel_call
 
@@ -602,8 +649,9 @@ def _measure_iteration(
     x = jnp.asarray(rng.standard_normal(rows), dtype=acc)
     xp = jnp.asarray(rng.standard_normal(rows), dtype=acc)
     beta = jnp.asarray(0.25, acc)
-    ublock = min(4096, rows)
     ell_data = bsr_data = w_synth = None
+    if not spmv_runs_pallas(interpret):
+        fmt = "coo"  # the SpMV is XLA either way: probe the update alone
     if fmt == "ell":
         ell_data = (
             jnp.asarray(rng.standard_normal((rows, width_b)), dtype=dtype),
@@ -619,9 +667,7 @@ def _measure_iteration(
         if mode == "unfused":
             u = w - a * x - beta * xp
             return u, jnp.sum(u * u)
-        return lanczos_update_kernel_call(
-            w, x, xp, a, beta, block=ublock, accum_dtype=acc, interpret=interpret
-        )
+        return lanczos_update(w, x, xp, a, beta, accum_dtype=acc, interpret=interpret)
 
     timings: Dict[str, float] = {}
     by_name: Dict[str, Tuple[str, TileConfig]] = {}
@@ -641,11 +687,9 @@ def _measure_iteration(
                         val, col, x, x, block_r=br, block_w=bw,
                         accum_dtype=acc, interpret=interpret,
                     )
-                    u, nrm = lanczos_update_kernel_call(
-                        w, x, xp, a, beta, block=ublock,
-                        accum_dtype=acc, interpret=interpret,
+                    return lanczos_update(
+                        w, x, xp, a[0], beta, accum_dtype=acc, interpret=interpret
                     )
-                    return u, nrm
             else:
 
                 def step(br=br, bw=bw, val=val, col=col, mode=mode):
@@ -766,6 +810,10 @@ class SpmvStats:
     hyb_tail_nnz: int = 0  # nnz spilled past the cap
     hyb_overhead: float = 0.0  # (capped ELL slots + tail) / nnz
     hyb_tail_frac: float = 0.0  # tail nnz / nnz
+    # Dense-hub split: hub rows stored dense, and the nnz share they and
+    # their (symmetric) columns cover.
+    hub_rows: int = 0
+    hub_frac: float = 0.0
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -816,6 +864,10 @@ def _stats_from_triplets(
     fill = nnz / (n_blocks * bs * bs) if n_blocks else 0.0
     cap = hybrid_width_cap(row_nnz) if hyb_width is None else int(hyb_width)
     tail = int(np.maximum(row_nnz - cap, 0).sum()) if (nnz and cap) else 0
+    from ..sparse.formats import hub_rows  # lazy, like every sparse import here
+
+    hubs = hub_rows(row_nnz, n_rows) if nnz else np.zeros(0, np.int32)
+    hub_nnz = int(row_nnz[hubs].sum())
     return SpmvStats(
         n_rows=n_rows,
         nnz=nnz,
@@ -829,6 +881,8 @@ def _stats_from_triplets(
         hyb_tail_nnz=tail,
         hyb_overhead=(cap * n_rows + tail) / max(1, nnz),
         hyb_tail_frac=tail / max(1, nnz),
+        hub_rows=int(hubs.size),
+        hub_frac=min(1.0, 2 * hub_nnz / max(1, nnz)),
     )
 
 
@@ -904,8 +958,13 @@ def choose_format(
     *,
     ell_max_overhead: Optional[float] = None,
     bsr_fill_factor: Optional[float] = None,
+    compiled: bool = False,
 ) -> str:
     """Pick a SpMV format from layout statistics (see module docstring).
+
+    ``compiled`` (a TPU, not the interpreter) admits the dense-hub split: it
+    trades element gathers — what the TPU pays most for — for dense passes
+    over the hub rows, a trade that only pays where gathers are expensive.
 
     ``stats`` is one :class:`SpmvStats` or a sequence of per-shard stats; with
     several shards the choice must hold for *every* shard (shard_map runs one
@@ -933,6 +992,8 @@ def choose_format(
     ell_ok = "ell" in allowed and all(s.ell_overhead <= ell_max for s in stats)
     if ell_ok:
         return "ell"
+    if compiled and "hub" in allowed and all(s.hub_frac >= HUB_MIN_SHARE for s in stats):
+        return "hub"
     # Hub-row split: the quantile-capped ELL part must respect the same
     # padding bound plain ELL failed (a *memory* bound: per shard), and the
     # spilled tail must stay a minority of the nnz (a *throughput* ratio:
@@ -985,9 +1046,9 @@ class SpmvEngine:
 
     Frozen and hashable so it can ride through ``jax.jit`` static arguments.
     ``interpret`` selects the Pallas interpreter (CPU containers) vs compiled
-    Mosaic (real TPU).  f64 accumulation is TPU-unsupported, so off-interpret
-    it falls back to the vectorized jnp layouts (still ELL/BSR, never
-    ``segment_sum``).
+    execution (real TPU).  Compiled, the SpMV runs as XLA gathers over the
+    same layouts (still ELL/BSR, never ``segment_sum``) — see
+    :func:`spmv_runs_pallas`.
     """
 
     format: str = "auto"
@@ -1008,9 +1069,7 @@ class SpmvEngine:
     # --- raw-array kernel dispatch (used inside shard_map / jit) -----------
 
     def _use_kernel(self) -> bool:
-        return not (
-            jnp.dtype(self.accum_dtype) == jnp.dtype(jnp.float64) and not self.interpret
-        )
+        return spmv_runs_pallas(self.interpret)
 
     def ell_matvec(self, val: jax.Array, col: jax.Array, x: jax.Array) -> jax.Array:
         """y = ELL(val, col) @ x -> (rows_padded,) in the accum dtype."""
@@ -1098,36 +1157,28 @@ class SpmvEngine:
         x: jax.Array,
         n_rows: int,
     ) -> jax.Array:
-        """Hub-split SpMV: capped-width ELL kernel + COO ``segment_sum`` tail.
+        """Hub-split SpMV: capped-width ELL kernel + COO tail (``row_sums``).
 
         ``tail_row`` indexes the output rows; padding slots (val 0, row 0)
         contribute nothing.  Returns (n_rows,) in the accum dtype.
         """
+        from ..sparse.formats import row_sums
+
         acc = jnp.dtype(self.accum_dtype)
         y = self.ell_matvec(val, col, x)[:n_rows]
         prod = tail_val.astype(acc) * jnp.take(x, tail_col).astype(acc)
-        return y + jax.ops.segment_sum(prod, tail_row, num_segments=n_rows)
+        return y + row_sums(prod, tail_row, n_rows)
 
     # --- container-level dispatch (single-device operators) ----------------
 
     def spmv(self, mat, x: jax.Array, accum_dtype=None) -> jax.Array:
-        """SpMV on a device container (DeviceCOO/ELL/BSR/Hybrid)."""
-        from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
+        """SpMV on a device container (DeviceCOO/ELL/BSR/Hybrid/Hub).
 
-        acc = accum_dtype or self.accum_dtype
-        if isinstance(mat, DeviceCOO):
-            return mat.matvec(x, accum_dtype=acc)
-        eng = self if acc == self.accum_dtype else dataclasses.replace(self, accum_dtype=acc)
-        if isinstance(mat, DeviceELL):
-            return eng.ell_matvec(mat.val, mat.col, x)[: mat.n_rows]
-        if isinstance(mat, DeviceBSR):
-            return eng.bsr_matvec(mat.val, mat.bcol, x)[: mat.n_rows]
-        if isinstance(mat, DeviceHybrid):
-            return eng.hybrid_matvec(
-                mat.ell_val, mat.ell_col, mat.tail_row, mat.tail_col, mat.tail_val,
-                x, mat.n_rows,
-            )
-        raise TypeError(f"SpmvEngine.spmv: unsupported container {type(mat).__name__}")
+        One compiled program, the container passed as an argument: run op by
+        op from a host loop (the restarted engine), the SpMV would materialise
+        every intermediate — ~3 GB of f64 temporaries per call on WK.
+        """
+        return _container_spmv(self, mat, x, jnp.dtype(accum_dtype or self.accum_dtype))
 
     def describe(self) -> dict:
         """Loggable summary (what ``EigenResult.partition`` records)."""
@@ -1144,6 +1195,25 @@ class SpmvEngine:
                 self.iteration_plan.as_dict() if self.iteration_plan is not None else None
             ),
         }
+
+
+@functools.partial(jax.jit, static_argnums=(0, 3))
+def _container_spmv(engine: SpmvEngine, mat, x: jax.Array, acc) -> jax.Array:
+    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHub, DeviceHybrid
+
+    if isinstance(mat, (DeviceCOO, DeviceHub)):
+        return mat.matvec(x, accum_dtype=acc)
+    eng = engine if acc == engine.accum_dtype else dataclasses.replace(engine, accum_dtype=acc)
+    if isinstance(mat, DeviceELL):
+        return eng.ell_matvec(mat.val, mat.col, x)[: mat.n_rows]
+    if isinstance(mat, DeviceBSR):
+        return eng.bsr_matvec(mat.val, mat.bcol, x)[: mat.n_rows]
+    if isinstance(mat, DeviceHybrid):
+        return eng.hybrid_matvec(
+            mat.ell_val, mat.ell_col, mat.tail_row, mat.tail_col, mat.tail_val,
+            x, mat.n_rows,
+        )
+    raise TypeError(f"SpmvEngine.spmv: unsupported container {type(mat).__name__}")
 
 
 def make_engine(
@@ -1178,12 +1248,14 @@ def make_engine(
     else:
         stats = tuple(stats)
 
+    interp = _default_interpret() if interpret is None else interpret
     if format == "auto":
         fmt = choose_format(
             stats,
             allowed,
             ell_max_overhead=ell_max_overhead,
             bsr_fill_factor=bsr_fill_factor,
+            compiled=not interp,
         )
     else:
         if format not in FORMATS:
@@ -1194,7 +1266,6 @@ def make_engine(
             )
         fmt = format
 
-    interp = _default_interpret() if interpret is None else interpret
     tiles_from = "override"
     n_rows = max(s.n_rows for s in stats)
     # Tiles (and autotune probes) must see the width the built layout

@@ -122,4 +122,5 @@ def spmv_ell_alpha_kernel_call(
             jax.ShapeDtypeStruct((1,), accum_dtype),
         ],
         interpret=interpret,
+        name="spmv_ell_alpha",
     )(x, v, val, col)

@@ -11,7 +11,7 @@ import jax.numpy as jnp
 
 from ..sparse.formats import DeviceELL
 from .lanczos_fused import spmv_ell_alpha_kernel_call
-from .lanczos_update import lanczos_update_kernel_call
+from .lanczos_update import LANES, lanczos_update_kernel_call
 from .mixed_dot import mixed_dot_kernel_call
 from .spmv_bsr import blocked_ell_from_csr, spmv_bsr_kernel_call
 from .spmv_ell import spmv_ell_kernel_call
@@ -28,8 +28,21 @@ __all__ = [
 ]
 
 
+# Elements per grid step of the vector kernels (lanczos_update, mixed_dot):
+# a (512, 128) f32 tile — 256 KiB per operand, so a step moves ~1 MiB.
+VECTOR_BLOCK = 1 << 16
+
+
 def default_interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def _vector_block(n: int, block: int, dtype) -> int:
+    """Vector-kernel block for an n-vector: at most ``block``, and no more
+    than n rounded up to the dtype's minimum tile — (8, 128) for 32 bits,
+    (16, 128) for 16 — (callers zero-pad n to it)."""
+    tile = (32 // jnp.dtype(dtype).itemsize) * LANES
+    return min(block, -(-n // tile) * tile)
 
 
 def spmv_ell(mat: DeviceELL, x: jax.Array, accum_dtype=None, **kw) -> jax.Array:
@@ -123,7 +136,8 @@ def mixed_dot(
     # Zero-pad up to the kernel block (padding lanes contribute nothing to
     # the sum or its compensation) — mirrors lanczos_update below.
     n = a.shape[0]
-    block = min(kw.pop("block", 4096), n)
+    narrow = min(a.dtype, b.dtype, key=lambda d: jnp.dtype(d).itemsize)
+    block = _vector_block(n, kw.pop("block", VECTOR_BLOCK), narrow)
     pad = (-n) % block
     if pad:
         a, b = jnp.pad(a, (0, pad)), jnp.pad(b, (0, pad))
@@ -146,7 +160,7 @@ def lanczos_update(w, v, v_prev, alpha, beta, accum_dtype=None, **kw):
         return lanczos_update_ref(w, v, v_prev, alpha, beta, accum_dtype=acc)
     kw.setdefault("interpret", default_interpret())
     n = w.shape[0]
-    block = min(kw.pop("block", 4096), n)
+    block = _vector_block(n, kw.pop("block", VECTOR_BLOCK), w.dtype)
     pad = (-n) % block
     if pad:
         w, v, v_prev = (jnp.pad(a, (0, pad)) for a in (w, v, v_prev))

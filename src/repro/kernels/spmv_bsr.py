@@ -69,6 +69,7 @@ def spmv_bsr_kernel_call(
         out_specs=pl.BlockSpec((1, bs), lambda i, j: (i, 0)),
         out_shape=jax.ShapeDtypeStruct((nbr, bs), accum_dtype),
         interpret=interpret,
+        name="spmv_bsr",
     )(x, val, bcol).reshape(nbr * bs)
 
 

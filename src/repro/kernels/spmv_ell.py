@@ -79,4 +79,5 @@ def spmv_ell_kernel_call(
         out_specs=pl.BlockSpec((block_r,), lambda i, j: (i,)),
         out_shape=jax.ShapeDtypeStruct((rows,), accum_dtype),
         interpret=interpret,
+        name="spmv_ell",
     )(x, val, col)

@@ -137,4 +137,5 @@ def spmv_ell_packed_kernel_call(
         out_specs=pl.BlockSpec((block_r,), lambda i: (i,)),
         out_shape=jax.ShapeDtypeStruct((rows,), accum_dtype),
         interpret=interpret,
+        name="spmv_ell_packed",
     )(x, val, scale, base, dcol)

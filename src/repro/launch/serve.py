@@ -155,6 +155,9 @@ def main(argv=None) -> int:
     _install_sigterm_handler()
     if args.kill_resume:
         return _kill_resume(args)
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     if args.smoke:
         args.n = min(args.n, 1024)
         args.matrices = min(args.matrices, 2)

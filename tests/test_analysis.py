@@ -63,7 +63,7 @@ def test_suppression_comment():
 
 
 def test_p001_red_undeclared_upcast():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(
             lambda x: (x.astype(jnp.float64) * 2.0).astype(jnp.float32)
         )(jax.ShapeDtypeStruct((8,), jnp.float32))

@@ -119,6 +119,38 @@ def test_auto_dispatch_decisions():
     assert select_backend("auto", has_matrix=False) == "single"
 
 
+WK_NNZ = 90_000_000  # the order of the paper's Wikipedia matrix (Table I) nnz
+V5E_HBM = 16 * 2**30
+
+
+@pytest.mark.parametrize(
+    "nnz, tol, device_bytes, want",
+    [
+        # in HBM many times over: stays in core although far above the
+        # host-RAM stand-in's 25M-nnz threshold
+        (WK_NNZ, None, V5E_HBM, "single"),
+        (WK_NNZ, 1e-5, V5E_HBM, "restarted"),
+        # a working set (12 B/nnz) over half the device: out of core
+        (V5E_HBM // 12, None, V5E_HBM, "chunked"),
+        (V5E_HBM // 24 - 1, None, V5E_HBM, "single"),
+    ],
+)
+def test_auto_dispatch_judges_device_memory(nnz, tol, device_bytes, want):
+    got = select_backend(
+        "auto", has_matrix=True, nnz=nnz, tol=tol, device_count=1,
+        device_bytes=device_bytes, free_bytes=1 << 40,
+    )
+    assert got == want
+
+
+def test_auto_dispatch_cpu_has_no_device_figure():
+    """The CPU backend reports no memory_stats, so host RAM stands in."""
+    from repro.api.dispatch import device_memory_bytes
+
+    assert device_memory_bytes() is None
+    assert select_backend("auto", has_matrix=True, nnz=WK_NNZ, device_count=1) == "chunked"
+
+
 def test_explicit_backend_validation():
     with pytest.raises(ValueError, match="unknown backend"):
         select_backend("warp", has_matrix=True)

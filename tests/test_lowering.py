@@ -1,98 +1,170 @@
-"""Compile-only Pallas lowering canary (``REPRO_PALLAS_LOWER_CHECK=1``).
+"""TPU compiles, without a chip: the kernels and the sweep the chip path runs.
 
-Interpret-mode tests exercise kernel *semantics*; this module instead pushes
-every Pallas entrypoint through ``jax.jit(...).lower(...).compile()`` so API
-drift in new jax releases (pallas_call signature, BlockSpec semantics, mosaic
-lowering) surfaces as a compile failure on the latest-stable canary CI legs —
-before anyone bumps the pin.  Nothing here checks numerics and nothing runs
-the kernels; off-TPU the entrypoints are lowered in their interpret
-configuration (exactly what CPU CI executes), on TPU as real mosaic kernels.
+Each test compiles for one chip of a described TPU v5e topology
+(``jax.experimental.topologies``), so Mosaic — the TPU kernel compiler —
+refuses here what it would refuse on the chip: misaligned blocks, scalar
+stores to VMEM, 64-bit index maps, more VMEM than a kernel may use.  Sizes
+are the paper's Wikipedia matrix (WK, Table I: n = 3,566,907).  The
+topology is described inside a module fixture, never at import, so every
+test worker collects the same tests and only the one that runs this file
+loads the TPU compiler.
 
-Skipped entirely unless ``REPRO_PALLAS_LOWER_CHECK=1`` — lowering each kernel
-is redundant with the semantic suite on the pinned leg and just adds wall
-time there.
+The last tests trace (no compile) a TPU-mode engine — ``interpret=False`` —
+through every format and update mode, and check that it dispatches only the
+kernels Mosaic accepts (``lanczos_update``, ``mixed_dot``): the SpMV kernels
+gather from a VMEM-resident ``x`` and run only under the interpreter.
 """
 
-import os
-
-import pytest
-
-if os.environ.get("REPRO_PALLAS_LOWER_CHECK", "").lower() not in ("1", "true", "on"):
-    pytest.skip(
-        "Pallas lowering canary disabled (set REPRO_PALLAS_LOWER_CHECK=1)",
-        allow_module_level=True,
-    )
-
 import functools
+import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
 
-from repro.kernels.lanczos_fused import spmv_ell_alpha_kernel_call
-from repro.kernels.lanczos_update import lanczos_update_kernel_call
-from repro.kernels.mixed_dot import mixed_dot_kernel_call
-from repro.kernels.ops import default_interpret
-from repro.kernels.spmv_bsr import spmv_bsr_kernel_call
-from repro.kernels.spmv_ell import spmv_ell_kernel_call
+from repro.analysis.kernel_check import pallas_eqns
+from repro.core.lanczos import _lanczos_loop, ops_for_operator
+from repro.core.operators import SparseOperator, make_operator
+from repro.core.precision import FFF
+from repro.kernels import ops as kops
+from repro.kernels.engine import FORMATS, ITER_UPDATE_MODES, make_engine
+from repro.sparse import generate
+from repro.sparse.formats import HUB_MAX_ROWS, DeviceHub, to_device_ell
 
-INTERPRET = default_interpret()
-
-
-def _compile(fn, *args, **static):
-    """Trace, lower and compile the entrypoint; the executable is discarded."""
-    jitted = jax.jit(functools.partial(fn, interpret=INTERPRET, **static))
-    jitted.lower(*args).compile()
-
-
-def test_lower_mixed_dot():
-    a = jnp.ones((4096,), jnp.float32)
-    _compile(mixed_dot_kernel_call, a, a, block=1024, accum_dtype=jnp.float32)
+WK_N = 3_566_907
+# Entries outside the hub rows and hub columns of the generated WK matrix
+# (29,079,360 nnz, 13,971,657 of them in its 32 hub rows).
+WK_REST_NNZ = 1_136_000
+# What the chip path may dispatch as a Pallas kernel.
+MOSAIC_KERNELS = {"lanczos_update", "mixed_dot"}
 
 
-def test_lower_mixed_dot_compensated():
-    a = jnp.ones((4096,), jnp.bfloat16)
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")  # else the compiler logs under /tmp
+    from jax.experimental import topologies
+
+    try:
+        return topologies.get_topology_desc(platform="tpu", topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 - any failure means "cannot describe"
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+def _compile(fn, *avals):
+    compiled = jax.jit(fn).lower(*avals).compile()
+    assert "tpu_custom_call" in compiled.as_text()  # the Mosaic kernel is in
+    return compiled
+
+
+def test_lanczos_update_compiles_at_wk_width(one_chip):
+    vec = jax.ShapeDtypeStruct((WK_N,), jnp.float32, sharding=one_chip)
+    s = jax.ShapeDtypeStruct((), jnp.float32, sharding=one_chip)
     _compile(
-        mixed_dot_kernel_call, a, a, block=1024, accum_dtype=jnp.float32, compensated=True
+        lambda w, v, vp, a, b: kops.lanczos_update(
+            w, v, vp, a, b, accum_dtype=jnp.float32, interpret=False
+        ),
+        vec, vec, vec, s, s,
     )
 
 
-def test_lower_lanczos_update():
-    w = jnp.ones((4096,), jnp.float32)
-    a = jnp.float32(0.25)
-    _compile(lanczos_update_kernel_call, w, w, w, a, a, block=1024)
-
-
-def test_lower_spmv_ell():
-    val = jnp.ones((64, 128), jnp.float32)
-    col = jnp.zeros((64, 128), jnp.int32)
-    x = jnp.ones((64,), jnp.float32)
-    _compile(spmv_ell_kernel_call, val, col, x, block_r=8, block_w=128)
-
-
-def test_lower_spmv_bsr():
-    bs, nbr, slots = 8, 4, 2
-    val = jnp.ones((nbr, slots, bs, bs), jnp.float32)
-    bcol = jnp.zeros((nbr, slots), jnp.int32)
-    x = jnp.ones((nbr * bs,), jnp.float32)
-    _compile(spmv_bsr_kernel_call, val, bcol, x, accum_dtype=jnp.float32)
-
-
-def test_lower_spmv_ell_alpha():
-    val = jnp.ones((64, 128), jnp.float32)
-    col = jnp.zeros((64, 128), jnp.int32)
-    x = jnp.ones((64,), jnp.float32)
-    v = jnp.ones((64,), jnp.float32)
-    _compile(spmv_ell_alpha_kernel_call, val, col, x, v, block_r=8, block_w=128)
-
-
-def test_lowered_text_mentions_every_kernel():
-    """The lowered module is a real artifact, not a folded constant: its
-    StableHLO must still contain computation (sanity guard against jit
-    constant-folding the whole call away)."""
-    a = jnp.asarray(np.arange(2048, dtype=np.float32))
-    jitted = jax.jit(
-        functools.partial(mixed_dot_kernel_call, block=1024, interpret=INTERPRET)
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+@pytest.mark.parametrize("compensated", [False, True])
+def test_mixed_dot_compiles_at_wk_width(one_chip, dtype, compensated):
+    vec = jax.ShapeDtypeStruct((WK_N,), dtype, sharding=one_chip)
+    _compile(
+        lambda a, b: kops.mixed_dot(
+            a, b, accum_dtype=jnp.float32, compensated=compensated, interpret=False
+        ),
+        vec, vec,
     )
-    text = jitted.lower(a, a).as_text()
-    assert "func" in text and len(text) > 100
+
+
+def test_lanczos_sweep_compiles_at_wk_width(one_chip):
+    """One jitted single-engine Lanczos sweep over the dense-hub layout the
+    chip path builds for WK, with the fused Mosaic update (FFF)."""
+    engine = make_engine(
+        generate("web", 4096, 12.6, seed=0), "hub", interpret=False
+    )
+    assert engine.iteration_plan.update == "fused"
+    pol = FFF.effective()
+
+    def sweep(dense, hubs, row, col, val, v1):
+        mat = DeviceHub(dense, hubs, row, col, val, WK_N, WK_N)
+        op = SparseOperator(mat, impl="engine", engine=engine)
+        return _lanczos_loop(v1, ops_for_operator(op, pol), 16, pol, "half")
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    compiled = _compile(
+        sweep,
+        sds((HUB_MAX_ROWS, WK_N), jnp.float32),
+        sds((HUB_MAX_ROWS,), jnp.int32),
+        sds((WK_REST_NNZ,), jnp.int32),
+        sds((WK_REST_NNZ,), jnp.int32),
+        sds((WK_REST_NNZ,), jnp.float32),
+        sds((WK_N,), jnp.float32),
+    )
+    mem = compiled.memory_analysis()
+    if mem is not None:  # the basis (16 x n f32) and the hub block fit one chip
+        assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+# ------------------------------------------- what a TPU-mode engine dispatches
+
+
+def _kernels_in(fn, *args):
+    """(name, interpret) of every pallas_call in the trace of ``fn``."""
+    return {
+        (e.params["name"], bool(e.params["interpret"]))
+        for e in pallas_eqns(jax.make_jaxpr(fn)(*args))
+    }
+
+
+@pytest.mark.parametrize("update", ITER_UPDATE_MODES)
+@pytest.mark.parametrize("fmt", FORMATS)
+def test_tpu_engine_dispatches_only_mosaic_kernels(fmt, update, monkeypatch):
+    monkeypatch.setenv("REPRO_ITER_UPDATE", update)  # pin, even fused_spmv
+    csr = generate("web", 512, 6.0, seed=3, values="normalized")
+    engine = make_engine(csr, fmt, interpret=False)
+    op = make_operator(csr, engine=engine)
+    ops = ops_for_operator(op, FFF.effective())
+    v1 = jnp.ones((csr.n,), jnp.float32)
+    found = _kernels_in(lambda v: _lanczos_loop(v, ops, 8, FFF.effective(), "half").alpha, v1)
+    assert {name for name, _ in found} <= MOSAIC_KERNELS, found
+    assert not any(interp for _, interp in found), found
+    if update != "unfused":  # the compiled update kernel is really used
+        assert ("lanczos_update", False) in found
+
+
+def test_tpu_engine_spmv_layouts_run_as_xla():
+    """Every raw-array SpMV entry of a TPU-mode engine traces to no kernel,
+    while the same engine in interpret mode does reach its kernel."""
+    csr = generate("web", 512, 6.0, seed=3, values="normalized")
+    ell = to_device_ell(csr, row_tile=32)
+    x = jnp.ones((csr.n,), jnp.float32)
+    tpu = make_engine(csr, "ell", interpret=False)
+    cpu = make_engine(csr, "ell", interpret=True)
+    assert _kernels_in(lambda v: tpu.ell_matvec(ell.val, ell.col, v), x) == set()
+    assert _kernels_in(lambda v: cpu.ell_matvec(ell.val, ell.col, v), x) == {("spmv_ell", True)}
+    rows, width = ell.val.shape
+    scale = jnp.ones((rows, 1), jnp.float32)
+    base = jnp.zeros((rows, 1), jnp.int32)
+    dcol = jnp.zeros((rows, width), jnp.int16)
+    packed = functools.partial(tpu.packed_ell_matvec, ell.val.astype(jnp.bfloat16), scale, base, dcol)
+    assert _kernels_in(packed, x) == set()
+    bsr = make_engine(csr, "bsr", interpret=False)
+    bmat = make_operator(csr, engine=bsr).mat
+    assert _kernels_in(lambda v: bsr.bsr_matvec(bmat.val, bmat.bcol, v), x) == set()
+    np.testing.assert_allclose(
+        np.asarray(tpu.ell_matvec(ell.val, ell.col, x))[: csr.n],
+        csr.to_scipy() @ np.ones(csr.n),
+        rtol=1e-5,
+    )

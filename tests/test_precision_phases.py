@@ -385,3 +385,22 @@ def test_compensated_sum_vs_fsum_hypothesis():
         check_compensated_vs_fsum(vals)
 
     prop()
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.float64])
+@pytest.mark.parametrize("a_shape, b_shape", [((5, 40), (40,)), ((5,), (5, 40)), ((3, 5), (5, 40))])
+def test_basis_matmul_matches_dot(dtype, a_shape, b_shape):
+    """The Krylov-basis product: f64 goes through multiply + reduce (a TPU's
+    f64 dot needs huge temporaries), every shape and dtype matches ``a @ b``."""
+    from repro.core.precision import basis_matmul
+
+    if dtype == jnp.float64 and not x64_enabled():
+        pytest.skip("f64 needs x64")
+    rng = np.random.default_rng(7)
+    a = jnp.asarray(rng.standard_normal(a_shape), dtype)
+    b = jnp.asarray(rng.standard_normal(b_shape), dtype)
+    got = basis_matmul(a, b)
+    assert got.shape == (a @ b).shape and got.dtype == jnp.dtype(dtype)
+    ref = np.asarray(a, np.float64) @ np.asarray(b, np.float64)
+    tol = 1e-12 if dtype == jnp.float64 else 1e-5
+    np.testing.assert_allclose(np.asarray(got, np.float64), ref, rtol=tol, atol=tol)
