@@ -13,11 +13,13 @@ that decision as an explicit, testable function of the input:
   3. ``"chunked"``     — an explicit sparse matrix too large to keep
      device-resident: the paper's out-of-core unified-memory mode.  Where
      the device reports its memory (``memory_stats()["bytes_limit"]``: TPU,
-     GPU), triggered when the estimated device working set (12 B per
-     non-zero: COO triplets at f32 values) exceeds half of it.  The CPU
-     reports no such figure, so there host RAM stands in for it: above
+     GPU), triggered when the in-core working set exceeds half of it: the
+     SpMV layout the solve would build, padding included, plus its Krylov
+     basis (:func:`device_working_set`).  The other half is headroom for
+     the SpMV's temporaries and the compiled programs.  The CPU reports no
+     such figure, so there host RAM stands in for it: above
      ``CHUNKED_NNZ_THRESHOLD`` non-zeros (~25M nnz ≈ 300 MB) or a working
-     set over half the free host RAM.
+     set (12 B per non-zero) over half the free host RAM.
   4. ``"single"``      — everything else: the paper's single-device pipeline.
 
 Explicit ``backend=`` requests skip the policy but are validated (the
@@ -27,7 +29,9 @@ distributed and chunked paths need an explicit sparse matrix).
 from __future__ import annotations
 
 import os
-from typing import Optional
+from typing import Callable, Optional
+
+import numpy as np
 
 from ..configs import env as envcfg
 
@@ -37,6 +41,7 @@ __all__ = [
     "select_backend",
     "host_available_bytes",
     "device_memory_bytes",
+    "device_working_set",
 ]
 
 BACKENDS = ("single", "distributed", "restarted", "chunked")
@@ -65,6 +70,14 @@ def device_memory_bytes() -> Optional[int]:
     return int(stats["bytes_limit"]) if stats and "bytes_limit" in stats else None
 
 
+def device_working_set(stats, fmt: str, storage_dtype, basis_vectors: int) -> int:
+    """Device bytes an in-core solve holds: the SpMV layout ``fmt`` at its
+    padded size (``SpmvStats.layout_bytes``) and the ``(basis_vectors, n)``
+    Krylov basis, both in the storage dtype."""
+    vb = np.dtype(storage_dtype).itemsize
+    return stats.layout_bytes(fmt, vb) + int(basis_vectors) * stats.n_rows * vb
+
+
 def select_backend(
     requested: str,
     *,
@@ -76,6 +89,7 @@ def select_backend(
     mesh_given: bool = False,
     disk_bytes: Optional[int] = None,
     device_bytes: Optional[int] = None,
+    working_set: Optional[Callable[[], int]] = None,
 ) -> str:
     """Resolve ``backend="auto"`` (or validate an explicit request).
 
@@ -97,6 +111,9 @@ def select_backend(
       device_bytes: device memory to judge residency against; defaults to
         the live :func:`device_memory_bytes` (None on the CPU, where the
         host-memory rule applies instead).
+      working_set: returns the in-core solve's device bytes
+        (:func:`device_working_set`); called only where the device reports
+        its memory.  Defaults to 12 B per non-zero (COO at f32 values).
     """
     if requested != "auto":
         if requested not in BACKENDS:
@@ -140,7 +157,8 @@ def select_backend(
     if has_matrix:
         device = device_bytes if device_bytes is not None else device_memory_bytes()
         if device is not None:
-            return "chunked" if nnz * 12 > device // 2 else "single"
+            need = working_set() if working_set is not None else nnz * 12
+            return "chunked" if need > device // 2 else "single"
         if nnz >= CHUNKED_NNZ_THRESHOLD:
             return "chunked"
         free = free_bytes if free_bytes is not None else host_available_bytes()

@@ -73,7 +73,9 @@ from ..core.restarted import solve_restarted
 from ..kernels.engine import (
     FORMATS,
     SpmvEngine,
+    choose_format,
     make_engine,
+    matrix_stats,
     phase_executors,
     tuner_probe_count,
 )
@@ -81,7 +83,7 @@ from ..kernels.ops import default_interpret
 from ..sparse.diskcsr import DiskCSR, is_diskcsr
 from ..sparse.formats import CSR, conversion_count
 from .coerce import CoercedInput, coerce_input, matrix_fingerprint
-from .dispatch import select_backend
+from .dispatch import device_working_set, select_backend
 from .frontend import (
     SolverConfig,
     _default_tol,
@@ -435,6 +437,7 @@ class EigenSession:
         self.fingerprint = _session_key(ci.fingerprint, cfg, mesh) if ci.fingerprint else None
         self._prepared: Dict[Tuple[str, str], _Prepared] = {}
         self._verify_a = None  # lazy f64 matrix for the auto ladder's verification
+        self._layout_stats = None  # lazy SpmvStats of the CSR (device residency)
         self._build_lock = threading.Lock()
         self._query_lock = threading.RLock()  # queries serialize per session
         self.stats = {"queries": 0, "sweeps": 0, "cache_hits": 0, "recoveries": 0}
@@ -453,7 +456,7 @@ class EigenSession:
         frontend's one-call path lets the first query build, so that call's
         counters honestly report what it paid.)"""
         pol0 = _plan_policy(self.cfg.policy).effective()
-        backend0 = self._resolve_backend(self.cfg.tol)
+        backend0 = self._resolve_backend(self.cfg.tol, pol0, self.cfg.num_iters or 0)
         prep, built = self._ensure(backend0, pol0)
         if built:
             self.prepare_s += prep.build_s
@@ -513,7 +516,9 @@ class EigenSession:
 
     # ------------------------------------------------------------ planning
 
-    def _resolve_backend(self, tol: Optional[float]) -> str:
+    def _resolve_backend(
+        self, tol: Optional[float], pol: PrecisionPolicy, steps: int
+    ) -> str:
         return select_backend(
             self.cfg.backend,
             has_matrix=self.csr is not None,
@@ -524,7 +529,25 @@ class EigenSession:
             disk_bytes=(
                 self.csr.nbytes_on_disk() if isinstance(self.csr, DiskCSR) else None
             ),
+            working_set=lambda: self._working_set(pol, steps),
         )
+
+    def _matrix_stats(self):
+        """Layout statistics of the CSR, computed once and shared with the
+        single-device engine build (the block census only where auto
+        selection may pick BSR, as in ``make_engine``)."""
+        if self._layout_stats is None:
+            self._layout_stats = matrix_stats(
+                self.csr, with_blocks=self.cfg.format == "auto"
+            )
+        return self._layout_stats
+
+    def _working_set(self, pol: PrecisionPolicy, steps: int) -> int:
+        """Device bytes of an in-core solve of ``steps`` Lanczos steps: the
+        layout the single-device engine would build, and the basis."""
+        stats = self._matrix_stats()
+        fmt = choose_format(stats) if self.cfg.format == "auto" else self.cfg.format
+        return device_working_set(stats, fmt, pol.storage, steps)
 
     def _mesh_for_solve(self):
         from jax.sharding import Mesh
@@ -573,6 +596,7 @@ class EigenSession:
         engine = make_engine(
             self.csr,
             self.cfg.format,
+            stats=self._layout_stats,
             accum_dtype=pol.phase_dtype("spmv"),
             storage_dtype=pol.storage,
         )
@@ -901,9 +925,9 @@ class EigenSession:
             raise ValueError(f"k={k} exceeds the operator dimension n={self.n}")
         pol = resolve_policy(q.policy if q.policy is not None else cfg.policy).effective()
         tol_req = pick(q.tol, cfg.tol)
-        backend = self._resolve_backend(tol_req)
-        reorth_raw = pick(q.reorth, cfg.reorth)
         num_iters = pick(q.num_iters, cfg.num_iters)
+        backend = self._resolve_backend(tol_req, pol, num_iters if num_iters is not None else k)
+        reorth_raw = pick(q.reorth, cfg.reorth)
         if backend == "restarted":
             if reorth_raw not in (None, "full"):
                 warnings.warn(
@@ -1583,7 +1607,7 @@ class EigenSession:
 def _export_operator(op) -> Optional[Tuple[str, Dict[str, np.ndarray]]]:
     """(container type, host arrays) of a single-placement operator, or None
     when the operator is not persistable (matrix-free / unknown)."""
-    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHub, DeviceHybrid
+    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
 
     if isinstance(op, DenseOperator):
         return "dense", {"a": np.asarray(op.a)}
@@ -1600,14 +1624,6 @@ def _export_operator(op) -> Optional[Tuple[str, Dict[str, np.ndarray]]]:
         return "ell", {"val": np.asarray(m.val), "col": np.asarray(m.col)}
     if isinstance(m, DeviceBSR):
         return "bsr", {"val": np.asarray(m.val), "bcol": np.asarray(m.bcol)}
-    if isinstance(m, DeviceHub):
-        return "hub", {
-            "dense": np.asarray(m.dense),
-            "hubs": np.asarray(m.hubs),
-            "row": np.asarray(m.row),
-            "col": np.asarray(m.col),
-            "val": np.asarray(m.val),
-        }
     if isinstance(m, DeviceHybrid):
         return "hybrid", {
             "ell_val": np.asarray(m.ell_val),
@@ -1625,7 +1641,7 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
     converters — so the ``conversion_count()`` audit stays untouched; the
     persisted tiles ride into the engine, so no tuner probes either."""
     from ..kernels.engine import TileConfig
-    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHub, DeviceHybrid
+    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
 
     dtypes = plan.get("dtypes", {})
 
@@ -1674,10 +1690,6 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
             mat = DeviceELL(arr("val"), arr("col"), n, n)
         elif ctype == "bsr":
             mat = DeviceBSR(arr("val"), arr("bcol"), n, n)
-        elif ctype == "hub":
-            mat = DeviceHub(
-                arr("dense"), arr("hubs"), arr("row"), arr("col"), arr("val"), n, n
-            )
         elif ctype == "hybrid":
             mat = DeviceHybrid(
                 arr("ell_val"),

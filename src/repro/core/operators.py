@@ -34,7 +34,6 @@ from ..sparse.formats import (
     to_device_bsr,
     to_device_coo,
     to_device_ell,
-    to_device_hub,
     to_device_hybrid,
 )
 from ..testing import faults as _faults
@@ -710,8 +709,6 @@ def make_operator(
             mat = to_device_hybrid(
                 csr, dtype=dtype, width_cap=cap, row_tile=engine.tiles.block_r
             )
-        elif engine.format == "hub":
-            mat = to_device_hub(csr, dtype=dtype)
         else:
             mat = to_device_coo(csr, dtype=dtype)
         return SparseOperator(mat, impl="engine", engine=engine)

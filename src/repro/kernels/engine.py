@@ -104,7 +104,7 @@ __all__ = [
     "make_engine",
 ]
 
-FORMATS = ("coo", "ell", "bsr", "hybrid", "hub")
+FORMATS = ("coo", "ell", "bsr", "hybrid")
 
 # ELL accepted while padded slots <= ELL_MAX_OVERHEAD * nnz.
 ELL_MAX_OVERHEAD = 3.0
@@ -116,9 +116,6 @@ HYBRID_QUANTILE = 0.95
 # ...and accept while the spilled tail stays a minority of the nnz (the
 # kernel must do the bulk of the work for the split to beat plain COO).
 HYBRID_MAX_TAIL = 0.6
-# Dense-hub split: accepted once the dense hub rows (and, by symmetry, hub
-# columns) cover at least this share of the nnz.
-HUB_MIN_SHARE = 0.5
 
 
 def _env_float(name: str, default: float) -> float:
@@ -561,11 +558,11 @@ def spmv_runs_pallas(interpret: bool) -> bool:
 def phase_executors(fmt: str, interpret: bool, update: str, compute_dtype) -> dict:
     """What runs each per-iteration phase: ``"mosaic"`` (a compiled Pallas
     kernel), ``"pallas_interpret"`` or ``"xla"``.  ``fmt`` is the SpMV format
-    ("coo" also stands for dense and matrix-free operators; "coo" and "hub"
-    have no kernel), ``update`` the effective update mode; f64 compute keeps
+    ("coo" also stands for dense and matrix-free operators, and has no
+    kernel), ``update`` the effective update mode; f64 compute keeps
     the jnp update."""
     pallas = "pallas_interpret" if interpret else "mosaic"
-    spmv = pallas if fmt not in ("coo", "hub") and spmv_runs_pallas(interpret) else "xla"
+    spmv = pallas if fmt != "coo" and spmv_runs_pallas(interpret) else "xla"
     fused = update != "unfused" and jnp.dtype(compute_dtype) != jnp.dtype(jnp.float64)
     return {"spmv": spmv, "update": pallas if fused else "xla"}
 
@@ -810,13 +807,30 @@ class SpmvStats:
     hyb_tail_nnz: int = 0  # nnz spilled past the cap
     hyb_overhead: float = 0.0  # (capped ELL slots + tail) / nnz
     hyb_tail_frac: float = 0.0  # tail nnz / nnz
-    # Dense-hub split: hub rows stored dense, and the nnz share they and
-    # their (symmetric) columns cover.
-    hub_rows: int = 0
-    hub_frac: float = 0.0
+    block_row_max: int = 0  # touched blocks in the fullest block-row
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
+
+    def layout_bytes(self, fmt: str, value_bytes: int) -> int:
+        """Device bytes of the layout ``fmt`` builds, padding included: a
+        value and an int32 column per slot, plus an int32 row per COO
+        triplet.  ELL pads every row to the longest (128-lane aligned),
+        hybrid to its capped width (8-slot aligned) plus the tail (counted at
+        the unaligned cap: an upper bound), BSR every block-row to the
+        fullest one."""
+        vb = int(value_bytes)
+        if fmt == "ell":
+            width = -(-max(1, self.max_row_nnz) // 128) * 128
+            return width * self.n_rows * (vb + 4)
+        if fmt == "hybrid":
+            width = -(-max(1, self.hyb_width) // 8) * 8
+            return width * self.n_rows * (vb + 4) + self.hyb_tail_nnz * (vb + 8)
+        if fmt == "bsr":
+            bs = self.block_size
+            slots = -(-self.n_rows // bs) * self.block_row_max
+            return slots * (bs * bs * vb + 4)
+        return self.nnz * (vb + 8)
 
 
 def hybrid_quantile() -> float:
@@ -855,19 +869,16 @@ def _stats_from_triplets(
     bs = block_size
     if nnz and rows is not None:
         nbc = -(-int(cols.max() + 1) // bs)
-        keys = (rows // bs).astype(np.int64) * nbc + cols // bs
-        n_blocks = int(np.unique(keys).size)
+        keys = np.unique((rows // bs).astype(np.int64) * nbc + cols // bs)
+        n_blocks = int(keys.size)
+        block_row_max = int(np.bincount(keys // nbc).max())
     else:
-        n_blocks = 0
+        n_blocks = block_row_max = 0
     # No census (skipped or empty matrix) must read as "no block structure",
     # never as infinite fill — otherwise auto-selection would pick BSR.
     fill = nnz / (n_blocks * bs * bs) if n_blocks else 0.0
     cap = hybrid_width_cap(row_nnz) if hyb_width is None else int(hyb_width)
     tail = int(np.maximum(row_nnz - cap, 0).sum()) if (nnz and cap) else 0
-    from ..sparse.formats import hub_rows  # lazy, like every sparse import here
-
-    hubs = hub_rows(row_nnz, n_rows) if nnz else np.zeros(0, np.int32)
-    hub_nnz = int(row_nnz[hubs].sum())
     return SpmvStats(
         n_rows=n_rows,
         nnz=nnz,
@@ -881,8 +892,7 @@ def _stats_from_triplets(
         hyb_tail_nnz=tail,
         hyb_overhead=(cap * n_rows + tail) / max(1, nnz),
         hyb_tail_frac=tail / max(1, nnz),
-        hub_rows=int(hubs.size),
-        hub_frac=min(1.0, 2 * hub_nnz / max(1, nnz)),
+        block_row_max=block_row_max,
     )
 
 
@@ -958,13 +968,8 @@ def choose_format(
     *,
     ell_max_overhead: Optional[float] = None,
     bsr_fill_factor: Optional[float] = None,
-    compiled: bool = False,
 ) -> str:
     """Pick a SpMV format from layout statistics (see module docstring).
-
-    ``compiled`` (a TPU, not the interpreter) admits the dense-hub split: it
-    trades element gathers — what the TPU pays most for — for dense passes
-    over the hub rows, a trade that only pays where gathers are expensive.
 
     ``stats`` is one :class:`SpmvStats` or a sequence of per-shard stats; with
     several shards the choice must hold for *every* shard (shard_map runs one
@@ -992,8 +997,6 @@ def choose_format(
     ell_ok = "ell" in allowed and all(s.ell_overhead <= ell_max for s in stats)
     if ell_ok:
         return "ell"
-    if compiled and "hub" in allowed and all(s.hub_frac >= HUB_MIN_SHARE for s in stats):
-        return "hub"
     # Hub-row split: the quantile-capped ELL part must respect the same
     # padding bound plain ELL failed (a *memory* bound: per shard), and the
     # spilled tail must stay a minority of the nnz (a *throughput* ratio:
@@ -1172,7 +1175,7 @@ class SpmvEngine:
     # --- container-level dispatch (single-device operators) ----------------
 
     def spmv(self, mat, x: jax.Array, accum_dtype=None) -> jax.Array:
-        """SpMV on a device container (DeviceCOO/ELL/BSR/Hybrid/Hub).
+        """SpMV on a device container (DeviceCOO/ELL/BSR/Hybrid).
 
         One compiled program, the container passed as an argument: run op by
         op from a host loop (the restarted engine), the SpMV would materialise
@@ -1199,9 +1202,9 @@ class SpmvEngine:
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def _container_spmv(engine: SpmvEngine, mat, x: jax.Array, acc) -> jax.Array:
-    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHub, DeviceHybrid
+    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
 
-    if isinstance(mat, (DeviceCOO, DeviceHub)):
+    if isinstance(mat, DeviceCOO):
         return mat.matvec(x, accum_dtype=acc)
     eng = engine if acc == engine.accum_dtype else dataclasses.replace(engine, accum_dtype=acc)
     if isinstance(mat, DeviceELL):
@@ -1248,14 +1251,12 @@ def make_engine(
     else:
         stats = tuple(stats)
 
-    interp = _default_interpret() if interpret is None else interpret
     if format == "auto":
         fmt = choose_format(
             stats,
             allowed,
             ell_max_overhead=ell_max_overhead,
             bsr_fill_factor=bsr_fill_factor,
-            compiled=not interp,
         )
     else:
         if format not in FORMATS:
@@ -1266,6 +1267,7 @@ def make_engine(
             )
         fmt = format
 
+    interp = _default_interpret() if interpret is None else interpret
     tiles_from = "override"
     n_rows = max(s.n_rows for s in stats)
     # Tiles (and autotune probes) must see the width the built layout

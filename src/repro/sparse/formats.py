@@ -11,9 +11,6 @@ Host-side construction is NumPy (CSR); device-side compute formats are:
 * ``DeviceHybrid`` — hub-row split: ELL capped at a quantile of the row
   lengths (Pallas kernel part) plus a COO overflow tail (``segment_sum``),
   so power-law matrices reach the kernel path without padding blowup.
-* ``DeviceHub`` — dense-hub split: the few hub rows of a symmetric
-  power-law matrix dense (serving hub rows and, by symmetry, hub columns),
-  the rest COO — far fewer element gathers, which the TPU pays dearly for.
 
 All device containers are registered pytrees so they can cross ``jit`` /
 ``shard_map`` boundaries.  The ``shard_to_*`` converters build *shard-local*
@@ -37,14 +34,11 @@ __all__ = [
     "DeviceELL",
     "DeviceBSR",
     "DeviceHybrid",
-    "DeviceHub",
     "csr_from_coo",
     "to_device_coo",
     "to_device_ell",
     "to_device_bsr",
     "to_device_hybrid",
-    "to_device_hub",
-    "hub_rows",
     "row_sums",
     "ell_padding_stats",
     "blocked_ell_from_triplets",
@@ -357,92 +351,6 @@ def to_device_hybrid(
         tail_row=jnp.asarray(trow),
         tail_col=jnp.asarray(tcol),
         tail_val=jnp.asarray(tval, dtype=dtype),
-        n_rows=n,
-        n_cols=n,
-    )
-
-
-# Dense-hub split: at most this many hub rows are stored dense, and a row
-# qualifies once it holds at least 1/HUB_MIN_FILL of the columns.
-HUB_MAX_ROWS = 32
-HUB_MIN_FILL = 64
-
-
-def hub_rows(row_nnz: np.ndarray, n_cols: int) -> np.ndarray:
-    """Sorted indices of the rows the dense-hub split stores dense: the
-    longest rows holding at least ``n_cols / HUB_MIN_FILL`` entries, at most
-    ``HUB_MAX_ROWS`` of them."""
-    order = np.argsort(row_nnz, kind="stable")[::-1][:HUB_MAX_ROWS]
-    return np.sort(order[row_nnz[order] * HUB_MIN_FILL >= max(1, n_cols)]).astype(np.int32)
-
-
-@jax.tree_util.register_pytree_node_class
-@dataclasses.dataclass
-class DeviceHub:
-    """Dense-hub split of a symmetric matrix: hub rows dense + COO rest.
-
-    The few hub rows of a power-law graph hold a large share of the
-    non-zeros, and by symmetry so do the hub columns.  Both are served by
-    the dense (hubs, n) block: ``y[hubs] = D @ x`` and ``y += D^T @ x[hubs]``
-    (the hub columns of every row).  Only the entries outside hub rows and
-    hub columns stay COO (:func:`row_sums`).  A gather of one element costs
-    the TPU far more than streaming a dense row, so this trades one
-    gather + scatter per hub non-zero for two dense passes over ``D``.
-    """
-
-    dense: jax.Array  # (hubs, n_cols) storage dtype: the hub rows
-    hubs: jax.Array  # (hubs,) int32, sorted
-    row: jax.Array  # (rest,) int32, sorted: entries outside hub rows/cols
-    col: jax.Array  # (rest,) int32
-    val: jax.Array  # (rest,) storage dtype
-    n_rows: int  # static
-    n_cols: int  # static
-
-    def tree_flatten(self):
-        return (self.dense, self.hubs, self.row, self.col, self.val), (self.n_rows, self.n_cols)
-
-    @classmethod
-    def tree_unflatten(cls, aux, children):
-        return cls(*children, *aux)
-
-    def matvec(self, x: jax.Array, accum_dtype=None) -> jax.Array:
-        acc = accum_dtype or self.val.dtype
-        prod = self.val.astype(acc) * jnp.take(x, self.col).astype(acc)
-        y = row_sums(prod, self.row, self.n_rows)
-        # The two dense products are written as multiply + reduce, not as
-        # dots: exact in the accumulation dtype at any matmul precision, and
-        # a TPU's f64 dot emulation would need ~13 GB of temporaries on WK.
-        d = self.dense.astype(acc)
-        xh = jnp.take(x, self.hubs).astype(acc)
-        # Hub columns of every row; at the hub rows this is the hub-hub
-        # block, which the dense row product below already holds, so the
-        # hub rows are overwritten rather than accumulated.
-        y = y + jnp.sum(xh[:, None] * d, axis=0)
-        yh = jnp.sum(d * x.astype(acc)[None, :], axis=1)
-        return y.at[self.hubs].set(yh, indices_are_sorted=True, unique_indices=True)
-
-
-def to_device_hub(csr: CSR, dtype=jnp.float32) -> DeviceHub:
-    """Convert a symmetric CSR to the dense-hub split (see :class:`DeviceHub`)."""
-    n = csr.n
-    count_conversions()
-    row_nnz = csr.row_nnz()
-    hubs = hub_rows(row_nnz, n)
-    is_hub = np.zeros(n, dtype=bool)
-    is_hub[hubs] = True
-    rix = np.repeat(np.arange(n, dtype=np.int32), row_nnz)
-    rest = ~is_hub[rix] & ~is_hub[csr.indices]
-    host = np.float64 if jnp.dtype(dtype) == jnp.float64 else np.float32
-    dense = np.zeros((hubs.size, n), dtype=host)
-    for i, r in enumerate(hubs):
-        lo, hi = int(csr.indptr[r]), int(csr.indptr[r + 1])
-        dense[i, csr.indices[lo:hi]] = csr.data[lo:hi]
-    return DeviceHub(
-        dense=jnp.asarray(dense, dtype=dtype),
-        hubs=jnp.asarray(hubs),
-        row=jnp.asarray(rix[rest]),
-        col=jnp.asarray(csr.indices[rest], dtype=jnp.int32),
-        val=jnp.asarray(csr.data[rest], dtype=dtype),
         n_rows=n,
         n_cols=n,
     )

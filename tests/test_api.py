@@ -143,6 +143,47 @@ def test_auto_dispatch_judges_device_memory(nnz, tol, device_bytes, want):
     assert got == want
 
 
+@pytest.mark.parametrize("fmt", ["coo", "ell", "hybrid", "bsr"])
+def test_layout_bytes_match_the_built_layout(norm_csr, fmt):
+    """The residency estimate counts what the engine really builds, padding
+    included (hybrid's tail is counted at the unaligned cap: at most 1% over
+    here)."""
+    from repro.core.operators import make_operator
+    from repro.kernels.engine import make_engine, matrix_stats
+
+    est = matrix_stats(norm_csr).layout_bytes(fmt, 4)
+    op = make_operator(norm_csr, dtype=jnp.float32, engine=make_engine(norm_csr, fmt))
+    built = sum(leaf.nbytes for leaf in jax.tree_util.tree_leaves(op.mat))
+    assert built <= est <= 1.01 * built
+
+
+# 1 MB of device memory: half of it holds norm_csr's 13,144 non-zeros at 12 B
+# each (158 KB) with room to spare, so the 12 B figure alone would keep
+# every case below in core.
+SMALL_DEVICE = 1_000_000
+
+
+@pytest.mark.parametrize(
+    "fmt, num_iters, want",
+    [
+        # 158 KB of COO and an 8-vector f32 basis (66 KB) fit
+        ("coo", 8, "single"),
+        # ELL pads every row to the hub row's 2,037 entries: 34 MB
+        ("ell", 8, "chunked"),
+        # a 64-vector basis (524 KB) pushes the same COO over
+        ("coo", 64, "chunked"),
+    ],
+)
+def test_auto_dispatch_counts_padding_and_basis(norm_csr, monkeypatch, fmt, num_iters, want):
+    from repro.api import dispatch, session_cache_clear
+
+    monkeypatch.setattr(dispatch, "device_memory_bytes", lambda: SMALL_DEVICE)
+    session_cache_clear()
+    res = eigsh(norm_csr, 4, format=fmt, num_iters=num_iters, policy="FDF")
+    session_cache_clear()
+    assert res.backend == want
+
+
 def test_auto_dispatch_cpu_has_no_device_figure():
     """The CPU backend reports no memory_stats, so host RAM stands in."""
     from repro.api.dispatch import device_memory_bytes

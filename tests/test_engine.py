@@ -691,50 +691,6 @@ def test_shard_stats_use_remapped_block_coordinates():
     assert choose_format(unaligned) != "bsr"
 
 
-# ------------------------------------------------------- dense-hub split
-
-
-@pytest.mark.parametrize("acc", [jnp.float32, jnp.float64])
-def test_hub_matvec_matches_dense(acc):
-    """Hub rows dense, hub columns through D^T x[hubs], the rest COO: the
-    split reproduces the plain product (symmetric input)."""
-    from repro.sparse.formats import to_device_hub
-
-    csr = generate("web", 3000, 12.6, seed=4, values="normalized")
-    hub = to_device_hub(csr, dtype=acc)
-    assert 0 < hub.hubs.shape[0] <= 32
-    x = np.random.default_rng(1).standard_normal(csr.n)
-    y = np.asarray(hub.matvec(jnp.asarray(x, acc), accum_dtype=acc), np.float64)
-    np.testing.assert_allclose(y, csr.to_scipy() @ x, rtol=ACCUM_TOL[acc], atol=ACCUM_TOL[acc])
-
-
-def test_selector_picks_hub_only_compiled():
-    """A hub-dominated power-law matrix goes to the dense-hub split when
-    compiled (element gathers are the TPU's costly operation), and keeps
-    the interpreter's choice otherwise."""
-    csr = generate("web", 3000, 12.6, seed=4, values="normalized")
-    stats = matrix_stats(csr)
-    assert stats.hub_rows > 0 and stats.hub_frac >= 0.5
-    assert choose_format(stats) != "hub"
-    assert choose_format(stats, compiled=True) == "hub"
-    assert make_engine(csr, "auto", interpret=False).format == "hub"
-    assert make_engine(csr, "auto", interpret=True).format != "hub"
-    # uniform rows have no hub: compiled mode keeps ELL
-    road = generate("road", 3000, seed=4, values="normalized")
-    assert choose_format(matrix_stats(road), compiled=True) == "ell"
-
-
-def test_eigsh_hub_format_matches_coo():
-    csr = generate("web", 3000, 12.6, seed=4, values="normalized")
-    r_hub = eigsh(csr, 4, format="hub", num_iters=40, policy="FDF", reorth="full", seed=2)
-    r_coo = eigsh(csr, 4, format="coo", num_iters=40, policy="FDF", reorth="full", seed=2)
-    assert r_hub.spmv_format == "hub"
-    assert r_hub.partition["spmv"]["kernels"]["spmv"] == "xla"
-    np.testing.assert_allclose(
-        np.asarray(r_hub.eigenvalues), np.asarray(r_coo.eigenvalues), rtol=1e-6
-    )
-
-
 # --------------------------------------- what runs each phase, as reported
 
 
@@ -744,7 +700,7 @@ def test_eigsh_hub_format_matches_coo():
         # compiled (TPU): the SpMV is an XLA gather, the update a Mosaic kernel
         ("ell", False, "fused", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
         ("hybrid", False, "fused_spmv", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
-        ("hub", False, "fused", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
+        ("coo", False, "fused", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
         ("bsr", False, "unfused", jnp.float32, {"spmv": "xla", "update": "xla"}),
         ("ell", False, "fused", jnp.float64, {"spmv": "xla", "update": "xla"}),
         # interpreter (CPU): every kernel runs interpreted

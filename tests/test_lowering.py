@@ -31,12 +31,15 @@ from repro.core.precision import FFF
 from repro.kernels import ops as kops
 from repro.kernels.engine import FORMATS, ITER_UPDATE_MODES, make_engine
 from repro.sparse import generate
-from repro.sparse.formats import HUB_MAX_ROWS, DeviceHub, to_device_ell
+from repro.sparse.formats import DeviceHybrid, to_device_ell
 
 WK_N = 3_566_907
-# Entries outside the hub rows and hub columns of the generated WK matrix
-# (29,079,360 nnz, 13,971,657 of them in its 32 hub rows).
-WK_REST_NNZ = 1_136_000
+# The generated WK matrix (29,079,360 non-zeros) in its hybrid layout: the
+# ELL part at the 95th-percentile row length (6), aligned to 8 slots, and a
+# COO tail of the rows' overflow, 14,723,974 entries past the cap of 6
+# (``benchmarks/probes/spmv_wk.py`` on WK; the aligned layout spills fewer).
+WK_HYB_WIDTH = 8
+WK_TAIL_NNZ = 14_723_974
 # What the chip path may dispatch as a Pallas kernel.
 MOSAIC_KERNELS = {"lanczos_update", "mixed_dot"}
 
@@ -87,16 +90,18 @@ def test_mixed_dot_compiles_at_wk_width(one_chip, dtype, compensated):
 
 
 def test_lanczos_sweep_compiles_at_wk_width(one_chip):
-    """One jitted single-engine Lanczos sweep over the dense-hub layout the
-    chip path builds for WK, with the fused Mosaic update (FFF)."""
+    """One jitted single-engine Lanczos sweep over the hybrid layout the
+    chip path builds for WK (SpMV as XLA gathers), with the fused Mosaic
+    update (FFF)."""
     engine = make_engine(
-        generate("web", 4096, 12.6, seed=0), "hub", interpret=False
+        generate("web", 4096, 12.6, seed=0), "hybrid", interpret=False
     )
     assert engine.iteration_plan.update == "fused"
     pol = FFF.effective()
+    rows_pad = -(-WK_N // engine.tiles.block_r) * engine.tiles.block_r
 
-    def sweep(dense, hubs, row, col, val, v1):
-        mat = DeviceHub(dense, hubs, row, col, val, WK_N, WK_N)
+    def sweep(ell_val, ell_col, tail_row, tail_col, tail_val, v1):
+        mat = DeviceHybrid(ell_val, ell_col, tail_row, tail_col, tail_val, WK_N, WK_N)
         op = SparseOperator(mat, impl="engine", engine=engine)
         return _lanczos_loop(v1, ops_for_operator(op, pol), 16, pol, "half")
 
@@ -105,15 +110,15 @@ def test_lanczos_sweep_compiles_at_wk_width(one_chip):
 
     compiled = _compile(
         sweep,
-        sds((HUB_MAX_ROWS, WK_N), jnp.float32),
-        sds((HUB_MAX_ROWS,), jnp.int32),
-        sds((WK_REST_NNZ,), jnp.int32),
-        sds((WK_REST_NNZ,), jnp.int32),
-        sds((WK_REST_NNZ,), jnp.float32),
+        sds((rows_pad, WK_HYB_WIDTH), jnp.float32),
+        sds((rows_pad, WK_HYB_WIDTH), jnp.int32),
+        sds((WK_TAIL_NNZ,), jnp.int32),
+        sds((WK_TAIL_NNZ,), jnp.int32),
+        sds((WK_TAIL_NNZ,), jnp.float32),
         sds((WK_N,), jnp.float32),
     )
     mem = compiled.memory_analysis()
-    if mem is not None:  # the basis (16 x n f32) and the hub block fit one chip
+    if mem is not None:  # the basis (16 x n f32) and the layout fit one chip
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
 
 
