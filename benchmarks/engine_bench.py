@@ -6,8 +6,7 @@ engine on the same matrix and reports which format ``format="auto"`` picks.
 A trailing section times one fused Lanczos update step (Pallas kernel) vs
 the unfused three-op reference.  Interpret mode on CPU — absolute numbers
 are CPU wall time of the kernel interpreter, useful as a regression
-trajectory, not as TPU projections (those live in kernels_bench.py /
-roofline.py).
+trajectory, not as TPU projections (those live in kernels_bench.py).
 """
 
 import jax

@@ -1,4 +1,4 @@
-"""Benchmark entry point: one section per paper table/figure + roofline.
+"""Benchmark entry point: one section per paper table/figure.
 
 Prints ``name,us_per_call,derived`` CSV lines (see common.emit) and writes
 JSON artifacts under artifacts/.
@@ -76,13 +76,6 @@ def main(argv=None) -> None:
         sections = _smoke_sections()
     else:
         sections = _full_sections()
-        # roofline runs only when dry-run artifacts exist
-        import glob
-
-        if glob.glob(os.path.join(ARTIFACTS, "dryrun", "*.json")):
-            from . import roofline
-
-            sections.append(("roofline", roofline.run))
 
     failures = []
     for name, fn in sections:

@@ -35,6 +35,7 @@ from ..core.operators import (
 )
 from ..sparse.diskcsr import DiskCSR, diskcsr_fingerprint, is_diskcsr, open_diskcsr
 from ..sparse.formats import CSR, DeviceCOO, DeviceELL
+from ..tracing import span
 
 __all__ = ["CoercedInput", "coerce_input", "matrix_fingerprint"]
 
@@ -80,6 +81,20 @@ def matrix_fingerprint(a) -> Optional[str]:
         h.update(np.ascontiguousarray(arr).tobytes())
         return h.hexdigest()
     return None
+
+
+def traced_fingerprint(a) -> Optional[str]:
+    """:func:`matrix_fingerprint` inside a ``repro.session.fingerprint`` span
+    whose ``bytes`` stat counts the buffers hashed in full (0 for the sampled
+    digest of a disk-backed matrix)."""
+    if isinstance(a, (DiskCSR, str, os.PathLike)):
+        hashed = 0
+    elif isinstance(a, CSR):
+        hashed = sum(np.asarray(x).nbytes for x in (a.indptr, a.indices, a.data))
+    else:
+        hashed = int(getattr(a, "nbytes", 0))
+    with span("repro.session.fingerprint", bytes=hashed):
+        return matrix_fingerprint(a)
 
 
 def _validate_values(data, storage_dtype, what: str) -> None:
@@ -156,7 +171,7 @@ def coerce_input(
     def _fp(x):
         if fingerprint is not None:
             return fingerprint
-        return matrix_fingerprint(x) if want_fingerprint else None
+        return traced_fingerprint(x) if want_fingerprint else None
 
     if isinstance(a, CSR):
         _validate_values(a.data, storage_dtype, "CSR data")

@@ -35,6 +35,7 @@ byte-identical call performs zero format conversions and zero tuner probes
 from __future__ import annotations
 
 import dataclasses
+import itertools
 import math
 import warnings
 from collections.abc import Mapping
@@ -44,9 +45,14 @@ import jax.numpy as jnp
 
 from ..core.precision import POLICIES, PrecisionPolicy
 from ..kernels.engine import FORMATS
+from ..tracing import span
 from .result import EigenResult
 
 __all__ = ["SolverConfig", "eigsh", "resolve_policy", "is_auto_policy"]
+
+# Process-wide sequence number of ``eigsh`` calls: the ``request`` stat of
+# the ``repro.eigsh`` span, which every span of the call nests under.
+_REQUEST_IDS = itertools.count()
 
 
 def is_auto_policy(policy) -> bool:
@@ -291,70 +297,73 @@ def eigsh(
       cached :class:`~repro.api.session.EigenSession` (``session_reuse`` is
       set, ``timings["prepare_s"]`` drops to 0); see the module docstring.
     """
-    if impl is not None:
-        warnings.warn(
-            "impl= is deprecated and now maps onto format= (impl='ell'/"
-            "'ell_kernel' -> format='ell', 'bsr_kernel' -> format='bsr', "
-            "'coo' -> format='coo'); the legacy fixed SpMV paths are gone — "
-            "pass format= directly",
-            DeprecationWarning,
-            stacklevel=2,
-        )
-        mapped = _IMPL_TO_FORMAT.get(impl)
-        if mapped is None:
-            raise ValueError(
-                f"unknown legacy impl {impl!r}; expected one of {sorted(_IMPL_TO_FORMAT)}"
+    pol = config.policy if config is not None else policy
+    pol_name = getattr(pol, "name", None) or str(pol)
+    with span("repro.eigsh", request=next(_REQUEST_IDS), k=k, policy=pol_name):
+        if impl is not None:
+            warnings.warn(
+                "impl= is deprecated and now maps onto format= (impl='ell'/"
+                "'ell_kernel' -> format='ell', 'bsr_kernel' -> format='bsr', "
+                "'coo' -> format='coo'); the legacy fixed SpMV paths are gone — "
+                "pass format= directly",
+                DeprecationWarning,
+                stacklevel=2,
             )
-        if format == "auto":
-            # impl defaults to None now, so an explicit impl="coo" is a real
-            # request for the segment-sum reference path and must pin it.
-            format = mapped
-    cfg = config or SolverConfig(
-        policy=policy,
-        backend=backend,
-        reorth=reorth,
-        tol=tol,
-        num_iters=num_iters,
-        subspace=subspace,
-        max_restarts=max_restarts,
-        seed=seed,
-        format=format,
-        chunk_nnz=chunk_nnz,
-        stage_depth=stage_depth,
-        staging=staging,
-        jacobi=jacobi,
-        axis=axis,
-        recovery=recovery,
-        checkpoint_dir=checkpoint_dir,
-        checkpoint_every=checkpoint_every,
-    )
-    if k < 1:
-        raise ValueError(f"k must be >= 1, got {k}")
-    if cfg.format not in ("auto",) + FORMATS:
-        raise ValueError(
-            f"unknown SpMV format {cfg.format!r}; expected 'auto' or one of {FORMATS}"
+            mapped = _IMPL_TO_FORMAT.get(impl)
+            if mapped is None:
+                raise ValueError(
+                    f"unknown legacy impl {impl!r}; expected one of {sorted(_IMPL_TO_FORMAT)}"
+                )
+            if format == "auto":
+                # impl defaults to None now, so an explicit impl="coo" is a real
+                # request for the segment-sum reference path and must pin it.
+                format = mapped
+        cfg = config or SolverConfig(
+            policy=policy,
+            backend=backend,
+            reorth=reorth,
+            tol=tol,
+            num_iters=num_iters,
+            subspace=subspace,
+            max_restarts=max_restarts,
+            seed=seed,
+            format=format,
+            chunk_nnz=chunk_nnz,
+            stage_depth=stage_depth,
+            staging=staging,
+            jacobi=jacobi,
+            axis=axis,
+            recovery=recovery,
+            checkpoint_dir=checkpoint_dir,
+            checkpoint_every=checkpoint_every,
         )
+        if k < 1:
+            raise ValueError(f"k must be >= 1, got {k}")
+        if cfg.format not in ("auto",) + FORMATS:
+            raise ValueError(
+                f"unknown SpMV format {cfg.format!r}; expected 'auto' or one of {FORMATS}"
+            )
 
-    from .session import get_session  # lazy: session imports this module
+        from .session import get_session  # lazy: session imports this module
 
-    session, _hit = get_session(A, cfg, mesh=mesh, n=n)
-    # Per-query fields come from THIS call's config — a cached session may
-    # have been prepared under different solver defaults.  Routed through
-    # eigsh_many(defaults=cfg) so non-query knobs that must bind per call
-    # (recovery, checkpoint_dir) resolve against THIS config too.
-    from .session import EigQuery
+        session, _hit = get_session(A, cfg, mesh=mesh, n=n)
+        # Per-query fields come from THIS call's config — a cached session may
+        # have been prepared under different solver defaults.  Routed through
+        # eigsh_many(defaults=cfg) so non-query knobs that must bind per call
+        # (recovery, checkpoint_dir) resolve against THIS config too.
+        from .session import EigQuery
 
-    q = EigQuery(
-        k=k,
-        policy=cfg.policy,
-        tol=cfg.tol,
-        num_iters=cfg.num_iters,
-        reorth=cfg.reorth,
-        v0=v0,
-        seed=cfg.seed,
-        subspace=cfg.subspace,
-        max_restarts=cfg.max_restarts,
-        jacobi=cfg.jacobi,
-        recovery=cfg.recovery,
-    )
-    return session.eigsh_many([q], defaults=cfg)[0]
+        q = EigQuery(
+            k=k,
+            policy=cfg.policy,
+            tol=cfg.tol,
+            num_iters=cfg.num_iters,
+            reorth=cfg.reorth,
+            v0=v0,
+            seed=cfg.seed,
+            subspace=cfg.subspace,
+            max_restarts=cfg.max_restarts,
+            jacobi=cfg.jacobi,
+            recovery=cfg.recovery,
+        )
+        return session.eigsh_many([q], defaults=cfg)[0]

@@ -82,7 +82,8 @@ from ..kernels.engine import (
 from ..kernels.ops import default_interpret
 from ..sparse.diskcsr import DiskCSR, is_diskcsr
 from ..sparse.formats import CSR, conversion_count
-from .coerce import CoercedInput, coerce_input, matrix_fingerprint
+from ..tracing import span
+from .coerce import CoercedInput, coerce_input, traced_fingerprint
 from .dispatch import device_working_set, select_backend
 from .frontend import (
     SolverConfig,
@@ -754,7 +755,7 @@ class EigenSession:
         if self.matrix_fingerprint is None:
             src = self.csr if self.csr is not None else self._dense
             if src is not None:
-                self.matrix_fingerprint = matrix_fingerprint(src)
+                self.matrix_fingerprint = traced_fingerprint(src)
         return self.matrix_fingerprint
 
     def group_key(self, query, defaults: Optional[SolverConfig] = None) -> Optional[tuple]:
@@ -1200,113 +1201,114 @@ class EigenSession:
         tridiag,
         group_size,
     ) -> Tuple[int, EigenResult]:
-        # Judge convergence on the engines' full-precision eigenvalues so the
-        # flags agree with the restarted engine's own stopping decision (the
-        # output-dtype cast could flip a boundary pair).
-        lam = np.abs(np.asarray(evals_f64, dtype=np.float64))
-        converged = np.asarray(residuals) <= q.tol_eff * np.maximum(lam, 1e-300)
-        t = dict(timings)
-        solve_s = float(t.get("total_s", 0.0))
-        t["solve_s"] = solve_s
-        # A building call also claims the session's so-far-unattributed init
-        # (coercion/fingerprint) cost, so first-call totals cover real wall
-        # time; pure executes report 0.
-        t["prepare_s"] = (prep.build_s + self._claim_init_s()) if built else 0.0
-        t["total_s"] = t["prepare_s"] + solve_s
-        if group_size > 1:
-            t["amortized_over"] = float(group_size)
-        part = dict(partition) if partition else {}
-        spmv = dict(part.get("spmv", {}))
-        if not spmv:
-            if prep.engine is not None:
-                spmv = prep.engine.describe()
-            else:
-                fmt0 = spmv_format[0] if isinstance(spmv_format, tuple) else spmv_format
-                spmv = {"format": fmt0}
-        # The reuse contract, verified: what THIS call actually paid.
-        spmv["conversions"] = prep.conversions if built else 0
-        spmv["tuner_probes"] = prep.tuner_probes if built else 0
-        spmv["reused"] = not built
-        # Iteration-plan provenance: what the tuner (or mode table) chose,
-        # plus the update mode this query's policy actually allows — the
-        # policy gate can demote a fused plan (compensated / phase splits).
-        iter_plan = getattr(prep.engine, "iteration_plan", None)
-        effective = resolve_update_mode(q.pol, plan=iter_plan)
-        if spmv.get("iteration_plan") or iter_plan is not None:
-            rec = dict(spmv.get("iteration_plan") or iter_plan.as_dict())
-            rec["effective"] = effective
-            spmv["iteration_plan"] = rec
-        # Which phases ran as Mosaic kernels and which as XLA (the restarted
-        # engine's update is its own jitted jnp, never the fused kernel).
-        eng = prep.engine
-        spmv["kernels"] = phase_executors(
-            eng.format if eng is not None else "coo",
-            eng.interpret if eng is not None else default_interpret(),
-            "unfused" if q.backend == "restarted" else effective,
-            q.pol.compute,
-        )
-        # Per-phase precision audit: the phase map this solve executed and a
-        # model-based count of element ops per dtype (how the "this split
-        # reduced f64 work" claim is verified — see precision.phase_op_counts).
-        spmv["precision"] = {
-            "policy": q.pol.name,
-            "phase_map": q.pol.phase_map(),
-            "compensated": bool(q.pol.compensated),
-            "uniform": q.pol.is_uniform(),
-            "ops_by_dtype": phase_op_counts(
-                q.pol,
-                n=self.n,
-                nnz=self._nnz_estimate(),
-                m=int(iterations),
-                k=q.k,
-                reorth=q.reorth,
-            ),
-        }
-        # Jaxpr-measured counterpart (repro.analysis P004 ground truth):
-        # traces the session's own operator — no execution, no data copies —
-        # so it is opt-in; a trace failure degrades to an error note, never
-        # a failed solve.
-        if envcfg.get_bool("REPRO_PRECISION_MEASURE"):
-            if prep.operator is None:
-                spmv["precision"]["ops_by_dtype_measured"] = {
-                    "error": "no single-device operator to trace (distributed plan)"
-                }
-            else:
-                try:
-                    from ..analysis.precision_flow import measure_session_ops
+        with span("repro.session.finish"):
+            # Judge convergence on the engines' full-precision eigenvalues so the
+            # flags agree with the restarted engine's own stopping decision (the
+            # output-dtype cast could flip a boundary pair).
+            lam = np.abs(np.asarray(evals_f64, dtype=np.float64))
+            converged = np.asarray(residuals) <= q.tol_eff * np.maximum(lam, 1e-300)
+            t = dict(timings)
+            solve_s = float(t.get("total_s", 0.0))
+            t["solve_s"] = solve_s
+            # A building call also claims the session's so-far-unattributed init
+            # (coercion/fingerprint) cost, so first-call totals cover real wall
+            # time; pure executes report 0.
+            t["prepare_s"] = (prep.build_s + self._claim_init_s()) if built else 0.0
+            t["total_s"] = t["prepare_s"] + solve_s
+            if group_size > 1:
+                t["amortized_over"] = float(group_size)
+            part = dict(partition) if partition else {}
+            spmv = dict(part.get("spmv", {}))
+            if not spmv:
+                if prep.engine is not None:
+                    spmv = prep.engine.describe()
+                else:
+                    fmt0 = spmv_format[0] if isinstance(spmv_format, tuple) else spmv_format
+                    spmv = {"format": fmt0}
+            # The reuse contract, verified: what THIS call actually paid.
+            spmv["conversions"] = prep.conversions if built else 0
+            spmv["tuner_probes"] = prep.tuner_probes if built else 0
+            spmv["reused"] = not built
+            # Iteration-plan provenance: what the tuner (or mode table) chose,
+            # plus the update mode this query's policy actually allows — the
+            # policy gate can demote a fused plan (compensated / phase splits).
+            iter_plan = getattr(prep.engine, "iteration_plan", None)
+            effective = resolve_update_mode(q.pol, plan=iter_plan)
+            if spmv.get("iteration_plan") or iter_plan is not None:
+                rec = dict(spmv.get("iteration_plan") or iter_plan.as_dict())
+                rec["effective"] = effective
+                spmv["iteration_plan"] = rec
+            # Which phases ran as Mosaic kernels and which as XLA (the restarted
+            # engine's update is its own jitted jnp, never the fused kernel).
+            eng = prep.engine
+            spmv["kernels"] = phase_executors(
+                eng.format if eng is not None else "coo",
+                eng.interpret if eng is not None else default_interpret(),
+                "unfused" if q.backend == "restarted" else effective,
+                q.pol.compute,
+            )
+            # Per-phase precision audit: the phase map this solve executed and a
+            # model-based count of element ops per dtype (how the "this split
+            # reduced f64 work" claim is verified — see precision.phase_op_counts).
+            spmv["precision"] = {
+                "policy": q.pol.name,
+                "phase_map": q.pol.phase_map(),
+                "compensated": bool(q.pol.compensated),
+                "uniform": q.pol.is_uniform(),
+                "ops_by_dtype": phase_op_counts(
+                    q.pol,
+                    n=self.n,
+                    nnz=self._nnz_estimate(),
+                    m=int(iterations),
+                    k=q.k,
+                    reorth=q.reorth,
+                ),
+            }
+            # Jaxpr-measured counterpart (repro.analysis P004 ground truth):
+            # traces the session's own operator — no execution, no data copies —
+            # so it is opt-in; a trace failure degrades to an error note, never
+            # a failed solve.
+            if envcfg.get_bool("REPRO_PRECISION_MEASURE"):
+                if prep.operator is None:
+                    spmv["precision"]["ops_by_dtype_measured"] = {
+                        "error": "no single-device operator to trace (distributed plan)"
+                    }
+                else:
+                    try:
+                        from ..analysis.precision_flow import measure_session_ops
 
-                    spmv["precision"]["ops_by_dtype_measured"] = measure_session_ops(
-                        q.pol,
-                        prep.operator,
-                        backend=q.backend,
-                        m=max(int(iterations), 1),
-                        k=q.k,
-                        reorth=q.reorth,
-                        jacobi=q.jacobi,
-                    )
-                except Exception as exc:  # pragma: no cover - defensive
-                    spmv["precision"]["ops_by_dtype_measured"] = {"error": str(exc)}
-        part["spmv"] = spmv
-        res = EigenResult(
-            eigenvalues=eigenvalues,
-            eigenvectors=eigenvectors,
-            residuals=np.asarray(residuals, dtype=np.float64),
-            converged=converged,
-            iterations=int(iterations),
-            restarts=int(restarts),
-            k=q.k,
-            n=self.n,
-            backend=q.backend,
-            policy=q.pol.name,
-            tol=q.tol_eff,
-            num_devices=self.device_count if q.backend == "distributed" else 1,
-            partition=part,
-            timings=t,
-            spmv_format=spmv_format,
-            tridiag=tridiag,
-            session_reuse=not built,
-        )
-        return q.idx, res
+                        spmv["precision"]["ops_by_dtype_measured"] = measure_session_ops(
+                            q.pol,
+                            prep.operator,
+                            backend=q.backend,
+                            m=max(int(iterations), 1),
+                            k=q.k,
+                            reorth=q.reorth,
+                            jacobi=q.jacobi,
+                        )
+                    except Exception as exc:  # pragma: no cover - defensive
+                        spmv["precision"]["ops_by_dtype_measured"] = {"error": str(exc)}
+            part["spmv"] = spmv
+            res = EigenResult(
+                eigenvalues=eigenvalues,
+                eigenvectors=eigenvectors,
+                residuals=np.asarray(residuals, dtype=np.float64),
+                converged=converged,
+                iterations=int(iterations),
+                restarts=int(restarts),
+                k=q.k,
+                n=self.n,
+                backend=q.backend,
+                policy=q.pol.name,
+                tol=q.tol_eff,
+                num_devices=self.device_count if q.backend == "distributed" else 1,
+                partition=part,
+                timings=t,
+                spmv_format=spmv_format,
+                tridiag=tridiag,
+                session_reuse=not built,
+            )
+            return q.idx, res
 
     def _chunked_partition(self, prep: _Prepared, staging_before: dict) -> dict:
         op = prep.operator
@@ -1524,18 +1526,19 @@ class EigenSession:
                 extra = max(0, math.floor((budget - m) / max(m - k_max, 1)))
                 max_restarts = min(max_restarts, extra + 1)
             tol_target = min(q.tol_eff for q in qs)
-            sweep = solve_restarted(
-                prep.operator,
-                k_max,
-                policy=pol,
-                m=m,
-                max_restarts=max_restarts,
-                tol=tol_target,
-                seed=q0.seed,
-                v1=q0.v0,
-                probe=q0.recovery != "none",
-                checkpoint=self._solve_checkpoint(q0, pol, "restarted", k_max, m),
-            )
+            with span("repro.engine.restarted", m=m, k=k_max, max_restarts=max_restarts):
+                sweep = solve_restarted(
+                    prep.operator,
+                    k_max,
+                    policy=pol,
+                    m=m,
+                    max_restarts=max_restarts,
+                    tol=tol_target,
+                    seed=q0.seed,
+                    v1=q0.v0,
+                    probe=q0.recovery != "none",
+                    checkpoint=self._solve_checkpoint(q0, pol, "restarted", k_max, m),
+                )
             self.stats["sweeps"] += 1
             for q in qs:
                 out.append(
@@ -1866,6 +1869,13 @@ def get_session(
     mutating a matrix in place changes its digest, so stale plans are never
     served — byte-identical re-submissions are.
     """
+    with span("repro.session.get") as sp:
+        session, hit = _lookup_or_build(A, config, mesh, n)
+        sp.set_metadata(hit=int(hit))
+    return session, hit
+
+
+def _lookup_or_build(A, config, mesh, n) -> Tuple[EigenSession, bool]:
     cfg = config or SolverConfig()
     limit = _cache_limit()
     key = None
@@ -1879,7 +1889,7 @@ def get_session(
         # the digest is of the host bytes; keep host copies of matrices you
         # re-submit in a hot loop.  Disk-backed inputs probe by the sampled
         # fingerprint — O(1) I/O however large the mapping.)
-        fp = matrix_fingerprint(A)
+        fp = traced_fingerprint(A)
         if fp is not None:
             key = _session_key(fp, cfg, mesh)
             hit = _cache_lookup(key)

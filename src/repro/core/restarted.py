@@ -37,6 +37,7 @@ import jax
 import jax.numpy as jnp
 
 from ..testing import faults as _faults
+from ..tracing import span
 from .eigensolver import EigResult
 from .jacobi import jacobi_eigh_host
 from .lanczos import LanczosResult, NumericalBreakdown
@@ -51,6 +52,10 @@ __all__ = [
     "solve_restarted",
     "topk_eigs_restarted",
 ]
+
+# Blocking device-to-host reads in one Lanczos step: the ``float(...)`` of
+# alpha and of beta (the ``host_reads`` stat of ``repro.lanczos.step``).
+_HOST_READS_PER_STEP = 2
 
 
 def restart_kernels(policy: PrecisionPolicy):
@@ -197,42 +202,44 @@ def solve_restarted(
         beta_prev = 0.0
         v_prev = jnp.zeros((n,), cdt)
         for i in range(nkeep, m):
-            basis = basis.at[i].set(v.astype(sdt))
-            u = mv(v.astype(sdt)).astype(cdt)
-            u = _faults.tap_spmv(u, i)
-            alpha = float(_dot(v, u))
-            if probe and not np.isfinite(alpha):
-                raise NumericalBreakdown("nonfinite", i, pol_name, f"alpha={alpha!r}")
-            t_hat[i, i] = alpha
-            u = u - alpha * v - beta_prev * v_prev
-            if i == nkeep and nkeep > 0:
-                # arrowhead coupling to the kept Ritz block
-                u = u - basis_matmul(jnp.asarray(s_border, cdt), basis[:nkeep].astype(cdt))
-                t_hat[i, :nkeep] = s_border
-                t_hat[:nkeep, i] = s_border
-            # full re-orthogonalization (stability: see EXPERIMENTS §Reorth)
-            mask = (jnp.arange(m) <= i).astype(cdt)
-            u = _orth(u, basis, mask)
-            beta = float(jnp.sqrt(jnp.maximum(_dot(u, u), 0.0)))
-            beta = float(_faults.tap_beta(beta, i))
-            if probe:
-                if not np.isfinite(beta):
-                    raise NumericalBreakdown("nonfinite", i, pol_name, f"beta={beta!r}")
-                if beta <= breakdown_tiny and i < m - 1:
-                    raise NumericalBreakdown(
-                        "beta_underflow", i, pol_name,
-                        f"beta={beta:.3e} <= {breakdown_tiny:.3e}",
-                    )
-            if i < m - 1:
-                t_hat[i, i + 1] = beta
-                t_hat[i + 1, i] = beta
-            beta_prev, v_prev = beta, v
-            v = u / max(beta, 1e-300)
+            with span("repro.lanczos.step", i=i, cycle=cycle, host_reads=_HOST_READS_PER_STEP):
+                basis = basis.at[i].set(v.astype(sdt))
+                u = mv(v.astype(sdt)).astype(cdt)
+                u = _faults.tap_spmv(u, i)
+                alpha = float(_dot(v, u))
+                if probe and not np.isfinite(alpha):
+                    raise NumericalBreakdown("nonfinite", i, pol_name, f"alpha={alpha!r}")
+                t_hat[i, i] = alpha
+                u = u - alpha * v - beta_prev * v_prev
+                if i == nkeep and nkeep > 0:
+                    # arrowhead coupling to the kept Ritz block
+                    u = u - basis_matmul(jnp.asarray(s_border, cdt), basis[:nkeep].astype(cdt))
+                    t_hat[i, :nkeep] = s_border
+                    t_hat[:nkeep, i] = s_border
+                # full re-orthogonalization (stability: see EXPERIMENTS §Reorth)
+                mask = (jnp.arange(m) <= i).astype(cdt)
+                u = _orth(u, basis, mask)
+                beta = float(jnp.sqrt(jnp.maximum(_dot(u, u), 0.0)))
+                beta = float(_faults.tap_beta(beta, i))
+                if probe:
+                    if not np.isfinite(beta):
+                        raise NumericalBreakdown("nonfinite", i, pol_name, f"beta={beta!r}")
+                    if beta <= breakdown_tiny and i < m - 1:
+                        raise NumericalBreakdown(
+                            "beta_underflow", i, pol_name,
+                            f"beta={beta:.3e} <= {breakdown_tiny:.3e}",
+                        )
+                if i < m - 1:
+                    t_hat[i, i + 1] = beta
+                    t_hat[i + 1, i] = beta
+                beta_prev, v_prev = beta, v
+                v = u / max(beta, 1e-300)
             steps += 1
         beta_m = beta_prev
 
         # --- Ritz pairs of the projected matrix ---
-        evals, w = jacobi_eigh_host(t_hat)  # |lambda|-desc
+        with span("repro.restarted.jacobi", m=m):
+            evals, w = jacobi_eigh_host(t_hat)  # |lambda|-desc
         resid = np.abs(beta_m * w[m - 1, :k])
         if np.all(resid <= tol * np.maximum(np.abs(evals[:k]), 1e-300)):
             break
@@ -245,7 +252,8 @@ def solve_restarted(
         # --- thick restart: compress to top-k Ritz vectors + residual dir ---
         restarts += 1
         wk = jnp.asarray(w[:, :k], dtype=rzdt)
-        ritz = ritz_rows(basis, wk, policy, out_dtype=rzdt)  # (k, n)
+        with span("repro.restarted.ritz"):
+            ritz = ritz_rows(basis, wk, policy, out_dtype=rzdt)  # (k, n)
         new_basis = jnp.zeros((m, n), sdt)
         new_basis = new_basis.at[:k].set(ritz.astype(sdt))
         basis = new_basis
@@ -280,7 +288,8 @@ def solve_restarted(
         store.clear(token)  # completed: the snapshot must not resurrect
     evals_k = jnp.asarray(evals[:k], dtype=policy.output)
     wk = jnp.asarray(w[:, :k], dtype=rzdt)
-    x = ritz_project(basis, wk, policy)
+    with span("repro.restarted.ritz"):
+        x = ritz_project(basis, wk, policy)
     lres = LanczosResult(
         alpha=jnp.asarray(np.diag(t_hat), cdt),
         beta=jnp.asarray(np.diag(t_hat, 1), cdt),

@@ -6,7 +6,7 @@ A Lanczos iteration (Alg. 1 lines 5-7) is three memory-bound passes today:
     alpha = <v, w>                     (dot over n)
     u     = w - alpha v - beta v_prev  (+ ||u||^2, fused update kernel)
 
-``roofline.py`` puts the step firmly on the memory roofline, so every pass
+``bench/roofline.py`` puts the step firmly on the memory roofline, so every pass
 saved over the n-length vectors is throughput.  The dependency structure
 caps fusion at *two* passes, not one: alpha needs every row of ``w`` before
 any element of ``u`` can be written, and the TPU grid is sequential — a

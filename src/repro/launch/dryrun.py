@@ -18,8 +18,8 @@ Per cell this script:
      and ``.compile()`` on the single-pod (16,16) mesh and the multi-pod
      (2,16,16) mesh,
   4. records memory_analysis / cost_analysis / per-collective byte counts
-     parsed from the post-SPMD optimized HLO into a JSON artifact consumed by
-     benchmarks/roofline.py and EXPERIMENTS.md.
+     parsed from the post-SPMD optimized HLO into a JSON artifact (the
+     benchmark's roofline, of the eigensolver's SpMV, is bench/roofline.py).
 
 Usage:
   PYTHONPATH=src python -m repro.launch.dryrun --arch qwen3-0.6b --shape train_4k
