@@ -10,8 +10,13 @@ times one jitted SpMV per layout and accumulation dtype (median of
 ``--reps`` runs, host clock around ``block_until_ready``, compile excluded):
 
   coo     every non-zero gathered, multiplied and scattered (``row_sums``);
-  hybrid  the layout auto selection builds for WK: capped-width ELL rows
-          (gather + row reduce) and a COO tail of the long rows' overflow;
+  hybrid  the layout auto selection builds for WK under the interpreter:
+          capped-width ELL rows (gather + row reduce) and a COO tail of the
+          long rows' overflow;
+  sell    the layout auto selection builds where the SpMV runs as XLA
+          gathers (a TPU): row-length-bucketed ELL, one gather over every
+          slot, dense sums per width class and one scatter of a sum per row
+          piece of at most 1,024 entries;
   gather  the gather and multiply of every non-zero, summed to one scalar
           (no scatter): the floor of any gather-based SpMV.
 
@@ -53,13 +58,17 @@ def probe_spmv(csr, reps: int) -> None:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.engine import choose_format, matrix_stats
-    from repro.sparse.formats import to_device_coo, to_device_hybrid
+    from repro.kernels.engine import choose_format, matrix_stats, spmv_runs_pallas
+    from repro.kernels.ops import default_interpret
+    from repro.sparse.formats import to_device_coo, to_device_hybrid, to_device_sell
 
     stats = matrix_stats(csr)
-    _emit(probe="stats", auto_format=choose_format(stats), **stats.as_dict())
+    compiled = not spmv_runs_pallas(default_interpret())
+    _emit(probe="stats", auto_format=choose_format(stats, compiled=compiled), **stats.as_dict())
     coo = to_device_coo(csr, dtype=jnp.float32)
     hyb = to_device_hybrid(csr, dtype=jnp.float32, width_cap=stats.hyb_width)
+    sell = to_device_sell(csr, dtype=jnp.float32)
+    _emit(probe="sell_layout", **sell.summary())
     x_host = np.random.default_rng(1).standard_normal(csr.n)
     want = csr.to_scipy() @ x_host
     for acc in (jnp.float32, jnp.float64):
@@ -68,12 +77,9 @@ def probe_spmv(csr, reps: int) -> None:
         def gather(m, v, acc=acc):
             return jnp.sum(m.val.astype(acc) * jnp.take(v, m.col).astype(acc))
 
-        paths = {
-            "coo": jax.jit(lambda m, v, acc=acc: m.matvec(v, accum_dtype=acc)),
-            "hybrid": jax.jit(lambda m, v, acc=acc: m.matvec(v, accum_dtype=acc)),
-            "gather": jax.jit(gather),
-        }
-        mats = {"coo": coo, "hybrid": hyb, "gather": coo}
+        matvec = jax.jit(lambda m, v, acc=acc: m.matvec(v, accum_dtype=acc))
+        paths = {"coo": matvec, "hybrid": matvec, "sell": matvec, "gather": jax.jit(gather)}
+        mats = {"coo": coo, "hybrid": hyb, "sell": sell, "gather": coo}
         for name, fn in paths.items():
             sec = _median_seconds(fn, mats[name], x, reps=reps)
             out = {"probe": "spmv", "layout": name, "accum": jnp.dtype(acc).name,

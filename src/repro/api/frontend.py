@@ -252,9 +252,11 @@ def eigsh(
         picks COO vs ELL vs blocked-ELL/BSR vs hybrid (quantile-capped ELL
         plus a COO hub tail — how power-law matrices reach the kernel path)
         from cheap row-length and block-density statistics
-        (``repro.kernels.engine``); "coo" / "ell" / "bsr" / "hybrid" force
-        one.  The kernel formats execute through the Pallas SpMV kernels
-        (interpret mode off-TPU); the executed choice is reported as
+        (``repro.kernels.engine``), and on a TPU, where the SpMV runs as XLA
+        gathers, "sell" (row-length-bucketed ELL) unless BSR wins;
+        "coo" / "ell" / "bsr" / "hybrid" / "sell" force one.  The kernel
+        formats execute through the Pallas SpMV kernels in interpret mode
+        (off-TPU); the executed choice is reported as
         ``EigenResult.spmv_format``.  The distributed backend auto-selects
         kernel formats only (pass format="coo" to opt back into
         ``segment_sum``); the chunked backend supports "coo" / "ell".

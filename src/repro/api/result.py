@@ -89,8 +89,8 @@ class EigenResult:
         ``"e2e_s"`` (``queue_s + total_s``, what the submitter observed) —
         see :func:`with_queue_time`.
       spmv_format: SpMV layout the hot loop executed — "coo" | "ell" | "bsr"
-        | "hybrid" (quantile-capped ELL + COO hub tail) for explicit sparse
-        inputs ("dense" / "matfree" otherwise).  The distributed backend
+        | "hybrid" (quantile-capped ELL + COO hub tail) | "sell" (row-length-
+        bucketed ELL) for explicit sparse inputs ("dense" / "matfree" otherwise).  The distributed backend
         reports one entry per shard (a tuple; shard_map runs one program, so
         entries agree).  This is the outcome of the ``format="auto"``
         selection (see ``repro.kernels.engine``).

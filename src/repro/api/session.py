@@ -77,11 +77,12 @@ from ..kernels.engine import (
     make_engine,
     matrix_stats,
     phase_executors,
+    spmv_runs_pallas,
     tuner_probe_count,
 )
 from ..kernels.ops import default_interpret
 from ..sparse.diskcsr import DiskCSR, is_diskcsr
-from ..sparse.formats import CSR, conversion_count
+from ..sparse.formats import CSR, DeviceSELL, conversion_count
 from ..tracing import span
 from .coerce import CoercedInput, coerce_input, traced_fingerprint
 from .dispatch import device_working_set, select_backend
@@ -547,7 +548,10 @@ class EigenSession:
         """Device bytes of an in-core solve of ``steps`` Lanczos steps: the
         layout the single-device engine would build, and the basis."""
         stats = self._matrix_stats()
-        fmt = choose_format(stats) if self.cfg.format == "auto" else self.cfg.format
+        if self.cfg.format == "auto":
+            fmt = choose_format(stats, compiled=not spmv_runs_pallas(default_interpret()))
+        else:
+            fmt = self.cfg.format
         return device_working_set(stats, fmt, pol.storage, steps)
 
     def _mesh_for_solve(self):
@@ -789,7 +793,7 @@ class EigenSession:
         stored widened to f32 with their dtype recorded).
 
         Only "single"-placement plans over explicit device containers (COO /
-        ELL / BSR / hybrid) or dense operators export; chunked plans are
+        ELL / BSR / hybrid / sell) or dense operators export; chunked plans are
         host-resident anyway (nothing device-converted to save) and
         distributed plans are mesh-bound — both rebuild lazily on import.
         """
@@ -1229,6 +1233,9 @@ class EigenSession:
             spmv["conversions"] = prep.conversions if built else 0
             spmv["tuner_probes"] = prep.tuner_probes if built else 0
             spmv["reused"] = not built
+            mat = getattr(prep.operator, "mat", None)
+            if isinstance(mat, DeviceSELL):
+                spmv["sell"] = mat.summary()
             # Iteration-plan provenance: what the tuner (or mode table) chose,
             # plus the update mode this query's policy actually allows — the
             # policy gate can demote a fused plan (compensated / phase splits).
@@ -1429,16 +1436,17 @@ class EigenSession:
         return out
 
     def _vmappable(self, prep: _Prepared) -> bool:
-        """Is this operator's matvec safe under ``jax.vmap``?  Dense matmul
-        and the COO ``segment_sum`` path batch cleanly; the Pallas kernel
-        layouts are excluded (their interpret-mode batching rule is
-        unvalidated), as is the host-loop chunked operator."""
+        """Is this operator's matvec safe under ``jax.vmap``?  Dense matmul,
+        the COO ``segment_sum`` path and the bucketed ``sell`` SpMV (plain
+        jnp) batch cleanly; the Pallas kernel layouts are excluded (their
+        interpret-mode batching rule is unvalidated), as is the host-loop
+        chunked operator."""
         op = prep.operator
         if isinstance(op, DenseOperator):
             return True
         if isinstance(op, SparseOperator):
             if op.engine is not None:
-                return op.engine.format == "coo"
+                return op.engine.format in ("coo", "sell")
             return op.impl == "coo"
         return False
 
@@ -1635,6 +1643,14 @@ def _export_operator(op) -> Optional[Tuple[str, Dict[str, np.ndarray]]]:
             "tail_col": np.asarray(m.tail_col),
             "tail_val": np.asarray(m.tail_val),
         }
+    if isinstance(m, DeviceSELL):
+        return "sell", {
+            "col": np.asarray(m.col),
+            "val": np.asarray(m.val),
+            "order": np.asarray(m.order),
+            "classes": np.asarray(m.classes, dtype=np.int64).reshape(-1, 3),
+            "nnz": np.asarray([m.nnz], dtype=np.int64),
+        }
     return None
 
 
@@ -1703,6 +1719,10 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
                 n,
                 n,
             )
+        elif ctype == "sell":
+            classes = tuple(tuple(int(v) for v in c) for c in plan["arrays"]["classes"])
+            nnz = int(plan["arrays"]["nnz"][0])
+            mat = DeviceSELL(arr("col"), arr("val"), arr("order"), classes, n, n, nnz)
         else:
             raise ValueError(f"unknown persisted container type {ctype!r}")
         op = SparseOperator(mat, impl="engine" if engine is not None else "coo", engine=engine)

@@ -35,6 +35,7 @@ from ..sparse.formats import (
     to_device_coo,
     to_device_ell,
     to_device_hybrid,
+    to_device_sell,
 )
 from ..testing import faults as _faults
 from .precision import PrecisionPolicy
@@ -119,7 +120,7 @@ class SparseOperator(LinearOperator):
     SpMV execution path.  With an engine attached, the container format and
     tile parameters come from the engine (`kernels/engine.py`)."""
 
-    mat: object  # DeviceCOO | DeviceELL | DeviceBSR
+    mat: object  # DeviceCOO | DeviceELL | DeviceBSR | DeviceHybrid | DeviceSELL
     impl: str = "coo"  # "coo" | "ell" | "ell_kernel" | "bsr_kernel" | "engine"
     engine: Optional[SpmvEngine] = None
 
@@ -709,6 +710,8 @@ def make_operator(
             mat = to_device_hybrid(
                 csr, dtype=dtype, width_cap=cap, row_tile=engine.tiles.block_r
             )
+        elif engine.format == "sell":
+            mat = to_device_sell(csr, dtype=dtype)
         else:
             mat = to_device_coo(csr, dtype=dtype)
         return SparseOperator(mat, impl="engine", engine=engine)

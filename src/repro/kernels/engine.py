@@ -29,6 +29,15 @@ a COO tail (``segment_sum``).  Power-law matrices whose max row blows the ELL
 bound still run the Pallas kernel for the bounded bulk of their non-zeros
 (``hyb_overhead`` / ``hyb_tail_frac`` in :class:`SpmvStats` drive the choice).
 
+A fifth, ``sell``, is the row-length-bucketed ELL of
+``sparse.formats.DeviceSELL``: width classes each padded to their own width
+(at most 1.125 slots per non-zero, ``sell_slots`` in :class:`SpmvStats`), one
+gather, dense per-class sums and one scatter of a sum per row piece, not
+COO's scatter-add per non-zero.  It has no Pallas kernel, so selection takes
+it only where the SpMV runs as compiled XLA gathers (see below): there it has
+the fewest gathered slots of the gather layouts, and the 128-lane ELL pad, a
+Pallas ``BlockSpec`` constraint, is pure cost.
+
 Tile parameters come from the static table (``select_tiles``) by default, or
 from the **measured autotuner** (:func:`tuned_tiles`) when
 ``REPRO_SPMV_TUNE=1``: a small candidate grid is timed on probe SpMVs for the
@@ -57,10 +66,11 @@ entries instead of requiring a manual CI cache-key bump.
 (``spmv_ell``, ``spmv_ell_alpha``, ``spmv_ell_packed``, ``spmv_bsr``) hold
 the whole ``x`` in VMEM and gather from it: Mosaic refuses their 1-D gather,
 and the 16 MiB scoped VMEM caps a double-buffered f32 ``x`` near 2M entries.
-So compiled (TPU) execution runs the SpMV as XLA gathers over the same ELL /
-BSR / hybrid layouts, and only the vector kernels (``lanczos_update``,
-``mixed_dot``) run as Mosaic kernels.  Interpret mode still runs every
-kernel, which is how the CPU tests cover them.  ``partition["spmv"]
+So compiled (TPU) execution runs the SpMV as XLA gathers, over the ``sell``
+layout where auto selection is free to pick it and over ELL / BSR / hybrid
+where a backend restricts the formats, and only the vector kernels
+(``lanczos_update``, ``mixed_dot``) run as Mosaic kernels.  Interpret mode
+still runs every kernel, which is how the CPU tests cover them.  ``partition["spmv"]
 ["kernels"]`` reports the split per phase (:func:`phase_executors`).
 """
 
@@ -104,7 +114,7 @@ __all__ = [
     "make_engine",
 ]
 
-FORMATS = ("coo", "ell", "bsr", "hybrid")
+FORMATS = ("coo", "ell", "bsr", "hybrid", "sell")
 
 # ELL accepted while padded slots <= ELL_MAX_OVERHEAD * nnz.
 ELL_MAX_OVERHEAD = 3.0
@@ -558,11 +568,11 @@ def spmv_runs_pallas(interpret: bool) -> bool:
 def phase_executors(fmt: str, interpret: bool, update: str, compute_dtype) -> dict:
     """What runs each per-iteration phase: ``"mosaic"`` (a compiled Pallas
     kernel), ``"pallas_interpret"`` or ``"xla"``.  ``fmt`` is the SpMV format
-    ("coo" also stands for dense and matrix-free operators, and has no
-    kernel), ``update`` the effective update mode; f64 compute keeps
+    ("coo" also stands for dense and matrix-free operators; it and "sell"
+    have no kernel), ``update`` the effective update mode; f64 compute keeps
     the jnp update."""
     pallas = "pallas_interpret" if interpret else "mosaic"
-    spmv = pallas if fmt != "coo" and spmv_runs_pallas(interpret) else "xla"
+    spmv = pallas if fmt not in ("coo", "sell") and spmv_runs_pallas(interpret) else "xla"
     fused = update != "unfused" and jnp.dtype(compute_dtype) != jnp.dtype(jnp.float64)
     return {"spmv": spmv, "update": pallas if fused else "xla"}
 
@@ -808,6 +818,12 @@ class SpmvStats:
     hyb_overhead: float = 0.0  # (capped ELL slots + tail) / nnz
     hyb_tail_frac: float = 0.0  # tail nnz / nnz
     block_row_max: int = 0  # touched blocks in the fullest block-row
+    # Row-length-bucketed ELL (sparse.formats.sell_classes): padded slots,
+    # stored row pieces (one per non-empty row of up to ROW_BLOCK entries)
+    # and width classes.
+    sell_slots: int = 0
+    sell_pieces: int = 0
+    sell_classes: int = 0
 
     def as_dict(self) -> dict:
         return dataclasses.asdict(self)
@@ -818,8 +834,11 @@ class SpmvStats:
         triplet.  ELL pads every row to the longest (128-lane aligned),
         hybrid to its capped width (8-slot aligned) plus the tail (counted at
         the unaligned cap: an upper bound), BSR every block-row to the
-        fullest one."""
+        fullest one; sell each row piece to its class width, plus an int32
+        row index per piece."""
         vb = int(value_bytes)
+        if fmt == "sell":
+            return self.sell_slots * (vb + 4) + 4 * self.sell_pieces
         if fmt == "ell":
             width = -(-max(1, self.max_row_nnz) // 128) * 128
             return width * self.n_rows * (vb + 4)
@@ -879,6 +898,9 @@ def _stats_from_triplets(
     fill = nnz / (n_blocks * bs * bs) if n_blocks else 0.0
     cap = hybrid_width_cap(row_nnz) if hyb_width is None else int(hyb_width)
     tail = int(np.maximum(row_nnz - cap, 0).sum()) if (nnz and cap) else 0
+    from ..sparse.formats import sell_classes  # lazy: sparse sits below kernels
+
+    pieces, _, classes = sell_classes(row_nnz)
     return SpmvStats(
         n_rows=n_rows,
         nnz=nnz,
@@ -893,6 +915,9 @@ def _stats_from_triplets(
         hyb_overhead=(cap * n_rows + tail) / max(1, nnz),
         hyb_tail_frac=tail / max(1, nnz),
         block_row_max=block_row_max,
+        sell_slots=sum(w * r for w, r in classes),
+        sell_pieces=int(pieces.size),
+        sell_classes=len(classes),
     )
 
 
@@ -968,6 +993,7 @@ def choose_format(
     *,
     ell_max_overhead: Optional[float] = None,
     bsr_fill_factor: Optional[float] = None,
+    compiled: bool = False,
 ) -> str:
     """Pick a SpMV format from layout statistics (see module docstring).
 
@@ -979,6 +1005,13 @@ def choose_format(
     ``("ell", "bsr")`` because its hot loop is kernel-only (COO remains an
     explicit opt-out there), the chunked engine passes ``("coo", "ell")``
     because per-chunk BSR staging is not implemented.
+
+    ``compiled`` says the SpMV runs as XLA gathers, not through the Pallas
+    kernels (:func:`spmv_runs_pallas` false).  Then ``"sell"``, where
+    allowed, is taken after the BSR test: it gathers the fewest slots of the
+    gather layouts and scatters one sum per row piece, not one product per
+    non-zero.  Under the interpreter the choice is among the kernel formats
+    and COO alone.
     """
     if isinstance(stats, SpmvStats):
         stats = (stats,)
@@ -994,6 +1027,8 @@ def choose_format(
     )
     if bsr_ok:
         return "bsr"
+    if compiled and "sell" in allowed:
+        return "sell"
     ell_ok = "ell" in allowed and all(s.ell_overhead <= ell_max for s in stats)
     if ell_ok:
         return "ell"
@@ -1050,7 +1085,7 @@ class SpmvEngine:
     Frozen and hashable so it can ride through ``jax.jit`` static arguments.
     ``interpret`` selects the Pallas interpreter (CPU containers) vs compiled
     execution (real TPU).  Compiled, the SpMV runs as XLA gathers over the
-    same layouts (still ELL/BSR, never ``segment_sum``) — see
+    layout the format names, ``sell`` under auto selection — see
     :func:`spmv_runs_pallas`.
     """
 
@@ -1175,7 +1210,7 @@ class SpmvEngine:
     # --- container-level dispatch (single-device operators) ----------------
 
     def spmv(self, mat, x: jax.Array, accum_dtype=None) -> jax.Array:
-        """SpMV on a device container (DeviceCOO/ELL/BSR/Hybrid).
+        """SpMV on a device container (DeviceCOO/ELL/BSR/Hybrid/SELL).
 
         One compiled program, the container passed as an argument: run op by
         op from a host loop (the restarted engine), the SpMV would materialise
@@ -1202,9 +1237,9 @@ class SpmvEngine:
 
 @functools.partial(jax.jit, static_argnums=(0, 3))
 def _container_spmv(engine: SpmvEngine, mat, x: jax.Array, acc) -> jax.Array:
-    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
+    from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid, DeviceSELL
 
-    if isinstance(mat, DeviceCOO):
+    if isinstance(mat, (DeviceCOO, DeviceSELL)):
         return mat.matvec(x, accum_dtype=acc)
     eng = engine if acc == engine.accum_dtype else dataclasses.replace(engine, accum_dtype=acc)
     if isinstance(mat, DeviceELL):
@@ -1235,8 +1270,9 @@ def make_engine(
 ) -> SpmvEngine:
     """Build a :class:`SpmvEngine` for a matrix (or precomputed shard stats).
 
-    ``format="auto"`` runs :func:`choose_format` on the statistics; an
-    explicit format is validated against ``allowed`` and used as-is.
+    ``format="auto"`` runs :func:`choose_format` on the statistics, with
+    ``compiled`` set from the execution mode; an explicit format is
+    validated against ``allowed`` and used as-is.
     """
     requested = format
     if stats is None:
@@ -1251,12 +1287,14 @@ def make_engine(
     else:
         stats = tuple(stats)
 
+    interp = _default_interpret() if interpret is None else interpret
     if format == "auto":
         fmt = choose_format(
             stats,
             allowed,
             ell_max_overhead=ell_max_overhead,
             bsr_fill_factor=bsr_fill_factor,
+            compiled=not spmv_runs_pallas(interp),
         )
     else:
         if format not in FORMATS:
@@ -1267,13 +1305,13 @@ def make_engine(
             )
         fmt = format
 
-    interp = _default_interpret() if interpret is None else interpret
     tiles_from = "override"
     n_rows = max(s.n_rows for s in stats)
     # Tiles (and autotune probes) must see the width the built layout
     # will actually have, not the raw row statistic: hybrid runs the ELL
     # kernel at the capped width (8-slot aligned, to_device_hybrid),
-    # plain ELL pads to the 128-lane tile (to_device_ell/shard_to_ell).
+    # plain ELL pads to the 128-lane tile (to_device_ell/shard_to_ell);
+    # sell and COO have no kernel tiles and report the longest row.
     if fmt == "hybrid":
         width = -(-max(1, max(s.hyb_width for s in stats)) // 8) * 8
     elif fmt == "ell":

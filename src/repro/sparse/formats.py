@@ -11,6 +11,11 @@ Host-side construction is NumPy (CSR); device-side compute formats are:
 * ``DeviceHybrid`` — hub-row split: ELL capped at a quantile of the row
   lengths (Pallas kernel part) plus a COO overflow tail (``segment_sum``),
   so power-law matrices reach the kernel path without padding blowup.
+* ``DeviceSELL`` — row-length-bucketed ELL: rows cut into pieces of at most
+  ``ROW_BLOCK`` entries, sorted by length into width classes, each padded
+  only to its own width and stored slot-major in flat 1-D arrays; the SpMV
+  is one gather, dense per-class sums and one scatter of one sum per piece
+  (the compiled layout, see ``kernels/engine.py``).
 
 All device containers are registered pytrees so they can cross ``jit`` /
 ``shard_map`` boundaries.  The ``shard_to_*`` converters build *shard-local*
@@ -34,11 +39,14 @@ __all__ = [
     "DeviceELL",
     "DeviceBSR",
     "DeviceHybrid",
+    "DeviceSELL",
     "csr_from_coo",
     "to_device_coo",
     "to_device_ell",
     "to_device_bsr",
     "to_device_hybrid",
+    "to_device_sell",
+    "sell_classes",
     "row_sums",
     "ell_padding_stats",
     "blocked_ell_from_triplets",
@@ -353,6 +361,146 @@ def to_device_hybrid(
         tail_val=jnp.asarray(tval, dtype=dtype),
         n_rows=n,
         n_cols=n,
+    )
+
+
+# Width classes of the bucketed layout: every piece length up to SELL_EXACT
+# is a class of its own; above it each class bound is at most SELL_GROWTH
+# times the one below, so no piece is padded by more than 12.5%.
+SELL_EXACT = 16
+SELL_GROWTH = 1.125
+
+
+def sell_classes(
+    row_nnz: np.ndarray,
+) -> Tuple[np.ndarray, np.ndarray, Tuple[Tuple[int, int], ...]]:
+    """Pieces and width classes of the bucketed layout.
+
+    Every row is cut into pieces of at most ``ROW_BLOCK`` consecutive
+    entries; empty rows have none.  Returns ``(row, first, classes)``:
+    ``row`` and ``first`` give, for each piece sorted by length (stable),
+    its row and the position of its first entry within the row, and
+    ``classes`` one ``(width, pieces)`` pair per non-empty class, in that
+    order; a class's width is its longest piece.
+    """
+    lens = np.asarray(row_nnz, dtype=np.int64)
+    cuts = -(-lens // ROW_BLOCK)
+    row = np.repeat(np.arange(lens.size, dtype=np.int64), cuts)
+    first = (np.arange(row.size) - np.repeat(np.cumsum(cuts) - cuts, cuts)) * ROW_BLOCK
+    plen = np.minimum(lens[row] - first, ROW_BLOCK)
+    order = np.argsort(plen, kind="stable")
+    row, first, plen = row[order], first[order], plen[order]
+    if not row.size:
+        return row, first, ()
+    top = int(plen[-1])
+    bounds = list(range(1, min(top, SELL_EXACT) + 1))
+    while bounds[-1] < top:
+        bounds.append(max(bounds[-1] + 1, int(bounds[-1] * SELL_GROWTH)))
+    cls = np.searchsorted(np.asarray(bounds), plen)
+    stops = np.append(np.flatnonzero(np.diff(cls)) + 1, plen.size)
+    starts = np.append(0, stops[:-1])
+    classes = tuple((int(plen[b - 1]), int(b - a)) for a, b in zip(starts, stops))
+    return row, first, classes
+
+
+@jax.tree_util.register_pytree_node_class
+@dataclasses.dataclass
+class DeviceSELL:
+    """Row-length-bucketed ELL: width classes, each padded to its own width.
+
+    Rows are cut into pieces of at most ``ROW_BLOCK`` entries
+    (:func:`sell_classes`).  Class ``c`` holds ``rows`` pieces, each padded
+    to ``width`` slots and stored slot-major from ``offset`` on: slot ``s``
+    of its piece ``p`` at ``offset + s * rows + p`` of the flat ``col`` /
+    ``val``, so a class sums across its pieces, 128 to a vector register.
+    Padding slots have ``val == 0`` and ``col == 0``.  ``order`` holds each
+    piece's row; a row longer than ``ROW_BLOCK`` has several.  Every array
+    the SpMV reads is 1-D: a ``(rows, width)`` array would be laid out 128
+    lanes wide on a TPU and bring the padding back.  The cut bounds every
+    class's width by ``ROW_BLOCK``: with whole hub rows as classes (up to
+    3.57M slots wide) the TPU compiler did not finish the Wikipedia
+    matrix's SpMV in ten minutes.
+    """
+
+    col: jax.Array  # (slots,) int32
+    val: jax.Array  # (slots,) storage dtype
+    order: jax.Array  # (pieces,) int32: the row each stored piece belongs to
+    classes: Tuple[Tuple[int, int, int], ...]  # static (width, pieces, offset)
+    n_rows: int  # static
+    n_cols: int  # static
+    nnz: int  # static: stored entries, padding excluded
+
+    def tree_flatten(self):
+        return (self.col, self.val, self.order), (self.classes, self.n_rows, self.n_cols, self.nnz)
+
+    @classmethod
+    def tree_unflatten(cls, aux, children):
+        return cls(*children, *aux)
+
+    @property
+    def slots(self) -> int:
+        return int(self.col.shape[0])
+
+    def summary(self) -> dict:
+        """Classes, slots and padding of the built layout."""
+        return {
+            "classes": len(self.classes),
+            "slots": self.slots,
+            "slots_per_nnz": self.slots / max(1, self.nnz),
+        }
+
+    def matvec(self, x: jax.Array, accum_dtype=None) -> jax.Array:
+        """One gather over all slots, a dense sum per class, and one
+        scatter-add of the pieces' sums into their rows: a row longer than
+        ``ROW_BLOCK`` adds up its pieces' partials, as ``row_sums`` does."""
+        acc = accum_dtype or self.val.dtype
+        y = jnp.zeros((self.n_rows,), acc)
+        if not self.classes:
+            return y
+        prod = self.val.astype(acc) * jnp.take(x, self.col).astype(acc)
+        # The barrier keeps each class's reshape on its own slice: left free,
+        # the TPU compiler rewrote a two-piece class's slice-and-reshape as a
+        # reshape of all the products to (slots / 2, 2), 64 lanes of padding
+        # to each value (8.4 GB on the kron-s20 matrix).
+        sums = [
+            jax.lax.optimization_barrier(prod[off : off + width * rows])
+            .reshape(width, rows)
+            .sum(axis=0)
+            for width, rows, off in self.classes
+        ]
+        return y.at[self.order].add(jnp.concatenate(sums))
+
+
+def to_device_sell(csr: CSR, dtype=jnp.float32) -> DeviceSELL:
+    """Convert CSR to the row-length-bucketed layout, class by class (host
+    temporaries stay within one class; values go straight to ``dtype``)."""
+    count_conversions()
+    row_nnz = csr.row_nnz()
+    row, first, classes = sell_classes(row_nnz)
+    slots = sum(w * r for w, r in classes)
+    col = np.zeros((slots,), dtype=np.int32)
+    val = np.zeros((slots,), dtype=jnp.dtype(dtype))
+    meta = []
+    off = done = 0
+    for width, rows in classes:
+        piece = slice(done, done + rows)
+        s = np.arange(width, dtype=np.int64)[:, None]
+        mask = s < (row_nnz[row[piece]] - first[piece])[None, :]  # (width, rows)
+        src = (csr.indptr[row[piece]] + first[piece])[None, :] + s
+        dst = off + np.flatnonzero(mask)
+        col[dst] = csr.indices[src[mask]]
+        val[dst] = csr.data[src[mask]]
+        meta.append((width, rows, off))
+        off += width * rows
+        done += rows
+    return DeviceSELL(
+        col=jnp.asarray(col),
+        val=jnp.asarray(val, dtype=dtype),
+        order=jnp.asarray(row.astype(np.int32)),
+        classes=tuple(meta),
+        n_rows=csr.n,
+        n_cols=csr.n,
+        nnz=csr.nnz,
     )
 
 

@@ -143,7 +143,7 @@ def test_auto_dispatch_judges_device_memory(nnz, tol, device_bytes, want):
     assert got == want
 
 
-@pytest.mark.parametrize("fmt", ["coo", "ell", "hybrid", "bsr"])
+@pytest.mark.parametrize("fmt", ["coo", "ell", "hybrid", "bsr", "sell"])
 def test_layout_bytes_match_the_built_layout(norm_csr, fmt):
     """The residency estimate counts what the engine really builds, padding
     included (hybrid's tail is counted at the unaligned cap: at most 1% over

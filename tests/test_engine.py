@@ -195,7 +195,7 @@ def test_tile_env_override(monkeypatch):
     ],
     ids=["blockdiag", "banded", "powerlaw"],
 )
-@pytest.mark.parametrize("fmt", ["coo", "ell", "bsr", "hybrid"])
+@pytest.mark.parametrize("fmt", ["coo", "ell", "bsr", "hybrid", "sell"])
 @pytest.mark.parametrize("acc", [jnp.float32, jnp.float64])
 def test_all_formats_match_dense_reference(make_csr, fmt, acc):
     csr = make_csr()

@@ -31,7 +31,7 @@ from repro.core.precision import FFF
 from repro.kernels import ops as kops
 from repro.kernels.engine import FORMATS, ITER_UPDATE_MODES, make_engine
 from repro.sparse import generate
-from repro.sparse.formats import DeviceHybrid, to_device_ell
+from repro.sparse.formats import DeviceHybrid, DeviceSELL, sell_classes, to_device_ell
 
 WK_N = 3_566_907
 # The generated WK matrix (29,079,360 non-zeros) in its hybrid layout: the
@@ -120,6 +120,49 @@ def test_lanczos_sweep_compiles_at_wk_width(one_chip):
     mem = compiled.memory_analysis()
     if mem is not None:  # the basis (16 x n f32) and the layout fit one chip
         assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 16 * 2**30
+
+
+@pytest.mark.parametrize("config", ["gap-kron-s20", "gap-urand-s20"])
+def test_sell_spmv_compiles_at_cell_scale(one_chip, config):
+    """The compiled SpMV over the bucketed layout of a benchmark cell's
+    matrix (2^20 rows, ~33M slots): one XLA gather over every slot, no
+    Mosaic kernel, and temporaries of about two copies of the products (a
+    compiler rewrite that pads a class to 128 lanes shows as gigabytes)."""
+    import json
+    import sys
+
+    from repro.kernels.engine import _container_spmv
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    if root not in sys.path:
+        sys.path.insert(0, root)
+    from bench.gen import generate as gap_generate
+
+    with open(os.path.join(root, "bench", "configs", f"{config}.json")) as f:
+        g = gap_generate(json.load(f), 1)
+    n = g.n
+    pieces, _, classes = sell_classes(np.diff(g.indptr))
+    meta, off = [], 0
+    for width, rows in classes:
+        meta.append((width, rows, off))
+        off += width * rows
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    mat = DeviceSELL(
+        sds((off,), jnp.int32), sds((off,), jnp.float32), sds((pieces.size,), jnp.int32),
+        tuple(meta), n, n, g.nnz,
+    )
+    engine = make_engine(generate("web", 512, 6.0, seed=3), "sell", interpret=False)
+    compiled = _container_spmv.lower(
+        engine, mat, sds((n,), jnp.float32), jnp.dtype(jnp.float32)
+    ).compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" not in text and text.count(" gather(") == 1
+    mem = compiled.memory_analysis()
+    if mem is not None:
+        assert mem.temp_size_in_bytes < 3 * 4 * off
 
 
 # ------------------------------------------- what a TPU-mode engine dispatches
