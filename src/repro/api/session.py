@@ -20,11 +20,13 @@ Operators are cached per precision policy (storage/compute dtype pair), so
 a session serves mixed-policy query streams without rebuilding.
 
 ``eigsh`` stays the one-call entrypoint: it is now a thin wrapper over a
-small fingerprint-keyed session cache (content digest of the CSR arrays +
-the layout-affecting config fields), so naive repeated calls transparently
-hit the prepared path.  Reuse is *verified*, not assumed: results report
-the conversion and tuner-probe counts their call actually paid
-(``partition["spmv"]``) and a ``session_reuse`` provenance flag.
+small fingerprint-keyed session cache, so naive repeated calls transparently
+hit the prepared path.  The key is the content digest of the CSR arrays
+(``coerce.matrix_fingerprint``: every byte, hashed in 16 MiB chunks on a
+thread pool without copies) plus the layout-affecting config fields.  Reuse
+is *verified*, not assumed: results report the conversion and tuner-probe
+counts their call actually paid (``partition["spmv"]``) and a
+``session_reuse`` provenance flag.
 
 ``eigsh_many`` amortizes one matrix across many ``(k, policy, tol)``
 queries: queries are grouped by (backend, policy, reorth, jacobi), each

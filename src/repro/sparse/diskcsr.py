@@ -22,12 +22,13 @@ staging window at a time).  ``DiskCSR`` duck-types the cheap parts of
 callers must gate it on size.
 
 ``diskcsr_fingerprint`` is the content key for the session cache and
-``SessionStore``: hashing the full byte payload (what ``matrix_fingerprint``
-does for in-RAM CSR) would read the whole file back, so the disk fingerprint
-digests the header plus *strided sample blocks* of each array file — O(1)
-I/O regardless of matrix size, still invalidating on header change, size
-change, or content change inside any sampled block (the block stride covers
-the file ends and evenly spaced interior windows).
+``SessionStore``: hashing every byte of the payload (what
+``matrix_fingerprint`` does for in-RAM CSR, in parallel chunks) would read
+the whole file back, so the disk fingerprint digests the header plus
+*strided sample blocks* of each array file — O(1) I/O regardless of matrix
+size, still invalidating on header change, size change, or content change
+inside any sampled block (the block stride covers the file ends and evenly
+spaced interior windows).
 """
 
 from __future__ import annotations
@@ -217,10 +218,10 @@ def diskcsr_fingerprint(
 
     Digest = header bytes + per-array (file size + strided 64 KiB sample
     blocks).  Cost is O(blocks) I/O — feasible for disk-resident matrices
-    where the full-payload ``matrix_fingerprint`` hash is not.  Any header
-    or size change invalidates; content-only changes invalidate when they
-    touch a sampled window (the documented contract of a *sampled* key —
-    callers that rewrite data in place should bump the header or re-save).
+    where hashing every byte, as ``matrix_fingerprint`` does, is not.  Any
+    header or size change invalidates; content-only changes invalidate when
+    they touch a sampled window (the documented contract of a *sampled* key
+    — callers that rewrite data in place should bump the header or re-save).
     """
     if blocks is None:
         from ..configs import env as envcfg

@@ -17,7 +17,7 @@ span                           where                               stats
 =============================  ==================================  ==============================
 ``repro.eigsh``                ``api.frontend.eigsh``              ``request``, ``k``, ``policy``
 ``repro.session.get``          ``api.session.get_session``         ``hit`` (0 or 1)
-``repro.session.fingerprint``  each request-path digest            ``bytes`` hashed
+``repro.session.fingerprint``  each request-path digest            ``bytes`` hashed, ``chunks``
 ``repro.engine.restarted``     ``EigenSession._run_restarted``     ``m``, ``k``, ``max_restarts``
 ``repro.lanczos.step``         one fill step of the restarted      ``i``, ``cycle``, ``host_reads``
                                engine

@@ -1,6 +1,10 @@
 """Plan/execute split: prepared sessions, fingerprint cache, eigsh_many."""
 
+import hashlib
 import json
+import os
+import sys
+import threading
 
 import numpy as np
 import pytest
@@ -9,6 +13,7 @@ import jax
 
 from repro.api import (
     EigenResult,
+    EigenSession,
     EigQuery,
     SolverConfig,
     config_fingerprint,
@@ -19,12 +24,13 @@ from repro.api import (
     session_cache_clear,
     session_cache_info,
 )
+from repro.api import coerce
 from repro.api.session import policy_key
 from repro.core import FDF, POLICIES
 from repro.core.metrics import eigsh_reference
 from repro.kernels.engine import get_tuner, tuner_probe_count
 from repro.sparse import generate
-from repro.sparse.formats import conversion_count
+from repro.sparse.formats import CSR, conversion_count
 
 K = 4
 ITERS = 24
@@ -55,6 +61,147 @@ def test_matrix_fingerprint_tracks_content(small_csr):
     retyped = generate("web", 512, 6.0, seed=3, values="normalized")
     retyped.data = retyped.data.astype(np.float32)
     assert matrix_fingerprint(retyped) != fp
+
+
+@pytest.fixture()
+def small_chunks(monkeypatch):
+    """Hash in 256-byte chunks, so the test CSR's arrays span many."""
+    monkeypatch.setattr(coerce, "_FP_CHUNK_BYTES", 256)
+    return 256
+
+
+def _csr_copy(csr, **replace):
+    arrays = {f: getattr(csr, f).copy() for f in ("indptr", "indices", "data")}
+    arrays.update(replace)
+    return CSR(shape=tuple(csr.shape), **arrays)
+
+
+@pytest.mark.parametrize("where", ["first", "middle", "last"])
+@pytest.mark.parametrize("field", ["indptr", "indices", "data"])
+def test_fingerprint_sees_one_flipped_byte_in_any_chunk(small_csr, small_chunks, field, where):
+    fp = matrix_fingerprint(small_csr)
+    raw = getattr(small_csr, field).copy()
+    nbytes = raw.nbytes
+    chunks = -(-nbytes // small_chunks)
+    assert chunks >= 3
+    chunk = {"first": 0, "middle": chunks // 2, "last": chunks - 1}[where]
+    at = min(chunk * small_chunks + 5, nbytes - 1)
+    raw.view(np.uint8)[at] ^= 0x01
+    assert matrix_fingerprint(_csr_copy(small_csr, **{field: raw})) != fp
+    # ... and the same flip back gives the original digest
+    raw.view(np.uint8)[at] ^= 0x01
+    assert matrix_fingerprint(_csr_copy(small_csr, **{field: raw})) == fp
+
+
+@pytest.mark.parametrize("workers", [1, 2, 8])
+def test_fingerprint_is_of_content_whatever_the_threads(
+    small_csr, small_chunks, monkeypatch, workers
+):
+    """A byte-identical copy in new arrays, hashed on one thread or many,
+    gives the digest of the original hashed on the calling thread alone."""
+    monkeypatch.setattr(coerce, "_usable_cpus", lambda: 1)
+    want = matrix_fingerprint(small_csr)
+    threads = set()
+    digest = coerce._chunk_digest
+
+    def spy(chunk):
+        threads.add(threading.current_thread().name)
+        return digest(chunk)
+
+    monkeypatch.setattr(coerce, "_chunk_digest", spy)
+    monkeypatch.setattr(coerce, "_usable_cpus", lambda: workers)
+    monkeypatch.setattr(coerce, "_fp_pool", None)
+    try:
+        got = matrix_fingerprint(_csr_copy(small_csr))
+    finally:
+        if coerce._fp_pool is not None:
+            coerce._fp_pool.shutdown()
+    assert got == want and got.startswith("v2-")
+    pooled = {t for t in threads if t.startswith("repro-fingerprint")}
+    assert (len(pooled) > 0) == (workers > 1)
+    assert pooled or threads == {threading.current_thread().name}
+
+
+def test_concurrent_fingerprints_share_the_pool(small_chunks, monkeypatch):
+    """Callers on many threads at once, more than the cores, each get the
+    digest their matrix has when hashed alone on the calling thread."""
+    mats = [generate("web", 256, 4.0, seed=s, values="normalized") for s in range(6)]
+    monkeypatch.setattr(coerce, "_usable_cpus", lambda: 1)
+    want = [matrix_fingerprint(m) for m in mats]
+    assert len(set(want)) == len(mats)
+    monkeypatch.setattr(coerce, "_usable_cpus", lambda: 4)
+    monkeypatch.setattr(coerce, "_fp_pool", None)
+    callers = 3 * (os.cpu_count() or 1)
+    got = [[] for _ in range(callers)]
+
+    def work(i):
+        for r in range(5):
+            got[i].append(matrix_fingerprint(mats[(i + r) % len(mats)]))
+
+    interval = sys.getswitchinterval()
+    sys.setswitchinterval(1e-5)
+    try:
+        threads = [threading.Thread(target=work, args=(i,)) for i in range(callers)]
+        for t in threads:
+            t.start()
+        for t in threads:
+            t.join(timeout=60)
+        assert not any(t.is_alive() for t in threads)
+    finally:
+        sys.setswitchinterval(interval)
+        coerce._fp_pool.shutdown()
+    for i, digests in enumerate(got):
+        assert digests == [want[(i + r) % len(mats)] for r in range(5)]
+
+
+def test_a_pool_made_in_another_process_is_not_reused(monkeypatch):
+    """A forked child inherits the pool but not its threads, and work sent
+    to it would wait forever: the child makes a pool of its own."""
+    monkeypatch.setattr(coerce, "_fp_pool", None)
+    first = coerce._fingerprint_pool()
+    try:
+        assert coerce._fingerprint_pool() is first
+        monkeypatch.setattr(coerce, "_fp_pool_pid", -1)  # as seen from a child
+        second = coerce._fingerprint_pool()
+        assert second is not first and coerce._fp_pool_pid == os.getpid()
+        second.shutdown()
+    finally:
+        first.shutdown()
+
+
+@pytest.mark.parametrize(
+    "field, dtype",
+    [("indptr", np.float64), ("indices", np.float32), ("data", np.int64), ("dense", np.int64)],
+)
+def test_fingerprint_sees_a_dtype_change_of_the_same_bytes(small_csr, field, dtype):
+    if field == "dense":
+        dense = small_csr.toarray()
+        assert matrix_fingerprint(dense.view(dtype)) != matrix_fingerprint(dense)
+        return
+    retyped = getattr(small_csr, field).view(dtype)
+    assert matrix_fingerprint(_csr_copy(small_csr, **{field: retyped})) != (
+        matrix_fingerprint(small_csr)
+    )
+
+
+def _unversioned_digest(csr) -> str:
+    """The single-pass digest fingerprints had before they carried a version."""
+    h = hashlib.blake2b(digest_size=16)
+    h.update(b"csr")
+    for arr in (csr.indptr, csr.indices, csr.data):
+        h.update(np.ascontiguousarray(arr).tobytes())
+    h.update(repr(csr.shape).encode())
+    return h.hexdigest()
+
+
+def test_plans_exported_under_an_unversioned_digest_are_stale(small_csr):
+    cfg = SolverConfig(reorth="full", backend="single")
+    state = prepare(small_csr, config=cfg).export_state()
+    assert state["plans"] and state["matrix_fingerprint"].startswith("v2-")
+    old = dict(state, matrix_fingerprint=_unversioned_digest(small_csr))
+    with pytest.warns(UserWarning, match="stale persisted session rejected.*matrix_fingerprint"):
+        assert EigenSession(small_csr, cfg).import_plans(old) == 0
+    assert EigenSession(small_csr, cfg).import_plans(state) >= 1
 
 
 def test_config_fingerprint_normalizes_policy():

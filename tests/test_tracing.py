@@ -13,7 +13,7 @@ import numpy as np
 import pytest
 
 from repro import eigsh
-from repro.api import session_cache_clear
+from repro.api import coerce, session_cache_clear
 from repro.sparse import generate
 from repro.tracing import SPANS
 
@@ -126,7 +126,10 @@ def test_fingerprint_inside_session_get(traced, call, hit):
     (fp,) = _named(spans, "repro.session.fingerprint")
     assert fp.inside(get)
     csr = traced["csr"]
-    assert fp.stats == {"bytes": csr.indptr.nbytes + csr.indices.nbytes + csr.data.nbytes}
+    sizes = [csr.indptr.nbytes, csr.indices.nbytes, csr.data.nbytes]
+    chunks = sum(-(-b // coerce._FP_CHUNK_BYTES) for b in sizes)
+    assert chunks == 3  # each array fits in one chunk at this size
+    assert fp.stats == {"bytes": sum(sizes), "chunks": chunks}
 
 
 def test_every_documented_name_is_emitted(traced):
