@@ -1,8 +1,10 @@
-"""SpMV cost per layout on the Wikipedia matrix, and a check of the two
-Mosaic kernels against host f64.
+"""SpMV cost per layout on the Wikipedia matrix, the ``"sell"`` gather
+kernel against XLA's gather, and a check of the Mosaic kernels against host
+f64.
 
     python benchmarks/probes/spmv_wk.py                  # WK at n = 3,566,907
     python benchmarks/probes/spmv_wk.py --n 20000        # a quick small run
+    python benchmarks/probes/spmv_wk.py --matrix gap-kron-s20 --only sell-gather
 
 Generates the WK matrix as ``chip_smoke.py`` does (``generate("web", n,
 12.6, values="normalized", seed=0)``), prints its layout statistics, then
@@ -20,6 +22,14 @@ times one jitted SpMV per layout and accumulation dtype (median of
   gather  the gather and multiply of every non-zero, summed to one scalar
           (no scatter): the floor of any gather-based SpMV.
 
+``sell-gather`` times the ``"sell"`` layout's gather of a random float32
+``x`` alone, ``jnp.take(x, col)`` and the ``sell_gather`` kernel (compiled
+on a TPU, interpreted elsewhere), in ns a slot, and checks that their bits
+are equal; then the whole jitted ``"sell"`` SpMV with each gather, whose
+bits must be equal too.  ``--matrix`` takes a benchmark configuration's name
+(``bench/configs/<name>.json``, graph made by ``bench.gen`` with seed 1) in
+place of WK.
+
 On a TPU it also runs ``lanczos_update`` and ``mixed_dot`` compiled at the
 matrix's n and prints their error against NumPy f64.  One JSON line per
 measurement on standard output.
@@ -33,6 +43,7 @@ import os
 import statistics
 import sys
 import time
+from unittest import mock
 
 import numpy as np
 
@@ -90,6 +101,61 @@ def probe_spmv(csr, reps: int) -> None:
             _emit(**out)
 
 
+def probe_sell_gather(csr, reps: int) -> None:
+    import jax
+    import jax.numpy as jnp
+
+    from repro.kernels import sell_gather as sg
+    from repro.sparse.formats import to_device_sell
+
+    sell = to_device_sell(csr, dtype=jnp.float32)
+    col = sell.col
+    how = sell.gather_executor(jnp.float32)
+    x = jax.random.normal(jax.random.PRNGKey(3), (csr.n,), jnp.float32)
+    take = jax.jit(jnp.take)
+    out = {"probe": "sell-gather", "n": csr.n, "slots": int(col.shape[0]), "executor": how}
+    sec = _median_seconds(take, x, col, reps=reps)
+    out.update(take_ms=sec * 1e3, take_ns_slot=sec / col.shape[0] * 1e9)
+    if how != "xla":
+        kernel = jax.jit(lambda v, c: sg.sell_gather(v, c, interpret=how == "pallas_interpret"))
+        sec = _median_seconds(kernel, x, col, reps=reps)
+        want = np.asarray(take(x, col)).view(np.uint32)
+        got = np.asarray(kernel(x, col)).view(np.uint32)
+        out.update(kernel_ms=sec * 1e3, kernel_ns_slot=sec / col.shape[0] * 1e9,
+                   bit_equal=bool(np.array_equal(got, want)))
+        # The whole jitted SpMV, the gather kernel's against XLA's gather
+        # (no VMEM to hold x: the fallback): the sums must not move either.
+        sec, y = _jitted_spmv(sell, x, reps)
+        with mock.patch.object(sg, "vmem_capacity_bytes", lambda: 0):
+            jax.clear_caches()
+            sec_xla, y_xla = _jitted_spmv(sell, x, reps)
+        jax.clear_caches()
+        out.update(spmv_ms=sec * 1e3, spmv_xla_ms=sec_xla * 1e3,
+                   spmv_bit_equal=bool(np.array_equal(y.view(np.uint32), y_xla.view(np.uint32))))
+    _emit(**out)
+
+
+def _jitted_spmv(sell, x, reps: int):
+    import jax
+
+    fn = jax.jit(lambda m, v: m.matvec(v))
+    return _median_seconds(fn, sell, x, reps=reps), np.asarray(fn(sell, x))
+
+
+def load_matrix(name: str, n: int):
+    """WK at ``n`` rows, or a benchmark configuration's graph."""
+    from repro.sparse import CSR, generate
+
+    if name == "wk":
+        return generate("web", n, 12.6, seed=0, values="normalized")
+    sys.path.insert(0, ROOT)
+    from bench.gen import generate as gap_generate
+
+    with open(os.path.join(ROOT, "bench", "configs", f"{name}.json")) as f:
+        g = gap_generate(json.load(f), 1)
+    return CSR(g.indptr, g.indices, g.data, (g.n, g.n))
+
+
 def probe_kernels(n: int) -> None:
     import jax.numpy as jnp
 
@@ -121,24 +187,28 @@ def main(argv=None) -> int:
     ap = argparse.ArgumentParser(description=__doc__.split("\n")[0])
     ap.add_argument("--n", type=int, default=WK_N)
     ap.add_argument("--reps", type=int, default=5)
+    ap.add_argument("--matrix", default="wk",
+                    help="'wk', or a configuration under bench/configs (e.g. gap-kron-s20)")
+    ap.add_argument("--only", choices=("all", "sell-gather"), default="all")
     args = ap.parse_args(argv)
 
     sys.path.insert(0, os.path.join(ROOT, "src"))
     import jax
 
     from repro.launch.compile_cache import enable_compile_cache
-    from repro.sparse import generate
 
     enable_compile_cache()
     jax.config.update("jax_enable_x64", True)
     dev = jax.devices()[0]
     t0 = time.perf_counter()
-    csr = generate("web", args.n, 12.6, seed=0, values="normalized")
-    _emit(probe="setup", platform=dev.platform, kind=dev.device_kind, n=csr.n,
-          nnz=csr.nnz, seconds=time.perf_counter() - t0)
-    probe_spmv(csr, args.reps)
-    if dev.platform == "tpu":
-        probe_kernels(csr.n)
+    csr = load_matrix(args.matrix, args.n)
+    _emit(probe="setup", platform=dev.platform, kind=dev.device_kind, matrix=args.matrix,
+          n=csr.n, nnz=csr.nnz, seconds=time.perf_counter() - t0)
+    probe_sell_gather(csr, args.reps)
+    if args.only == "all":
+        probe_spmv(csr, args.reps)
+        if dev.platform == "tpu":
+            probe_kernels(csr.n)
     return 0
 
 

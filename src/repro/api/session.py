@@ -1235,9 +1235,9 @@ class EigenSession:
             spmv["conversions"] = prep.conversions if built else 0
             spmv["tuner_probes"] = prep.tuner_probes if built else 0
             spmv["reused"] = not built
-            mat = getattr(prep.operator, "mat", None)
-            if isinstance(mat, DeviceSELL):
-                spmv["sell"] = mat.summary()
+            gather = _sell_gather(prep, q.pol)
+            if gather is not None:
+                spmv["sell"] = {**prep.operator.mat.summary(), "gather": gather}
             # Iteration-plan provenance: what the tuner (or mode table) chose,
             # plus the update mode this query's policy actually allows — the
             # policy gate can demote a fused plan (compensated / phase splits).
@@ -1255,6 +1255,7 @@ class EigenSession:
                 eng.interpret if eng is not None else default_interpret(),
                 "unfused" if q.backend == "restarted" else effective,
                 q.pol.compute,
+                gather=gather,
             )
             # Per-phase precision audit: the phase map this solve executed and a
             # model-based count of element ops per dtype (how the "this split
@@ -1439,10 +1440,13 @@ class EigenSession:
 
     def _vmappable(self, prep: _Prepared) -> bool:
         """Is this operator's matvec safe under ``jax.vmap``?  Dense matmul,
-        the COO ``segment_sum`` path and the bucketed ``sell`` SpMV (plain
-        jnp) batch cleanly; the Pallas kernel layouts are excluded (their
-        interpret-mode batching rule is unvalidated), as is the host-loop
-        chunked operator."""
+        the COO ``segment_sum`` path and the bucketed ``sell`` SpMV batch
+        cleanly: ``sell``'s gather kernel takes the batch as a leading grid
+        axis with one start's ``x`` resident at a time, so its VMEM test
+        holds batched (``tests/test_sell_gather.py`` checks the bits).  The
+        other Pallas kernel layouts are excluded (their interpret-mode
+        batching rule is unvalidated), as is the host-loop chunked
+        operator."""
         op = prep.operator
         if isinstance(op, DenseOperator):
             return True
@@ -1536,7 +1540,14 @@ class EigenSession:
                 extra = max(0, math.floor((budget - m) / max(m - k_max, 1)))
                 max_restarts = min(max_restarts, extra + 1)
             tol_target = min(q.tol_eff for q in qs)
-            with span("repro.engine.restarted", m=m, k=k_max, max_restarts=max_restarts):
+            gather = _sell_gather(prep, pol)
+            with span(
+                "repro.engine.restarted",
+                m=m,
+                k=k_max,
+                max_restarts=max_restarts,
+                sell_gather=int(gather not in (None, "xla")),
+            ):
                 sweep = solve_restarted(
                     prep.operator,
                     k_max,
@@ -1729,6 +1740,17 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
             raise ValueError(f"unknown persisted container type {ctype!r}")
         op = SparseOperator(mat, impl="engine" if engine is not None else "coo", engine=engine)
     return _Prepared("single", op, None, plan.get("spmv_format"), engine)
+
+
+def _sell_gather(prep: _Prepared, pol) -> Optional[str]:
+    """What gathers ``x`` in the ``"sell"`` SpMV of a solve under ``pol``
+    (its Lanczos vectors, the SpMV's ``x``, are in ``pol.storage``);
+    None where the operator is not a :class:`DeviceSELL`."""
+    mat = getattr(prep.operator, "mat", None)
+    if not isinstance(mat, DeviceSELL):
+        return None
+    engine = getattr(prep.operator, "engine", None)
+    return mat.gather_executor(pol.storage, engine.interpret if engine is not None else None)
 
 
 # --------------------------------------------------------------- frontends

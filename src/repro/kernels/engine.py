@@ -33,10 +33,13 @@ A fifth, ``sell``, is the row-length-bucketed ELL of
 ``sparse.formats.DeviceSELL``: width classes each padded to their own width
 (at most 1.125 slots per non-zero, ``sell_slots`` in :class:`SpmvStats`), one
 gather, dense per-class sums and one scatter of a sum per row piece, not
-COO's scatter-add per non-zero.  It has no Pallas kernel, so selection takes
-it only where the SpMV runs as compiled XLA gathers (see below): there it has
-the fewest gathered slots of the gather layouts, and the 128-lane ELL pad, a
-Pallas ``BlockSpec`` constraint, is pure cost.
+COO's scatter-add per non-zero.  Its gather of ``x`` is the ``sell_gather``
+Mosaic kernel (``x`` resident in VMEM, bit for bit ``jnp.take``) where ``x``
+is float32 and fits VMEM, ``jnp.take`` otherwise; the sums and the scatter
+are XLA.  Selection takes it only where the other layouts' SpMV runs as
+compiled XLA gathers (see below): there it has the fewest gathered slots of
+the gather layouts, and the 128-lane ELL pad, a Pallas ``BlockSpec``
+constraint, is pure cost.
 
 Tile parameters come from the static table (``select_tiles``) by default, or
 from the **measured autotuner** (:func:`tuned_tiles`) when
@@ -66,12 +69,15 @@ entries instead of requiring a manual CI cache-key bump.
 (``spmv_ell``, ``spmv_ell_alpha``, ``spmv_ell_packed``, ``spmv_bsr``) hold
 the whole ``x`` in VMEM and gather from it: Mosaic refuses their 1-D gather,
 and the 16 MiB scoped VMEM caps a double-buffered f32 ``x`` near 2M entries.
-So compiled (TPU) execution runs the SpMV as XLA gathers, over the ``sell``
-layout where auto selection is free to pick it and over ELL / BSR / hybrid
-where a backend restricts the formats, and only the vector kernels
-(``lanczos_update``, ``mixed_dot``) run as Mosaic kernels.  Interpret mode
-still runs every kernel, which is how the CPU tests cover them.  ``partition["spmv"]
-["kernels"]`` reports the split per phase (:func:`phase_executors`).
+So compiled (TPU) execution runs the ELL / BSR / hybrid SpMV, where a backend
+restricts the formats to them, as XLA gathers.  The ``sell`` layout, which
+auto selection picks where it is free to, gathers ``x`` with the
+``sell_gather`` kernel (scalar-addressed row loads from a single-buffered
+``x`` in VMEM, limit raised to fit), and the vector kernels
+(``lanczos_update``, ``mixed_dot``) run as Mosaic kernels too.  Interpret
+mode still runs every kernel, which is how the CPU tests cover them.
+``partition["spmv"]["kernels"]`` reports the split per phase
+(:func:`phase_executors`).
 """
 
 from __future__ import annotations
@@ -559,20 +565,29 @@ def tuned_tiles(
 
 
 def spmv_runs_pallas(interpret: bool) -> bool:
-    """Whether the SpMV goes through its Pallas kernels: only under the
-    interpreter.  Compiled (TPU) SpMV runs as XLA gathers over the same
-    layouts — see the module docstring for why Mosaic cannot take them."""
+    """Whether the ELL / BSR / hybrid SpMV goes through its Pallas kernels:
+    only under the interpreter.  Compiled (TPU), those layouts' SpMV runs as
+    XLA gathers — see the module docstring for why Mosaic cannot take them.
+    ``"sell"`` is apart: its gather of ``x`` is the ``sell_gather`` kernel,
+    compiled or interpreted, wherever ``x`` qualifies
+    (``DeviceSELL.gather_executor``)."""
     return interpret
 
 
-def phase_executors(fmt: str, interpret: bool, update: str, compute_dtype) -> dict:
+def phase_executors(
+    fmt: str, interpret: bool, update: str, compute_dtype, gather: Optional[str] = None
+) -> dict:
     """What runs each per-iteration phase: ``"mosaic"`` (a compiled Pallas
     kernel), ``"pallas_interpret"`` or ``"xla"``.  ``fmt`` is the SpMV format
-    ("coo" also stands for dense and matrix-free operators; it and "sell"
-    have no kernel), ``update`` the effective update mode; f64 compute keeps
-    the jnp update."""
+    ("coo" also stands for dense and matrix-free operators, and has no
+    kernel), ``update`` the effective update mode; f64 compute keeps the jnp
+    update.  ``"sell"``'s SpMV reports ``gather``, what gathered its ``x``
+    (``DeviceSELL.gather_executor``; ``"xla"`` when not given)."""
     pallas = "pallas_interpret" if interpret else "mosaic"
-    spmv = pallas if fmt not in ("coo", "sell") and spmv_runs_pallas(interpret) else "xla"
+    if fmt == "sell":
+        spmv = gather or "xla"
+    else:
+        spmv = pallas if fmt != "coo" and spmv_runs_pallas(interpret) else "xla"
     fused = update != "unfused" and jnp.dtype(compute_dtype) != jnp.dtype(jnp.float64)
     return {"spmv": spmv, "update": pallas if fused else "xla"}
 
@@ -834,11 +849,13 @@ class SpmvStats:
         triplet.  ELL pads every row to the longest (128-lane aligned),
         hybrid to its capped width (8-slot aligned) plus the tail (counted at
         the unaligned cap: an upper bound), BSR every block-row to the
-        fullest one; sell each row piece to its class width, plus an int32
-        row index per piece."""
+        fullest one; sell each row piece to its class width and the slots
+        to a multiple of 128, plus an int32 row index per piece."""
         vb = int(value_bytes)
         if fmt == "sell":
-            return self.sell_slots * (vb + 4) + 4 * self.sell_pieces
+            from ..sparse.formats import sell_stored_slots  # lazy: sparse sits below kernels
+
+            return sell_stored_slots(self.sell_slots) * (vb + 4) + 4 * self.sell_pieces
         if fmt == "ell":
             width = -(-max(1, self.max_row_nnz) // 128) * 128
             return width * self.n_rows * (vb + 4)
@@ -1085,8 +1102,8 @@ class SpmvEngine:
     Frozen and hashable so it can ride through ``jax.jit`` static arguments.
     ``interpret`` selects the Pallas interpreter (CPU containers) vs compiled
     execution (real TPU).  Compiled, the SpMV runs as XLA gathers over the
-    layout the format names, ``sell`` under auto selection — see
-    :func:`spmv_runs_pallas`.
+    layout the format names, or, over ``sell`` (auto selection's pick), as
+    the ``sell_gather`` kernel and XLA sums — see :func:`spmv_runs_pallas`.
     """
 
     format: str = "auto"
@@ -1239,7 +1256,9 @@ class SpmvEngine:
 def _container_spmv(engine: SpmvEngine, mat, x: jax.Array, acc) -> jax.Array:
     from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid, DeviceSELL
 
-    if isinstance(mat, (DeviceCOO, DeviceSELL)):
+    if isinstance(mat, DeviceSELL):
+        return mat.matvec(x, accum_dtype=acc, interpret=engine.interpret)
+    if isinstance(mat, DeviceCOO):
         return mat.matvec(x, accum_dtype=acc)
     eng = engine if acc == engine.accum_dtype else dataclasses.replace(engine, accum_dtype=acc)
     if isinstance(mat, DeviceELL):

@@ -14,8 +14,9 @@ Host-side construction is NumPy (CSR); device-side compute formats are:
 * ``DeviceSELL`` — row-length-bucketed ELL: rows cut into pieces of at most
   ``ROW_BLOCK`` entries, sorted by length into width classes, each padded
   only to its own width and stored slot-major in flat 1-D arrays; the SpMV
-  is one gather, dense per-class sums and one scatter of one sum per piece
-  (the compiled layout, see ``kernels/engine.py``).
+  is one gather (the ``sell_gather`` Mosaic kernel where ``x`` fits VMEM),
+  dense per-class sums and one scatter of one sum per piece (the compiled
+  layout, see ``kernels/engine.py``).
 
 All device containers are registered pytrees so they can cross ``jit`` /
 ``shard_map`` boundaries.  The ``shard_to_*`` converters build *shard-local*
@@ -47,6 +48,7 @@ __all__ = [
     "to_device_hybrid",
     "to_device_sell",
     "sell_classes",
+    "sell_stored_slots",
     "row_sums",
     "ell_padding_stats",
     "blocked_ell_from_triplets",
@@ -369,6 +371,14 @@ def to_device_hybrid(
 # times the one below, so no piece is padded by more than 12.5%.
 SELL_EXACT = 16
 SELL_GROWTH = 1.125
+# The flat slot arrays are padded to a multiple of this (the gather kernel's
+# lane-dense rows of 128 slots); the classes' slots come first.
+SELL_SLOT_ALIGN = 128
+
+
+def sell_stored_slots(slots: int) -> int:
+    """Length of the flat arrays holding ``slots`` class slots."""
+    return -(-int(slots) // SELL_SLOT_ALIGN) * SELL_SLOT_ALIGN
 
 
 def sell_classes(
@@ -413,8 +423,10 @@ class DeviceSELL:
     to ``width`` slots and stored slot-major from ``offset`` on: slot ``s``
     of its piece ``p`` at ``offset + s * rows + p`` of the flat ``col`` /
     ``val``, so a class sums across its pieces, 128 to a vector register.
-    Padding slots have ``val == 0`` and ``col == 0``.  ``order`` holds each
-    piece's row; a row longer than ``ROW_BLOCK`` has several.  Every array
+    Padding slots have ``val == 0`` and ``col == 0``; they include the tail
+    that pads the flat arrays to a multiple of ``SELL_SLOT_ALIGN`` after the
+    last class.  ``order`` holds each piece's row; a row longer than
+    ``ROW_BLOCK`` has several.  Every array
     the SpMV reads is 1-D: a ``(rows, width)`` array would be laid out 128
     lanes wide on a TPU and bring the padding back.  The cut bounds every
     class's width by ``ROW_BLOCK``: with whole hub rows as classes (up to
@@ -422,8 +434,8 @@ class DeviceSELL:
     matrix's SpMV in ten minutes.
     """
 
-    col: jax.Array  # (slots,) int32
-    val: jax.Array  # (slots,) storage dtype
+    col: jax.Array  # (sell_stored_slots(slots),) int32
+    val: jax.Array  # (sell_stored_slots(slots),) storage dtype
     order: jax.Array  # (pieces,) int32: the row each stored piece belongs to
     classes: Tuple[Tuple[int, int, int], ...]  # static (width, pieces, offset)
     n_rows: int  # static
@@ -439,7 +451,8 @@ class DeviceSELL:
 
     @property
     def slots(self) -> int:
-        return int(self.col.shape[0])
+        """The classes' slots (the aligned tail is not counted)."""
+        return sum(w * r for w, r, _ in self.classes)
 
     def summary(self) -> dict:
         """Classes, slots and padding of the built layout."""
@@ -449,15 +462,37 @@ class DeviceSELL:
             "slots_per_nnz": self.slots / max(1, self.nnz),
         }
 
-    def matvec(self, x: jax.Array, accum_dtype=None) -> jax.Array:
+    def gather_executor(self, x_dtype, interpret: Optional[bool] = None) -> str:
+        """What gathers ``x[col]`` for an ``x`` of ``x_dtype``: ``"mosaic"``
+        or ``"pallas_interpret"`` (the ``sell_gather`` kernel, where ``x``
+        is 32-bit float and fits VMEM) or ``"xla"`` (``jnp.take``).
+        ``interpret`` None means the default backend's mode."""
+        from ..kernels import sell_gather  # lazy: sparse sits below kernels
+        from ..kernels.ops import default_interpret
+
+        interp = default_interpret() if interpret is None else interpret
+        return sell_gather.executor(self.n_cols, x_dtype, int(self.col.shape[0]), interp)
+
+    def matvec(
+        self, x: jax.Array, accum_dtype=None, interpret: Optional[bool] = None
+    ) -> jax.Array:
         """One gather over all slots, a dense sum per class, and one
         scatter-add of the pieces' sums into their rows: a row longer than
-        ``ROW_BLOCK`` adds up its pieces' partials, as ``row_sums`` does."""
+        ``ROW_BLOCK`` adds up its pieces' partials, as ``row_sums`` does.
+        The gather is the ``sell_gather`` kernel or ``jnp.take``, as
+        :meth:`gather_executor` decides: the same bits either way."""
         acc = accum_dtype or self.val.dtype
         y = jnp.zeros((self.n_rows,), acc)
         if not self.classes:
             return y
-        prod = self.val.astype(acc) * jnp.take(x, self.col).astype(acc)
+        how = self.gather_executor(x.dtype, interpret)
+        if how == "xla":
+            xg = jnp.take(x, self.col)
+        else:
+            from ..kernels.sell_gather import sell_gather
+
+            xg = sell_gather(x, self.col, interpret=how == "pallas_interpret")
+        prod = self.val.astype(acc) * xg.astype(acc)
         # The barrier keeps each class's reshape on its own slice: left free,
         # the TPU compiler rewrote a two-piece class's slice-and-reshape as a
         # reshape of all the products to (slots / 2, 2), 64 lanes of padding
@@ -477,7 +512,7 @@ def to_device_sell(csr: CSR, dtype=jnp.float32) -> DeviceSELL:
     count_conversions()
     row_nnz = csr.row_nnz()
     row, first, classes = sell_classes(row_nnz)
-    slots = sum(w * r for w, r in classes)
+    slots = sell_stored_slots(sum(w * r for w, r in classes))
     col = np.zeros((slots,), dtype=np.int32)
     val = np.zeros((slots,), dtype=jnp.dtype(dtype))
     meta = []

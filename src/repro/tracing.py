@@ -18,7 +18,11 @@ span                           where                               stats
 ``repro.eigsh``                ``api.frontend.eigsh``              ``request``, ``k``, ``policy``
 ``repro.session.get``          ``api.session.get_session``         ``hit`` (0 or 1)
 ``repro.session.fingerprint``  each request-path digest            ``bytes`` hashed, ``chunks``
-``repro.engine.restarted``     ``EigenSession._run_restarted``     ``m``, ``k``, ``max_restarts``
+``repro.engine.restarted``     ``EigenSession._run_restarted``     ``m``, ``k``, ``max_restarts``,
+                                                                   ``sell_gather`` (1: the
+                                                                   ``"sell"`` SpMV gathers ``x``
+                                                                   with its Mosaic kernel, 0:
+                                                                   XLA or another layout)
 ``repro.lanczos.step``         one fill step of the restarted      ``i``, ``cycle``, ``host_reads``
                                engine
 ``repro.restarted.jacobi``     the host Jacobi of ``T_hat``        ``m``

@@ -715,6 +715,17 @@ def test_phase_executors_name_the_mosaic_xla_split(fmt, interpret, update, compu
     assert phase_executors(fmt, interpret, update, compute) == want
 
 
+@pytest.mark.parametrize("interpret", [False, True])
+@pytest.mark.parametrize("gather", ["mosaic", "pallas_interpret", "xla", None])
+def test_phase_executors_report_what_gathered_sells_x(interpret, gather):
+    """``"sell"``'s SpMV is what gathered its ``x`` (its kernel or XLA's
+    gather), whatever the mode; not given, it reads ``"xla"``."""
+    from repro.kernels.engine import phase_executors
+
+    got = phase_executors("sell", interpret, "unfused", jnp.float32, gather=gather)
+    assert got == {"spmv": gather or "xla", "update": "xla"}
+
+
 @pytest.mark.parametrize(
     "pin, tol, want_update",
     [("unfused", None, "xla"), ("fused", None, "pallas_interpret"), ("fused", 1e-4, "xla")],

@@ -11,7 +11,8 @@ loads the TPU compiler.
 
 The last tests trace (no compile) a TPU-mode engine — ``interpret=False`` —
 through every format and update mode, and check that it dispatches only the
-kernels Mosaic accepts (``lanczos_update``, ``mixed_dot``): the SpMV kernels
+kernels Mosaic accepts (``lanczos_update``, ``mixed_dot``, and
+``sell_gather`` for the ``"sell"`` layout): the ELL / BSR SpMV kernels
 gather from a VMEM-resident ``x`` and run only under the interpreter.
 """
 
@@ -31,7 +32,13 @@ from repro.core.precision import FFF
 from repro.kernels import ops as kops
 from repro.kernels.engine import FORMATS, ITER_UPDATE_MODES, make_engine
 from repro.sparse import generate
-from repro.sparse.formats import DeviceHybrid, DeviceSELL, sell_classes, to_device_ell
+from repro.sparse.formats import (
+    DeviceHybrid,
+    DeviceSELL,
+    sell_classes,
+    sell_stored_slots,
+    to_device_ell,
+)
 
 WK_N = 3_566_907
 # The generated WK matrix (29,079,360 non-zeros) in its hybrid layout: the
@@ -41,7 +48,7 @@ WK_N = 3_566_907
 WK_HYB_WIDTH = 8
 WK_TAIL_NNZ = 14_723_974
 # What the chip path may dispatch as a Pallas kernel.
-MOSAIC_KERNELS = {"lanczos_update", "mixed_dot"}
+MOSAIC_KERNELS = {"lanczos_update", "mixed_dot", "sell_gather"}
 
 
 @pytest.fixture(scope="module")
@@ -125,9 +132,10 @@ def test_lanczos_sweep_compiles_at_wk_width(one_chip):
 @pytest.mark.parametrize("config", ["gap-kron-s20", "gap-urand-s20"])
 def test_sell_spmv_compiles_at_cell_scale(one_chip, config):
     """The compiled SpMV over the bucketed layout of a benchmark cell's
-    matrix (2^20 rows, ~33M slots): one XLA gather over every slot, no
-    Mosaic kernel, and temporaries of about two copies of the products (a
-    compiler rewrite that pads a class to 128 lanes shows as gigabytes)."""
+    matrix (2^20 rows, ~33M slots): one ``sell_gather`` Mosaic kernel over
+    every slot in place of XLA's gather, and temporaries of about two
+    copies of the products (a compiler rewrite that pads a class to 128
+    lanes shows as gigabytes)."""
     import json
     import sys
 
@@ -150,8 +158,9 @@ def test_sell_spmv_compiles_at_cell_scale(one_chip, config):
     def sds(shape, dtype):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
+    stored = sell_stored_slots(off)
     mat = DeviceSELL(
-        sds((off,), jnp.int32), sds((off,), jnp.float32), sds((pieces.size,), jnp.int32),
+        sds((stored,), jnp.int32), sds((stored,), jnp.float32), sds((pieces.size,), jnp.int32),
         tuple(meta), n, n, g.nnz,
     )
     engine = make_engine(generate("web", 512, 6.0, seed=3), "sell", interpret=False)
@@ -159,10 +168,69 @@ def test_sell_spmv_compiles_at_cell_scale(one_chip, config):
         engine, mat, sds((n,), jnp.float32), jnp.dtype(jnp.float32)
     ).compile()
     text = compiled.as_text()
-    assert "tpu_custom_call" not in text and text.count(" gather(") == 1
+    assert text.count("tpu_custom_call") == 1 and "sell_gather" in text
+    assert text.count(" gather(") == 0
     mem = compiled.memory_analysis()
     if mem is not None:
         assert mem.temp_size_in_bytes < 3 * 4 * off
+
+
+@pytest.mark.parametrize("n", [WK_N, 1 << 24], ids=["wk", "scale24"])
+def test_sell_gather_compiles_where_it_is_chosen(one_chip, n):
+    """The gather kernel at WK's x (14.3 MB) and at the largest x the
+    VMEM test admits on a v5e (scale 24, 64 MiB): Mosaic takes both."""
+    from repro.kernels import sell_gather as sg
+
+    slots = 128 * 4096
+    assert sg.executor(n, jnp.float32, slots, interpret=False) == "mosaic"
+    _compile(
+        lambda x, c: sg.sell_gather(x, c, interpret=False),
+        jax.ShapeDtypeStruct((n,), jnp.float32, sharding=one_chip),
+        jax.ShapeDtypeStruct((slots,), jnp.int32, sharding=one_chip),
+    )
+
+
+def test_sell_sums_compile_alike_with_either_gather(one_chip, monkeypatch):
+    """Compiled for the chip, the ``"sell"`` SpMV with the gather kernel and
+    with XLA's gather differ only up to the products: every slice, reshape,
+    class sum and the scatter-add have the same shapes and layouts, so the
+    kernel's bits (equal to ``jnp.take``'s) give the same sums."""
+    import re
+    from collections import Counter
+
+    from repro.kernels import sell_gather as sg
+    from repro.kernels.engine import _container_spmv
+    from repro.sparse.formats import to_device_sell
+
+    csr = generate("web", 4096, 12.0, seed=5, values="normalized")
+    mat = to_device_sell(csr, dtype=jnp.float32)
+    engine = make_engine(csr, "sell", interpret=False)
+
+    def sds(a):
+        return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one_chip)
+
+    def after_products():
+        text = _container_spmv.lower(
+            engine, jax.tree_util.tree_map(sds, mat), sds(jnp.zeros(csr.n, jnp.float32)),
+            jnp.dtype(jnp.float32),
+        ).compile().as_text()
+        ops = []
+        whole = f"[{mat.col.shape[0]}]"  # the gather's and the products' arrays
+        for line in text.splitlines():
+            if not re.search(r" (reduce|reshape|concatenate)\(|slice=|scatter", line):
+                continue
+            if whole not in line.split(" = ", 1)[-1].split("(")[0]:
+                line = re.sub(r", metadata=\{.*", "", line)
+                ops.append(re.sub(r"%[\w.\-]+", "%", line.strip()))
+        return text, Counter(ops)
+
+    kernel_text, kernel = after_products()
+    monkeypatch.setattr(sg, "vmem_capacity_bytes", lambda: 0)
+    jax.clear_caches()
+    xla_text, xla = after_products()
+    assert "sell_gather" in kernel_text and "sell_gather" not in xla_text
+    assert any(" reduce(" in op for op in kernel) and any("scatter" in op for op in kernel)
+    assert kernel == xla
 
 
 # ------------------------------------------- what a TPU-mode engine dispatches
@@ -190,6 +258,8 @@ def test_tpu_engine_dispatches_only_mosaic_kernels(fmt, update, monkeypatch):
     assert not any(interp for _, interp in found), found
     if update != "unfused":  # the compiled update kernel is really used
         assert ("lanczos_update", False) in found
+    # the "sell" SpMV gathers its float32 x with the compiled kernel
+    assert (("sell_gather", False) in found) == (fmt == "sell")
 
 
 def test_tpu_engine_spmv_layouts_run_as_xla():

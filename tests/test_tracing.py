@@ -100,7 +100,8 @@ def test_lanczos_steps_nest_in_the_engine(traced, call):
     spans = traced["per_call"][call]
     (engine,) = _named(spans, "repro.engine.restarted")
     restarts = (traced["num_iters"] - M) // (M - K)
-    assert engine.stats == {"m": M, "k": K, "max_restarts": restarts + 1}
+    # the auto layout under the interpreter is not "sell": no gather kernel
+    assert engine.stats == {"m": M, "k": K, "max_restarts": restarts + 1, "sell_gather": 0}
     steps = _named(spans, "repro.lanczos.step")
     assert len(steps) == traced["num_iters"] == traced["results"][call].iterations
     assert all(s.inside(engine) for s in steps)
@@ -142,3 +143,29 @@ def test_tracing_leaves_the_answer_unchanged(traced, call):
     np.testing.assert_array_equal(np.asarray(got.eigenvalues), np.asarray(want.eigenvalues))
     np.testing.assert_array_equal(np.asarray(got.eigenvectors), np.asarray(want.eigenvectors))
     np.testing.assert_array_equal(got.residuals, want.residuals)
+
+
+@pytest.mark.parametrize(
+    "policy, over_budget, want",
+    [("FFF", False, 1), ("DDD", False, 0), ("FFF", True, 0)],
+    ids=["kernel", "float64_x", "x_over_vmem"],
+)
+def test_restarted_span_reports_the_sell_gather(policy, over_budget, want, tmp_path, monkeypatch):
+    """``sell_gather`` reads 1 where the ``"sell"`` SpMV gathers a float32
+    ``x`` with its kernel, 0 where ``x`` is float64 or over the VMEM budget
+    (the fallback to ``jnp.take``), as ``partition["spmv"]`` reports."""
+    from repro.kernels import sell_gather
+
+    if over_budget:
+        monkeypatch.setattr(sell_gather, "VMEM_MARGIN_BYTES", sell_gather.vmem_capacity_bytes())
+        jax.clear_caches()
+    csr = generate("web", 512, 8.0, seed=13, values="normalized")
+    session_cache_clear()
+    with jax.profiler.trace(str(tmp_path)):
+        res = eigsh(csr, 4, policy=policy, subspace=12, num_iters=12, backend="restarted",
+                    format="sell")
+    session_cache_clear()
+    (path,) = list(tmp_path.rglob("*.xplane.pb"))
+    (engine,) = _named(_host_spans(str(path)), "repro.engine.restarted")
+    assert engine.stats["sell_gather"] == want
+    assert (res.partition["spmv"]["sell"]["gather"] != "xla") == bool(want)
