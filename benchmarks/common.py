@@ -32,7 +32,7 @@ def timeit(fn, repeats=3, warmup=1):
 
 # When capture is enabled (benchmarks.run --smoke), every emit() lands here as
 # name -> us_per_call so the run can be written to a comparable JSON artifact.
-# emit_plan() records routing decisions (autotuner winners, auto-format picks)
+# emit_plan() records routing decisions (update mode, auto-format picks)
 # alongside: compare.py's --pair gates use them to tell "the fused path lost
 # AND we shipped it" apart from "the fused path lost and the plan routed
 # around it".

@@ -22,8 +22,8 @@ Three independent checks, one exit code:
   unfused path" unlandable even when both moved together (the baseline gate
   compares each metric only to its own past).  A pair is *escaped* when the
   run's recorded decision plan for the metrics' common prefix selected
-  something other than A's leaf — e.g. the whole-iteration autotuner chose
-  the unfused update, so fused losing is the measured, routed-around truth,
+  something other than A's leaf — e.g. the execution mode runs the unfused
+  update, so fused losing is the measured, routed-around truth,
   not a shipped regression.  No recorded plan means no escape.
 
 * **Trend watch** (``--trend history.jsonl``) — warn-only: flags metrics

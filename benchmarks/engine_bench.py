@@ -4,8 +4,8 @@ One section per matrix family (banded road lattice, power-law web, block
 diagonal): times the COO / ELL / BSR / hybrid execution paths through the
 engine on the same matrix and reports which format ``format="auto"`` picks.
 A trailing section times one fused Lanczos update step (Pallas kernel) vs
-the unfused three-op reference.  Interpret mode on CPU — absolute numbers
-are CPU wall time of the kernel interpreter, useful as a regression
+the unfused three-op reference.  On the CPU absolute numbers are CPU wall
+time (the update kernel runs in the interpreter), useful as a regression
 trajectory, not as TPU projections (those live in kernels_bench.py).
 """
 
@@ -74,7 +74,6 @@ def run(scale: float = 1.0):
         rows.append(case)
     rows.append(_chunked_staging(scale))
     rows.append(_lanczos_step(scale))
-    rows.append(_lanczos_iteration(scale))
     rows.append(_serving_amortization(scale))
     rows.append(_serving_scheduler(scale))
     rows.append(_precision_policies(scale))
@@ -133,8 +132,11 @@ def _chunked_staging(scale: float) -> dict:
 
 def _lanczos_step(scale: float) -> dict:
     """Fused three-term recurrence + norm (one memory pass) vs the unfused
-    reference (update then separate dot) — the core/lanczos.py hot step."""
+    reference (update then separate dot) — the core/lanczos.py hot step.
+    The emitted plan is the update mode the engine runs in this execution
+    mode, which arms/escapes the ``fused:unfused`` pair gate."""
     from repro.kernels import ops as kops
+    from repro.kernels.engine import table_update_mode
 
     n = max(4096, int((1 << 16) * scale))
     rng = np.random.default_rng(0)
@@ -160,59 +162,13 @@ def _lanczos_step(scale: float) -> dict:
     t_u = timeit(unfused)
     emit("engine/lanczos_step/fused", t_f * 1e6, f"n={n} fused Pallas update+norm")
     emit("engine/lanczos_step/unfused", t_u * 1e6, f"n={n} separate ops reference")
+    emit_plan("engine/lanczos_step", table_update_mode(kops.default_interpret()),
+              "update mode of the execution mode")
     return {
         "matrix": "lanczos_step",
         "n": n,
         "t_fused_us": t_f * 1e6,
         "t_unfused_us": t_u * 1e6,
-    }
-
-
-def _lanczos_iteration(scale: float) -> dict:
-    """Whole-iteration probe, end to end: a short Lanczos sweep with the
-    update pinned to each plan rung (unfused reference vs the fully-fused
-    SpMV+alpha / update+norm two-pass path), on a real ELL-backed operator.
-    These are the quantities the whole-iteration autotuner decides between;
-    the emitted plan is the engine's *actual* measured (or table) decision,
-    which arms/escapes the ``fused_iter:unfused_iter`` pair gate."""
-    from repro.core.lanczos import lanczos_tridiag, make_local_ops
-    from repro.core.operators import make_operator
-    from repro.core.precision import FFF
-    from repro.kernels.engine import IterationPlan, make_engine
-    from repro.sparse import generate
-
-    n = max(256, int(1024 * scale))
-    csr = generate("web", n, 6.0, seed=3, values="normalized")
-    engine = make_engine(csr, "ell", accum_dtype=jnp.float32)
-    op = make_operator(csr, dtype=jnp.float32, engine=engine)
-    pol = FFF.effective()
-    iters = 8
-    v1 = jnp.ones((csr.n,), jnp.float64)
-
-    def sweep(update):
-        plan = IterationPlan(update=update, tiles=engine.tiles, source="override")
-        ops = make_local_ops(op.bound_matvec(pol), pol, plan=plan, operator=op)
-        return lambda: lanczos_tridiag(
-            None, v1, iters, pol, reorth="none", ops=ops
-        ).alpha.block_until_ready()
-
-    t_u = timeit(sweep("unfused"))
-    t_f = timeit(sweep("fused_spmv"))
-    emit("engine/lanczos_step/unfused_iter", t_u * 1e6,
-         f"n={csr.n} m={iters} matvec+dot+update reference sweep")
-    emit("engine/lanczos_step/fused_iter", t_f * 1e6,
-         f"n={csr.n} m={iters} fused spmv+alpha / update+norm sweep")
-    plan = engine.iteration_plan
-    selected = {"fused_spmv": "fused_iter"}.get(plan.update, plan.update)
-    emit_plan("engine/lanczos_step", selected,
-              f"iteration plan source={plan.source}")
-    return {
-        "matrix": "lanczos_iteration",
-        "n": csr.n,
-        "iters": iters,
-        "t_fused_iter_us": t_f * 1e6,
-        "t_unfused_iter_us": t_u * 1e6,
-        "plan": plan.as_dict(),
     }
 
 
@@ -408,9 +364,7 @@ def _precision_policies(scale: float) -> dict:
 def _robustness(scale: float) -> dict:
     """Cost of the numerical-health layer.  Three quantities: the health
     probe (a host scan of the m-sized tridiagonal scalars, run once per
-    sweep — its per-iteration amortization is what the CI pair gate holds
-    under 2% of one whole unfused Lanczos iteration, so "the probe is free"
-    stays a measured claim), ``recovery="auto"`` on a clean solve vs
+    sweep, and its per-iteration amortization), ``recovery="auto"`` on a clean solve vs
     ``recovery="none"`` (the no-fault overhead of the recovery wrapper), and
     one injected mid-sweep NaN recovered end to end (what surviving a
     breakdown actually costs: the poisoned sweep + one rung-up re-solve)."""
@@ -432,7 +386,7 @@ def _robustness(scale: float) -> dict:
     emit("engine/health_probe", t_probe * 1e6,
          f"m={iters} tridiag health scan, absolute (1 probe per sweep)")
     emit("engine/health_probe_per_iter", t_probe / iters * 1e6,
-         f"probe/m: per-iteration amortization (gated <2% of unfused_iter)")
+         f"probe/m: per-iteration amortization")
 
     session_cache_clear()
     sess = prepare(csr, reorth="full", backend="single")

@@ -69,12 +69,12 @@ def probe_spmv(csr, reps: int) -> None:
     import jax
     import jax.numpy as jnp
 
-    from repro.kernels.engine import choose_format, matrix_stats, spmv_runs_pallas
+    from repro.kernels.engine import choose_format, matrix_stats
     from repro.kernels.ops import default_interpret
     from repro.sparse.formats import to_device_coo, to_device_hybrid, to_device_sell
 
     stats = matrix_stats(csr)
-    compiled = not spmv_runs_pallas(default_interpret())
+    compiled = not default_interpret()
     _emit(probe="stats", auto_format=choose_format(stats, compiled=compiled), **stats.as_dict())
     coo = to_device_coo(csr, dtype=jnp.float32)
     hyb = to_device_hybrid(csr, dtype=jnp.float32, width_cap=stats.hyb_width)

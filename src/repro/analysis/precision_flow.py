@@ -32,9 +32,7 @@ state for the rest of the process.
 
 from __future__ import annotations
 
-import contextlib
-import os
-from typing import Dict, Iterable, List, Optional, Tuple
+from typing import Dict, Iterable, List, Tuple
 
 import numpy as np
 
@@ -170,26 +168,6 @@ def find_phase_leaks(
 # ------------------------------------------------------------ trace builders
 
 
-@contextlib.contextmanager
-def _pin_update_mode(mode: Optional[str]):
-    """Pin REPRO_ITER_UPDATE for the duration of a trace build (the same
-    knob the engines honor, so the pinned mode is the executed mode)."""
-    if mode is None:
-        yield
-        return
-    from ..configs import env as envcfg
-
-    old = envcfg.raw("REPRO_ITER_UPDATE")
-    os.environ["REPRO_ITER_UPDATE"] = mode
-    try:
-        yield
-    finally:
-        if old is None:
-            os.environ.pop("REPRO_ITER_UPDATE", None)
-        else:
-            os.environ["REPRO_ITER_UPDATE"] = old
-
-
 def _fixture(policy: PrecisionPolicy, n: int, seed: int = 3):
     """Synthetic near-uniform problem + ELL engine + operator for tracing.
 
@@ -243,12 +221,13 @@ def _trace_ritz(policy: PrecisionPolicy, *, n: int, m: int, k: int, jacobi: str)
     return traces
 
 
-def _single_traces(policy, *, n, m, reorth, op, chunked: bool = False):
-    """(phase jaxprs, full-loop jaxpr, n_model, nnz_exec) for the in-core
-    single-device loop (also the chunked engine's loop, eager/unrolled)."""
+def _single_traces(policy, *, n, m, reorth, op, chunked: bool = False, fused=None):
+    """(phase jaxprs, full-loop jaxpr) for the in-core single-device loop
+    (also the chunked engine's loop, eager/unrolled); ``fused`` pins the
+    update mode."""
     pol = policy.effective()
     sdt, cdt = pol.storage, pol.compute
-    ops = ops_for_operator(op, pol)
+    ops = ops_for_operator(op, pol, fused=fused)
     mv = op.bound_matvec(pol)
     phases = {
         "spmv": make_jaxpr_of(lambda v: ops.matvec(v), abstract((n,), sdt)),
@@ -302,7 +281,7 @@ def _restarted_traces(policy, *, n, m, reorth, op):
     return phases, step_jaxpr
 
 
-def _distributed_traces(policy, *, n, m, reorth, csr, fmt="ell"):
+def _distributed_traces(policy, *, n, m, reorth, csr, fmt="ell", fused=None):
     """Phase + full traces through the real shard_map program (1-device mesh)."""
     from jax.sharding import Mesh
 
@@ -314,7 +293,7 @@ def _distributed_traces(policy, *, n, m, reorth, csr, fmt="ell"):
     n_pad = ps.pm.n_pad
     axis = "data"
     local = tuple(mat[0] for mat in ps.mats)
-    ops = _make_sharded_ops(local, n_pad, pol, axis, engine=ps.engine)
+    ops = _make_sharded_ops(local, n_pad, pol, axis, engine=ps.engine, fused=fused)
     env = [(axis, 1)]
     phases = {
         "spmv": jax.make_jaxpr(lambda v: ops.matvec(v), axis_env=env)(
@@ -333,7 +312,7 @@ def _distributed_traces(policy, *, n, m, reorth, csr, fmt="ell"):
     full = make_jaxpr_of(
         lambda v: sharded_lanczos(
             ps.pm, v, m, pol, mesh, reorth=reorth, axis=axis,
-            engine=ps.engine, mats=ps.mats,
+            engine=ps.engine, mats=ps.mats, fused=fused,
         ),
         abstract((1, n_pad), cdt),
     )
@@ -366,40 +345,38 @@ def _build_traces(
 ):
     """All jaxprs + parity-model inputs for one (policy, engine, mode)."""
     pol = policy.effective()
-    mode = "fused" if fused else "unfused"
-    with _pin_update_mode(mode):
-        csr, _, op = _fixture(pol, n)
-        n = csr.n  # 'road' rounds n up to a grid square
-        if engine == "distributed":
-            phases, full, n_model, nnz_exec = _distributed_traces(
-                pol, n=n, m=m, reorth=reorth, csr=csr
-            )
-            step_scale = 1
-        elif engine == "restarted":
-            phases, full = _restarted_traces(pol, n=n, m=m, reorth=reorth, op=op)
-            n_model = n
-            nnz_exec = _executed_nnz(op, csr.nnz)
-            step_scale = m  # host loop: one traced step x m fill iterations
-        elif engine == "chunked":
-            from ..core.operators import ChunkedOperator
+    csr, _, op = _fixture(pol, n)
+    n = csr.n  # 'road' rounds n up to a grid square
+    if engine == "distributed":
+        phases, full, n_model, nnz_exec = _distributed_traces(
+            pol, n=n, m=m, reorth=reorth, csr=csr, fused=fused
+        )
+        step_scale = 1
+    elif engine == "restarted":
+        phases, full = _restarted_traces(pol, n=n, m=m, reorth=reorth, op=op)
+        n_model = n
+        nnz_exec = _executed_nnz(op, csr.nnz)
+        step_scale = m  # host loop: one traced step x m fill iterations
+    elif engine == "chunked":
+        from ..core.operators import ChunkedOperator
 
-            # Two chunks: exercises the streaming loop; chunks are padded to
-            # chunk_nnz, so executed nnz is the padded total.
-            chunk_nnz = max(1, (csr.nnz + 1) // 2)
-            op = ChunkedOperator(csr, chunk_nnz=chunk_nnz, dtype=pol.storage)
-            phases, full = _single_traces(
-                pol, n=n, m=m, reorth=reorth, op=op, chunked=True
-            )
-            n_model = n
-            nnz_exec = op.num_chunks * chunk_nnz
-            step_scale = 1
-        elif engine == "single":
-            phases, full = _single_traces(pol, n=n, m=m, reorth=reorth, op=op)
-            n_model = n
-            nnz_exec = _executed_nnz(op, csr.nnz)
-            step_scale = 1
-        else:
-            raise ValueError(f"unknown engine {engine!r}; expected one of {ENGINES}")
+        # Two chunks: exercises the streaming loop; chunks are padded to
+        # chunk_nnz, so executed nnz is the padded total.
+        chunk_nnz = max(1, (csr.nnz + 1) // 2)
+        op = ChunkedOperator(csr, chunk_nnz=chunk_nnz, dtype=pol.storage)
+        phases, full = _single_traces(
+            pol, n=n, m=m, reorth=reorth, op=op, chunked=True, fused=fused
+        )
+        n_model = n
+        nnz_exec = op.num_chunks * chunk_nnz
+        step_scale = 1
+    elif engine == "single":
+        phases, full = _single_traces(pol, n=n, m=m, reorth=reorth, op=op, fused=fused)
+        n_model = n
+        nnz_exec = _executed_nnz(op, csr.nnz)
+        step_scale = 1
+    else:
+        raise ValueError(f"unknown engine {engine!r}; expected one of {ENGINES}")
     ritz_traces = _trace_ritz(pol, n=n_model, m=m, k=k, jacobi=jacobi)
     phases["ritz"] = ritz_traces[0]
     return phases, full, ritz_traces, step_scale, n_model, nnz_exec

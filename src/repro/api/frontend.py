@@ -23,10 +23,10 @@ dispatches to the right execution engine, and reports the outcome in a single
 Since the plan/execute split (``repro.api.session``), ``eigsh`` is a thin
 wrapper: ``prepare(A, ...)`` builds an :class:`~repro.api.session.EigenSession`
 owning every per-matrix setup product (coerced input, chosen placement,
-converted operators, tuned tiles) and the call executes one query against
+converted operators) and the call executes one query against
 it.  A fingerprint-keyed cache of recent sessions makes naive repeated
 calls on the same matrix hit the prepared path transparently — the second
-byte-identical call performs zero format conversions and zero tuner probes
+byte-identical call performs zero format conversions
 (verified by the counters in ``EigenResult.partition["spmv"]``, flagged by
 ``EigenResult.session_reuse``).  For many-query workloads, use
 :func:`repro.api.prepare` / :func:`repro.api.eigsh_many` directly.
@@ -132,8 +132,9 @@ class SolverConfig:
     stage_depth: int = 1  # chunked backend: chunks prefetched ahead of compute
     # Chunked backend: how staged ELL chunks travel host -> device.  "f32"
     # ships plain storage-dtype buffers; "bf16"/"fp8" quantize values (with
-    # per-row-block scales) and delta-encode columns, decompressed in-kernel
-    # (kernels/spmv_ell_packed) for 2-4x effective staging bandwidth; "auto"
+    # per-row-block scales) and delta-encode columns, decompressed in the
+    # SpMV (sparse.formats.pack_ell_chunk) for 2-4x effective staging
+    # bandwidth; "auto"
     # packs when the policy's storage dtype is already narrow.
     staging: str = "f32"
     jacobi: str = "host"  # phase-2 placement, "host" (paper) or "jax"

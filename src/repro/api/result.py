@@ -73,13 +73,12 @@ class EigenResult:
         ``"staging"`` counters: one-time host conversions, THIS call's
         device_put transfers, peak device-resident chunks).  Every backend
         (since the plan/execute split) carries a ``"spmv"`` dict with the
-        executed kernel format, tiles, tile provenance (``"tiles_from"``:
-        "table" | "tuned" | "override" — the autotuner's decision trail),
-        padding stats, and the session-reuse audit (``"conversions"`` /
-        ``"tuner_probes"`` this call paid, ``"reused"``).
+        executed SpMV format, the layout padding, what ran each phase
+        (``"kernels"``), and the session-reuse audit (``"conversions"`` this
+        call paid, ``"reused"``).
       timings: seconds per phase — always contains ``"total_s"``, plus the
         plan/execute split ``"prepare_s"`` (what this call spent building
-        session state: coercion, conversion, tuning; 0.0 on session reuse)
+        session state: coercion, conversion; 0.0 on session reuse)
         and ``"solve_s"`` (the execute phase); fixed-m backends add
         ``"lanczos_s"`` / ``"jacobi_s"`` / ``"project_s"``.  Batched
         ``eigsh_many`` results sharing one sweep also carry
@@ -97,7 +96,7 @@ class EigenResult:
       tridiag: raw Lanczos output (alpha / beta / basis), for diagnostics.
       session_reuse: this solve executed against an already-prepared
         :class:`~repro.api.session.EigenSession` — no coercion, format
-        conversion, or tile tuning was paid (the counters in
+        or format conversion was paid (the counters in
         ``partition["spmv"]`` verify it).
       policy_escalations: ``policy="auto"`` attempt trail — one dict per
         ladder rung tried ({policy, max_residual, tol, converged}, cheapest

@@ -2,7 +2,7 @@
 
 ``eigsh(A, k)`` reproduces the paper's transparency claim, but every call
 re-pays the full plan phase — input coercion, format census, ELL/BSR/hybrid
-conversion, tile tuning, shard remapping, chunk pinning — even when the
+conversion, shard remapping, chunk pinning — even when the
 matrix is identical.  The serving pattern the ROADMAP targets (one graph,
 millions of queries) is the opposite shape: one expensive plan, many cheap
 executes.  This module makes the split explicit:
@@ -13,7 +13,7 @@ executes.  This module makes the split explicit:
     rs = sess.eigsh_many([{"k": 4}, {"k": 8}])  # batched: one shared sweep
 
 A session owns the coerced input, the resolved placement, the converted
-device/shard/chunk operators and their tuned tiles — everything that is a
+device/shard/chunk operators and their layout padding — everything that is a
 function of the *matrix* and the layout-affecting config, and nothing that
 is a function of the *query* (k, policy, tol, num_iters, start vector).
 Operators are cached per precision policy (storage/compute dtype pair), so
@@ -24,8 +24,8 @@ small fingerprint-keyed session cache, so naive repeated calls transparently
 hit the prepared path.  The key is the content digest of the CSR arrays
 (``coerce.matrix_fingerprint``: every byte, hashed in 16 MiB chunks on a
 thread pool without copies) plus the layout-affecting config fields.  Reuse
-is *verified*, not assumed: results report the conversion and tuner-probe
-counts their call actually paid (``partition["spmv"]``) and a
+is *verified*, not assumed: results report the conversion count their
+call actually paid (``partition["spmv"]``) and a
 ``session_reuse`` provenance flag.
 
 ``eigsh_many`` amortizes one matrix across many ``(k, policy, tol)``
@@ -79,8 +79,6 @@ from ..kernels.engine import (
     make_engine,
     matrix_stats,
     phase_executors,
-    spmv_runs_pallas,
-    tuner_probe_count,
 )
 from ..kernels.ops import default_interpret
 from ..sparse.diskcsr import DiskCSR, is_diskcsr
@@ -350,7 +348,6 @@ class _Prepared:
     engine: Optional[SpmvEngine]
     build_s: float = 0.0
     conversions: int = 0
-    tuner_probes: int = 0
     # Arithmetic-kernel records (core.lanczos.Ops) memoized per policy: the
     # jitted Lanczos loop is keyed on the record's identity, so reusing one
     # record across queries turns every repeat solve into an XLA compile
@@ -361,11 +358,10 @@ class _Prepared:
         from ..core.lanczos import ops_for_operator
 
         eng = getattr(self.operator, "engine", None)
-        plan = getattr(eng, "iteration_plan", None)
-        # The resolved update mode joins the memo key so env-pin changes
-        # (REPRO_FUSED_LANCZOS / REPRO_ITER_UPDATE) between executes on one
-        # warm session can never serve a stale record.
-        mode = resolve_update_mode(pol, plan=plan, fused=fused)
+        # The resolved update mode joins the memo key so a kill-switch change
+        # (REPRO_FUSED_LANCZOS) between executes on one warm session can
+        # never serve a stale record.
+        mode = resolve_update_mode(pol, fused=fused, interpret=getattr(eng, "interpret", None))
         key = (pol, fused, mode)
         ops = self.ops_cache.get(key)
         if ops is None:
@@ -428,7 +424,7 @@ class EigenSession:
         self.mesh = mesh
         self._default_mesh = None
         t0 = time.perf_counter()
-        conv0, probes0 = conversion_count(), tuner_probe_count()
+        conv0 = conversion_count()
         pol0 = _plan_policy(cfg.policy).effective()
         ci = _coerced or coerce_input(A, n=n, storage_dtype=pol0.storage)
         self.op, self.csr, self.n = ci.operator, ci.csr, ci.n
@@ -447,7 +443,6 @@ class EigenSession:
         self.stats = {"queries": 0, "sweeps": 0, "cache_hits": 0, "recoveries": 0}
         self.prepare_s = time.perf_counter() - t0
         self.prepare_conversions = conversion_count() - conv0
-        self.prepare_tuner_probes = tuner_probe_count() - probes0
         # Coercion cost not yet attributed to any result: the first query
         # that builds a plan claims it into its timings["prepare_s"] (a
         # warmup() claims it into session.prepare_s instead).
@@ -456,7 +451,7 @@ class EigenSession:
     def warmup(self) -> "EigenSession":
         """Eagerly build the plan for the configured placement and default
         policy, so :func:`prepare` — not the first query — pays the
-        conversion/tuning cost.  (Construction alone builds lazily: the
+        conversion cost.  (Construction alone builds lazily: the
         frontend's one-call path lets the first query build, so that call's
         counters honestly report what it paid.)"""
         pol0 = _plan_policy(self.cfg.policy).effective()
@@ -465,7 +460,6 @@ class EigenSession:
         if built:
             self.prepare_s += prep.build_s
             self.prepare_conversions += prep.conversions
-            self.prepare_tuner_probes += prep.tuner_probes
         self._unclaimed_init_s = 0.0  # prepare() paid it; queries report 0
         return self
 
@@ -551,7 +545,7 @@ class EigenSession:
         layout the single-device engine would build, and the basis."""
         stats = self._matrix_stats()
         if self.cfg.format == "auto":
-            fmt = choose_format(stats, compiled=not spmv_runs_pallas(default_interpret()))
+            fmt = choose_format(stats, compiled=not default_interpret())
         else:
             fmt = self.cfg.format
         return device_working_set(stats, fmt, pol.storage, steps)
@@ -576,7 +570,7 @@ class EigenSession:
             if hit is not None:
                 return hit, False
             t0 = time.perf_counter()
-            conv0, probes0 = conversion_count(), tuner_probe_count()
+            conv0 = conversion_count()
             if kind == "distributed":
                 prep = self._build_distributed(pol)
             elif kind == "chunked":
@@ -585,7 +579,6 @@ class EigenSession:
                 prep = self._build_single(pol)
             prep.build_s = time.perf_counter() - t0
             prep.conversions = conversion_count() - conv0
-            prep.tuner_probes = tuner_probe_count() - probes0
             self._prepared[key] = prep
         # A lazy build grew this session's footprint: let the cache re-check
         # its byte budget (no-op for sessions that were never cached).
@@ -788,7 +781,7 @@ class EigenSession:
     def export_state(self) -> dict:
         """Serializable snapshot of this session's built plans (the warm
         state a restarted server needs): per-plan device-container arrays +
-        the engine configuration (format, accumulator dtype, tuned tiles).
+        the engine configuration (format, accumulator dtype, padding).
         The header carries the repro version, the matrix fingerprint, and the
         layout-config fingerprint so :meth:`import_plans` can reject stale
         artifacts.  Arrays come back as npz-safe NumPy (bf16 values are
@@ -832,10 +825,6 @@ class EigenSession:
                     },
                     "interpret": bool(e.interpret),
                     "requested": e.requested,
-                    "tiles_from": e.tiles_from,
-                    "iteration_plan": (
-                        e.iteration_plan.as_dict() if e.iteration_plan is not None else None
-                    ),
                 }
             fmt = prep.spmv_format
             plans.append(
@@ -872,8 +861,8 @@ class EigenSession:
         """Install plans exported by :meth:`export_state` into this session;
         returns how many were imported.  Containers are rebuilt with the
         plain device constructors — NO format conversion runs (the
-        ``conversion_count()`` audit stays untouched) and the persisted tiles
-        ride in, so no tuner probes either: the next query is a pure execute.
+        ``conversion_count()`` audit stays untouched) and the persisted
+        layout padding rides in: the next query is a pure execute.
 
         Stale artifacts are *rejected, not trusted*: a mismatched schema,
         repro version, matrix fingerprint, layout fingerprint, or dimension
@@ -1233,26 +1222,18 @@ class EigenSession:
                     spmv = {"format": fmt0}
             # The reuse contract, verified: what THIS call actually paid.
             spmv["conversions"] = prep.conversions if built else 0
-            spmv["tuner_probes"] = prep.tuner_probes if built else 0
             spmv["reused"] = not built
             gather = _sell_gather(prep, q.pol)
             if gather is not None:
                 spmv["sell"] = {**prep.operator.mat.summary(), "gather": gather}
-            # Iteration-plan provenance: what the tuner (or mode table) chose,
-            # plus the update mode this query's policy actually allows — the
-            # policy gate can demote a fused plan (compensated / phase splits).
-            iter_plan = getattr(prep.engine, "iteration_plan", None)
-            effective = resolve_update_mode(q.pol, plan=iter_plan)
-            if spmv.get("iteration_plan") or iter_plan is not None:
-                rec = dict(spmv.get("iteration_plan") or iter_plan.as_dict())
-                rec["effective"] = effective
-                spmv["iteration_plan"] = rec
             # Which phases ran as Mosaic kernels and which as XLA (the restarted
             # engine's update is its own jitted jnp, never the fused kernel).
             eng = prep.engine
+            interpret = eng.interpret if eng is not None else default_interpret()
+            effective = resolve_update_mode(q.pol, interpret=interpret)
             spmv["kernels"] = phase_executors(
                 eng.format if eng is not None else "coo",
-                eng.interpret if eng is not None else default_interpret(),
+                interpret,
                 "unfused" if q.backend == "restarted" else effective,
                 q.pol.compute,
                 gather=gather,
@@ -1671,7 +1652,9 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
     """Rebuild a :class:`_Prepared` from one exported plan record.  Uses the
     plain device-container constructors — never the ``to_device_*``
     converters — so the ``conversion_count()`` audit stays untouched; the
-    persisted tiles ride into the engine, so no tuner probes either."""
+    persisted padding rides into the engine.  Keys a plan written by an
+    older build may hold (``tiles_from``, ``iteration_plan``) are ignored:
+    they named tuner decisions that no longer exist, over the same layout."""
     from ..kernels.engine import TileConfig
     from ..sparse.formats import DeviceBSR, DeviceCOO, DeviceELL, DeviceHybrid
 
@@ -1685,21 +1668,7 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
     engine = None
     ecfg = plan.get("engine")
     if ecfg:
-        from ..kernels.engine import IterationPlan
-
         tiles = TileConfig(**{k: int(v) for k, v in ecfg["tiles"].items()})
-        iter_plan = None
-        ip = ecfg.get("iteration_plan")
-        if ip:
-            iter_plan = IterationPlan(
-                update=ip["update"],
-                tiles=TileConfig(
-                    block_r=int(ip["block_r"]),
-                    block_w=int(ip["block_w"]),
-                    block_size=int(ip["block_size"]),
-                ),
-                source=ip.get("source", "tuned"),
-            )
         engine = SpmvEngine(
             format=ecfg["format"],
             accum_dtype=jnp.dtype(ecfg["accum_dtype"]),
@@ -1709,8 +1678,6 @@ def _import_plan(plan: dict, n: int) -> _Prepared:
             interpret=default_interpret(),
             requested=ecfg.get("requested", ecfg["format"]),
             stats=None,
-            tiles_from=ecfg.get("tiles_from", "override"),
-            iteration_plan=iter_plan,
         )
     ctype = plan["container"]
     if ctype == "dense":
@@ -1780,7 +1747,7 @@ def prepare(
     checkpoint_dir: Optional[str] = None,
     checkpoint_every: int = 8,
 ) -> EigenSession:
-    """Plan phase of :func:`repro.api.eigsh`: coerce, place, convert, tune —
+    """Plan phase of :func:`repro.api.eigsh`: coerce, place, convert —
     once — and return the :class:`EigenSession` that owns the result.
 
     Arguments mirror :func:`repro.api.eigsh` (minus the per-query ``k`` /

@@ -38,9 +38,9 @@ class EnvKnob:
 
     ``type`` is documentation-facing ("bool", "int", "float", "str",
     "path"); parsing is done by the accessor the call site picks, so a knob
-    whose raw string is parsed specially (e.g. ``REPRO_SPMV_TILES``'s
-    ``RxW[@B]`` spec) declares type "str" and keeps its parser at the call
-    site.
+    whose raw string is parsed specially (e.g. ``REPRO_FAULT``'s
+    ``kind[@iter=N]`` spec) declares type "str" and keeps its parser at the
+    call site.
     """
 
     name: str
@@ -54,31 +54,7 @@ def _k(name: str, type: str, default: Any, description: str) -> EnvKnob:
 
 
 _DECLARED: Iterable[EnvKnob] = (
-    # --- SpMV engine / autotuner -------------------------------------------
-    _k(
-        "REPRO_SPMV_TUNE",
-        "bool",
-        False,
-        "Enable the measured SpMV/iteration autotuner (off = heuristic tiles).",
-    ),
-    _k(
-        "REPRO_SPMV_TUNE_CACHE",
-        "path",
-        ".cache/spmv_tune.json",
-        "Path of the persistent autotune decision cache ('' disables persistence).",
-    ),
-    _k(
-        "REPRO_SPMV_TUNE_BUDGET",
-        "int",
-        6,
-        "Max number of tile candidates the autotuner measures per matrix.",
-    ),
-    _k(
-        "REPRO_SPMV_TILES",
-        "str",
-        None,
-        "Force SpMV tile config as 'RxW[@B]' (rows x width [@ bsr block]), bypassing heuristics.",
-    ),
+    # --- SpMV engine -------------------------------------------------------
     _k(
         "REPRO_SPMV_ELL_OVERHEAD",
         "float",
@@ -103,13 +79,7 @@ _DECLARED: Iterable[EnvKnob] = (
         0.05,
         "Max tail-nnz fraction for which hybrid is preferred over plain COO.",
     ),
-    # --- Lanczos iteration plan --------------------------------------------
-    _k(
-        "REPRO_ITER_UPDATE",
-        "str",
-        None,
-        "Force the Lanczos update mode: 'fused', 'fused_spmv', 'unfused', or 'auto'.",
-    ),
+    # --- Lanczos update ----------------------------------------------------
     _k(
         "REPRO_FUSED_LANCZOS",
         "bool",

@@ -23,12 +23,11 @@ communication analysis.
 Per-shard SpMV runs through the :class:`~repro.kernels.engine.SpmvEngine`
 layer: each shard's COO slice is converted host-side to ELL, blocked-ELL,
 or the hybrid hub split (``sparse.formats.shard_to_*``) and the Lanczos hot
-loop calls the Pallas kernels in interpret mode off-TPU, and XLA gathers over
-the same layouts on TPU (``kernels.engine.spmv_runs_pallas``).  ``spmv_format=
-"auto"`` picks ELL vs BSR vs hybrid from per-shard statistics — hybrid keeps
-power-law shards on the kernel path by capping the ELL width and spilling
-hub overflow to a small ``segment_sum`` tail; plain COO remains only as an
-explicit opt-out (``spmv_format="coo"``).
+loop runs the engine's gather body for that layout.  ``spmv_format="auto"``
+picks ELL vs BSR vs hybrid from per-shard statistics — hybrid keeps
+power-law shards off ``segment_sum`` for the bulk of their non-zeros by
+capping the ELL width and spilling hub overflow to a small tail; plain COO
+remains only as an explicit opt-out (``spmv_format="coo"``).
 """
 
 from __future__ import annotations
@@ -42,7 +41,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ..kernels.engine import SpmvEngine, make_engine, shard_stats, spmv_runs_pallas
+from ..kernels.engine import SpmvEngine, make_engine, shard_stats
 from ..sparse.formats import CSR, row_sums, shard_to_blocked_ell, shard_to_ell, shard_to_hybrid
 from .eigensolver import EigResult
 from .jacobi import jacobi_eigh_host, tridiag_to_dense
@@ -67,8 +66,8 @@ __all__ = [
     "sharded_lanczos",
 ]
 
-# Formats the distributed hot loop may auto-select: kernel-backed only (the
-# paper's design point; hybrid's tail segment_sum is bounded by the hub
+# Formats the distributed hot loop may auto-select: no per-nnz segment_sum
+# (the paper's design point; hybrid's tail segment_sum is bounded by the hub
 # split, so it still counts).  "coo" stays available as an explicit request.
 DISTRIBUTED_FORMATS = ("ell", "bsr", "hybrid")
 
@@ -83,6 +82,7 @@ def _make_sharded_ops(
     policy: PrecisionPolicy,
     axis: str,
     engine: Optional[SpmvEngine] = None,
+    fused: Optional[bool] = None,
 ) -> Ops:
     cdt = policy.compute
     abdt = policy.phase_dtype("alpha_beta")  # alpha/beta reduction phase
@@ -124,55 +124,12 @@ def _make_sharded_ops(
         coeffs = jax.lax.psum(local, axis)  # sync point C
         return (u.astype(rdt) - basis_matmul(coeffs, vs_c)).astype(cdt)
 
-    plan = getattr(engine, "iteration_plan", None) if engine is not None else None
-    mode = resolve_update_mode(policy, plan=plan)
-    fused_iteration = None
-    if (
-        mode == "fused_spmv"
-        and fmt == "ell"
-        and spmv_runs_pallas(engine.interpret)
-        and jnp.dtype(sdt_spmv) == jnp.dtype(cdt)
-    ):
-        from ..kernels import ops as kops
-        from ..kernels.engine import _fit_tile
-        from ..kernels.lanczos_fused import spmv_ell_alpha_kernel_call
-
-        val, col = mats
-        rows = val.shape[0]  # local padded rows (>= n_pad)
-        block_r = _fit_tile(engine.tiles.block_r, rows)
-        block_w = _fit_tile(engine.tiles.block_w, val.shape[1])
-        acc = jnp.dtype(sdt_spmv)
-
-        def fused_iteration(v, v_prev, beta, need_norm=True):
-            x_full = jax.lax.all_gather(v.astype(policy.storage), axis, tiled=True)
-            vpad = jnp.pad(v, (0, rows - n_pad)) if rows > n_pad else v
-            w, a_loc = spmv_ell_alpha_kernel_call(
-                val, col, x_full, vpad,
-                block_r=block_r, block_w=block_w,
-                accum_dtype=acc, interpret=engine.interpret,
-            )
-            # Sync point A, dispatched immediately so XLA's scheduler can
-            # overlap it with the local SpMV tail below: the beta term of the
-            # three-term update needs no alpha, so ``t`` computes while the
-            # alpha partials are on the wire.  (Association differs from the
-            # single-device path — (w - beta v_prev) - alpha v — an accepted
-            # last-ulp tradeoff for the overlap.)
-            alpha = jax.lax.psum(a_loc[0], axis).astype(cdt)
-            t = w[:n_pad].astype(cdt) - beta * v_prev
-            u, nrm_sq = kops.lanczos_update(
-                t, v, v, alpha, jnp.zeros((), cdt), accum_dtype=cdt,
-                interpret=engine.interpret,
-            )
-            if need_norm:
-                nrm_sq = jax.lax.psum(nrm_sq, axis)  # sync point B
-            # Two collectives per iteration — the paper's 2-psum budget holds.
-            return u, alpha, nrm_sq
-
     fused_update = None
-    if fused_iteration is None and mode in ("fused", "fused_spmv"):
+    interpret = None if engine is None else engine.interpret
+    if resolve_update_mode(policy, fused=fused, interpret=interpret) == "fused":
         from ..kernels import ops as kops
 
-        kw = {} if engine is None else {"interpret": engine.interpret}
+        kw = {} if interpret is None else {"interpret": interpret}
 
         def fused_update(w, v, v_prev, alpha, beta, need_norm=True):
             u, nrm_sq = kops.lanczos_update(w, v, v_prev, alpha, beta, accum_dtype=cdt, **kw)
@@ -185,7 +142,7 @@ def _make_sharded_ops(
 
     return Ops(
         matvec=matvec, dot=dot, gram=gram, project_out=project_out,
-        fused_update=fused_update, fused_iteration=fused_iteration,
+        fused_update=fused_update,
     )
 
 
@@ -199,11 +156,13 @@ def sharded_lanczos(
     axis: str = "data",
     engine: Optional[SpmvEngine] = None,
     mats: Optional[tuple] = None,
+    fused: Optional[bool] = None,
 ) -> LanczosResult:
     """Run the distributed Lanczos loop. ``v1_padded``: (G, n_pad) layout.
 
     ``mats`` are the shard-stacked SpMV arrays matching ``engine.format``
-    (default: the COO triplets of ``pm`` — the legacy segment-sum path).
+    (default: the COO triplets of ``pm`` — the legacy segment-sum path);
+    ``fused`` pins the update mode (:func:`resolve_update_mode`).
     """
     policy = policy.effective()
     _faults.check_sweep_entry()
@@ -213,7 +172,7 @@ def sharded_lanczos(
     def local_fn(v1, *shard_mats):
         v1 = v1[0]  # drop shard axis
         local = tuple(m[0] for m in shard_mats)
-        ops = _make_sharded_ops(local, pm.n_pad, policy, axis, engine=engine)
+        ops = _make_sharded_ops(local, pm.n_pad, policy, axis, engine=engine, fused=fused)
         res = _lanczos_loop(v1, ops, num_iters, policy, reorth)
         return res.alpha, res.beta, res.beta_last, res.basis[None]  # re-add shard axis
 
@@ -233,8 +192,8 @@ def sharded_lanczos(
 
 class PreparedShards(NamedTuple):
     """Plan-time product of the distributed engine: the nnz-balanced row
-    partition, the shard-stacked SpMV arrays in the engine's chosen kernel
-    format, and the engine itself (tiles + accum dtype).  Building one is
+    partition, the shard-stacked SpMV arrays in the engine's chosen
+    format, and the engine itself (padding + accum dtype).  Building one is
     the entire per-matrix setup cost of :func:`solve_sharded`; reusing it
     across solves (``api/session.py``) skips every host-side conversion."""
 

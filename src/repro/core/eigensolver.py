@@ -161,8 +161,7 @@ def solve_fixed(
     # Lanczos loop eagerly: see LinearOperator.prefers_jit / lanczos module doc.
     use_jit = getattr(op, "prefers_jit", True)
     if ops is None:
-        # Route by the operator's measured iteration plan (fused/unfused/
-        # fully-fused SpMV+alpha) instead of the bare policy gate.
+        # The update mode follows the operator's engine (compiled -> fused).
         ops = ops_for_operator(op, policy)
     lres = lanczos_tridiag(
         op.bound_matvec(policy), v1, m, policy, reorth=reorth, jit=use_jit, ops=ops,

@@ -23,25 +23,20 @@ The loop body is generic over an ``Ops`` record so the same code runs
 single-device (plain reductions) and multi-device (psum reductions inside
 ``shard_map`` — see ``core/distributed.py``).
 
-Three memory-roofline optimizations ride on the record (beyond-paper):
+Two memory-roofline optimizations ride on the record (beyond-paper):
 
   * ``fused_update`` — the three-term recurrence + squared norm execute as
     ONE pass over the n-length vectors through the Pallas kernel in
     ``kernels/lanczos_update.py`` (policy-gated: compensated policies keep
     the reference reductions; f64 compute falls back to ``kernels/ref.py``
     inside the wrapper).
-  * ``fused_iteration`` — the whole SpMV + alpha + update (+ norm) step in
-    two passes over the Krylov vectors (``kernels/lanczos_fused.py`` chained
-    with the update kernel); ELL operators only.
   * ``project_out`` — the masked re-orthogonalization casts the stored basis
     to the compute dtype ONCE per pass (coefficients and subtraction reuse
     the same masked cast) instead of materializing two full (m, n) copies.
 
-Which of these actually runs is a **measured decision**: the engine's
-:class:`~repro.kernels.engine.IterationPlan` (whole-iteration autotuner, or
-its static mode table) routes the update via :func:`resolve_update_mode`.
-``REPRO_FUSED_LANCZOS=0`` force-disables all fusion; ``=1`` force-enables the
-fused update; ``REPRO_ITER_UPDATE`` pins the exact mode at the plan layer.
+Whether the fused update runs is :func:`resolve_update_mode`'s decision: a
+caller's pin, the policy gate (``REPRO_FUSED_LANCZOS=0`` disables fusion),
+then the execution mode (compiled -> fused, interpreter -> unfused).
 """
 
 from __future__ import annotations
@@ -168,10 +163,6 @@ class Ops:
     # ``need_norm=False`` tells distributed variants the caller will discard
     # the norm (reorth recomputes beta), so they must not psum it.
     fused_update: Optional[Callable] = None
-    # (v, v_prev, beta_prev, need_norm) -> (u, alpha, ||u||^2 or None): the
-    # whole SpMV + alpha + three-term update step in two fused passes.  When
-    # set it subsumes matvec/dot/fused_update in the loop body.
-    fused_iteration: Optional[Callable] = None
 
 
 def fused_update_enabled(policy: PrecisionPolicy) -> bool:
@@ -187,47 +178,31 @@ def fused_update_enabled(policy: PrecisionPolicy) -> bool:
     return jnp.dtype(policy.phase_dtype("alpha_beta")) == jnp.dtype(policy.compute)
 
 
-def resolve_update_mode(policy: PrecisionPolicy, plan=None, fused: Optional[bool] = None) -> str:
-    """How the three-term update (and SpMV fusion) should run for this solve.
+def resolve_update_mode(
+    policy: PrecisionPolicy, fused: Optional[bool] = None, interpret: Optional[bool] = None
+) -> str:
+    """How the three-term update runs for this solve: "fused" or "unfused".
 
-    Layered decision:
-      1. an explicit ``fused=`` pin from the caller wins (legacy knob — e.g.
-         the vmapped multi-start path pins ``False``);
+      1. an explicit ``fused=`` pin from the caller wins (the vmapped
+         multi-start path and the recovery ladder pin ``False``), within
+         the policy gate;
       2. the policy gate (:func:`fused_update_enabled`, which also honors the
          ``REPRO_FUSED_LANCZOS=0`` kill switch) can force "unfused";
-      3. ``REPRO_FUSED_LANCZOS=1`` *explicitly set* force-enables fusion (the
-         pre-plan default behavior, kept for A/B runs);
-      4. otherwise the engine's measured :class:`IterationPlan` decides, or —
-         with no plan in scope — the static mode table for the execution mode
-         (interpret -> unfused: the Pallas interpreter's per-grid-step
-         overhead makes fused kernels lose there; compiled -> fused).
+      3. otherwise the execution mode decides
+         (:func:`~repro.kernels.engine.table_update_mode`): ``interpret`` is
+         the operator's engine's mode, this process's backend when None.
     """
     if fused is not None:
         return "fused" if (fused and fused_update_enabled(policy)) else "unfused"
     if not fused_update_enabled(policy):
         return "unfused"
-    pin = (envcfg.get_str("REPRO_ITER_UPDATE") or "").strip().lower()
-    if pin:
-        # Same pin resolve_iteration_plan honors — re-checked here so it
-        # also reaches warm sessions whose plan was built before the pin.
-        from ..kernels.engine import ITER_UPDATE_MODES
+    from ..kernels.engine import table_update_mode  # lazy: core sits below kernels
 
-        if pin not in ITER_UPDATE_MODES:
-            raise ValueError(
-                f"REPRO_ITER_UPDATE={pin!r}: expected one of {ITER_UPDATE_MODES}"
-            )
-        return pin
-    env = (envcfg.raw("REPRO_FUSED_LANCZOS") or "").strip().lower()
-    if env in ("1", "true", "on", "yes"):
-        if plan is not None and plan.update != "unfused":
-            return plan.update
-        return "fused"
-    if plan is not None:
-        return plan.update
-    from ..kernels.engine import table_update_mode
-    from ..kernels.ops import default_interpret
+    if interpret is None:
+        from ..kernels.ops import default_interpret
 
-    return table_update_mode(default_interpret())
+        interpret = default_interpret()
+    return table_update_mode(interpret)
 
 
 def _local_reduce(x: jax.Array, policy: PrecisionPolicy, dtype=None) -> jax.Array:
@@ -236,62 +211,10 @@ def _local_reduce(x: jax.Array, policy: PrecisionPolicy, dtype=None) -> jax.Arra
     return jnp.sum(x)
 
 
-def _make_fused_iteration(operator, policy: PrecisionPolicy) -> Optional[Callable]:
-    """Whole-iteration fused step for an ELL-backed operator, or None.
-
-    Requires the operator to expose its :class:`DeviceELL` container and
-    engine (``SparseOperator`` does), an engine that runs the SpMV kernels
-    (interpret mode: compiled TPU runs the SpMV as XLA, see
-    ``kernels.engine.spmv_runs_pallas``), and the spmv-phase accumulation
-    dtype to match the carried compute dtype — the kernel's in-pass alpha
-    replaces ``dot(v, w)``, so a phase split there would change what alpha
-    means.
-    """
-    from ..kernels.engine import spmv_runs_pallas  # lazy: core sits below kernels
-
-    eng = getattr(operator, "engine", None)
-    mat = getattr(operator, "mat", None)
-    if eng is None or mat is None or eng.format != "ell" or not spmv_runs_pallas(eng.interpret):
-        return None
-    from ..kernels import ops as kops
-    from ..sparse.formats import DeviceELL
-
-    if not isinstance(mat, DeviceELL):
-        return None
-    cdt, sdt = policy.compute, policy.storage
-    acc = jnp.dtype(policy.phase_dtype("spmv"))
-    if acc != jnp.dtype(cdt):
-        return None
-    from ..kernels.engine import _fit_tile
-
-    # Same divisibility clamp the engine's ell_matvec applies to its tiles.
-    block_r = _fit_tile(eng.tiles.block_r, mat.val.shape[0])
-    block_w = _fit_tile(eng.tiles.block_w, mat.val.shape[1])
-
-    def fused_iteration(v, v_prev, beta, need_norm=True):
-        # Pass 1: w = A v with alpha = <v, w> folded into the width sweep.
-        w, alpha = kops.spmv_ell_alpha(
-            mat,
-            v.astype(sdt),
-            v,
-            accum_dtype=acc,
-            block_r=block_r,
-            block_w=block_w,
-            interpret=eng.interpret,
-        )
-        alpha = alpha.astype(cdt)
-        # Pass 2: three-term update + squared norm in one pass.
-        u, nrm = kops.lanczos_update(w.astype(cdt), v, v_prev, alpha, beta, accum_dtype=cdt)
-        return u, alpha, nrm
-
-    return fused_iteration
-
-
 def make_local_ops(
     matvec: Callable,
     policy: PrecisionPolicy,
     fused: Optional[bool] = None,
-    plan=None,
     operator=None,
 ) -> Ops:
     """Single-device ops: plain reductions in the per-phase compute dtypes
@@ -299,10 +222,8 @@ def make_local_ops(
     is cast back to the carried ``compute`` dtype, so a policy with no phase
     overrides is bit-identical to the pre-phase uniform arithmetic.
 
-    ``plan`` (an :class:`~repro.kernels.engine.IterationPlan`) and the legacy
-    ``fused`` pin route the update through :func:`resolve_update_mode`;
-    ``operator`` enables the fully-fused SpMV+alpha pass when the plan asks
-    for it and the operator exposes an ELL layout.
+    ``fused`` (a pin) and the execution mode of ``operator``'s engine route
+    the update through :func:`resolve_update_mode`.
     """
     cdt = policy.compute
     abdt = policy.phase_dtype("alpha_beta")
@@ -322,38 +243,28 @@ def make_local_ops(
         coeffs = basis_matmul(basis_c, u.astype(policy.storage).astype(rdt))
         return (u.astype(rdt) - basis_matmul(coeffs, basis_c)).astype(cdt)
 
-    mode = resolve_update_mode(policy, plan=plan, fused=fused)
-    fused_iteration = None
-    if mode == "fused_spmv":
-        fused_iteration = _make_fused_iteration(operator, policy)
-        if fused_iteration is None:
-            mode = "fused"  # operator can't supply the fused pass: next rung
+    # The operator's engine decides interpret vs compiled, like its SpMV.
+    eng = getattr(operator, "engine", None)
+    interpret = None if eng is None else eng.interpret
     fused_update = None
-    if mode in ("fused", "fused_spmv") and fused_iteration is None:
+    if resolve_update_mode(policy, fused=fused, interpret=interpret) == "fused":
         from ..kernels import ops as kops  # lazy: core sits below kernels
 
-        # The operator's engine decides interpret vs compiled, like its SpMV.
-        eng = getattr(operator, "engine", None)
-        kw = {} if eng is None else {"interpret": eng.interpret}
+        kw = {} if interpret is None else {"interpret": interpret}
 
         def fused_update(w, v, v_prev, alpha, beta, need_norm=True):
             return kops.lanczos_update(w, v, v_prev, alpha, beta, accum_dtype=cdt, **kw)
 
     return Ops(
         matvec=matvec, dot=dot, gram=gram, project_out=project_out,
-        fused_update=fused_update, fused_iteration=fused_iteration,
+        fused_update=fused_update,
     )
 
 
 def ops_for_operator(operator, policy: PrecisionPolicy, fused: Optional[bool] = None) -> Ops:
-    """Ops for a :class:`LinearOperator`, routed by its engine's measured
-    :class:`IterationPlan` (operators without an engine fall back to the
-    static mode table)."""
-    eng = getattr(operator, "engine", None)
-    plan = getattr(eng, "iteration_plan", None)
-    return make_local_ops(
-        operator.bound_matvec(policy), policy, fused=fused, plan=plan, operator=operator
-    )
+    """Ops for a :class:`LinearOperator`, its update mode resolved for its
+    engine's execution mode (this process's backend without an engine)."""
+    return make_local_ops(operator.bound_matvec(policy), policy, fused=fused, operator=operator)
 
 
 def _reorth_mask(m: int, i: jax.Array, mode: str, dtype) -> jax.Array:
@@ -412,33 +323,22 @@ def _lanczos_loop(
         v = jnp.where(i == 0, v1, w / jnp.maximum(beta_prev, tiny))
         basis = jax.lax.dynamic_update_slice(basis, v.astype(sdt)[None, :], (i, 0))
         nrm_sq = None
-        if ops.fused_iteration is not None:
-            # --- lines 9-11 in two fused passes: SpMV + alpha in one kernel,
-            # update + norm in the other (each Krylov vector read once) ---
-            u, alpha, fused_nrm = ops.fused_iteration(
-                v, v_prev, beta_prev, need_norm=(reorth == "none")
+        # --- projection (line 9): SpMV in compute precision ---
+        u = ops.matvec(v.astype(sdt)).astype(cdt)
+        u = _faults.tap_spmv(u, i)
+        # --- alpha (line 10): sync point A ---
+        alpha = ops.dot(v, u)
+        alphas = alphas.at[i].set(alpha)
+        # --- three-term recurrence (line 11): one fused memory pass when the
+        # update mode is fused (the kernel also yields ||u||^2 for free) ---
+        if ops.fused_update is not None:
+            u, fused_nrm = ops.fused_update(
+                u, v, v_prev, alpha, beta_prev, need_norm=(reorth == "none")
             )
-            u = _faults.tap_spmv(u, i)
-            alphas = alphas.at[i].set(alpha)
             if reorth == "none":
                 nrm_sq = fused_nrm
         else:
-            # --- projection (line 9): SpMV in compute precision ---
-            u = ops.matvec(v.astype(sdt)).astype(cdt)
-            u = _faults.tap_spmv(u, i)
-            # --- alpha (line 10): sync point A ---
-            alpha = ops.dot(v, u)
-            alphas = alphas.at[i].set(alpha)
-            # --- three-term recurrence (line 11): one fused memory pass when
-            # the plan asks for it (the kernel also yields ||u||^2 for free) ---
-            if ops.fused_update is not None:
-                u, fused_nrm = ops.fused_update(
-                    u, v, v_prev, alpha, beta_prev, need_norm=(reorth == "none")
-                )
-                if reorth == "none":
-                    nrm_sq = fused_nrm
-            else:
-                u = u - alpha * v - beta_prev * v_prev
+            u = u - alpha * v - beta_prev * v_prev
         # --- re-orthogonalization (lines 12-21): sync point C ---
         if reorth != "none":
             mask = _reorth_mask(m, i, reorth, cdt)

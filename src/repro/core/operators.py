@@ -4,8 +4,8 @@ The paper's solver is matrix-driven (sparse SpMV), but the Lanczos phase only
 needs ``y = A @ x``; we expose that as a small operator protocol so the same
 solver runs on:
 
-  * explicit sparse matrices (COO segment-sum path, or the Pallas ELL/BSR
-    kernels — the paper's case);
+  * explicit sparse matrices (any layout of the SpMV engine — the paper's
+    case);
   * chunk-streamed matrices whose triplets live in **host** memory and are
     staged to the device chunk-by-chunk (the paper's out-of-core unified
     memory mode, DESIGN.md §3.4);
@@ -30,6 +30,7 @@ from ..sparse.formats import (
     DeviceCOO,
     DeviceELL,
     count_conversions,
+    pack_ell_chunk,
     row_sums,
     to_device_bsr,
     to_device_coo,
@@ -67,12 +68,11 @@ def chunk_row_bounds(indptr: np.ndarray, n: int, chunk_nnz: int) -> list:
 
 def chunk_rows_pad(rows: int, block_r: int, storage_dtype, row_multiple: int = 1) -> int:
     """Padded row count of one staged ELL chunk: rows round up to the chunk's
-    own row tile — the kernel's ``block_r`` capped at the next power of two
+    own row tile — the engine's ``block_r`` capped at the next power of two
     of the row count (floored at the TPU sublane minimum: 8 for 4-byte
     dtypes, 16 for bf16/f16, 32 for fp8), so a chunk with FEW rows (e.g. a
     hub row chunked alone) never allocates the full global row tile times
-    its huge width.  ``ell_matvec`` adapts its row tile down to whatever
-    divides this.  ``row_multiple`` additionally aligns the padded count
+    its huge width.  ``row_multiple`` additionally aligns the padded count
     (the chunk-resident sharded path needs rows divisible by the mesh)."""
     itemsize = jnp.dtype(storage_dtype).itemsize
     min_r = {1: 32, 2: 16}.get(itemsize, 8)
@@ -118,37 +118,27 @@ class DenseOperator(LinearOperator):
 class SparseOperator(LinearOperator):
     """Explicit sparse matrix; ``impl`` (or an :class:`SpmvEngine`) picks the
     SpMV execution path.  With an engine attached, the container format and
-    tile parameters come from the engine (`kernels/engine.py`)."""
+    layout padding come from the engine (`kernels/engine.py`)."""
 
     mat: object  # DeviceCOO | DeviceELL | DeviceBSR | DeviceHybrid | DeviceSELL
-    impl: str = "coo"  # "coo" | "ell" | "ell_kernel" | "bsr_kernel" | "engine"
+    impl: str = "coo"  # "coo" | "ell" | "engine"
     engine: Optional[SpmvEngine] = None
 
     @property
     def n(self) -> int:
-        if isinstance(self.mat, tuple):  # blocked-ELL: (val, bcol, n_rows)
-            return int(self.mat[2])
         return self.mat.n_rows
 
     @property
     def spmv_format(self) -> str:
         if self.engine is not None:
             return self.engine.format
-        return {"ell_kernel": "ell", "bsr_kernel": "bsr"}.get(self.impl, self.impl)
+        return self.impl
 
     def matvec(self, x, accum_dtype=None):
         if self.engine is not None:
             return self.engine.spmv(self.mat, x, accum_dtype=accum_dtype)
         if self.impl in ("coo", "ell"):
             return self.mat.matvec(x, accum_dtype=accum_dtype)
-        if self.impl == "ell_kernel":
-            from ..kernels import ops as kops
-
-            return kops.spmv_ell(self.mat, x, accum_dtype=accum_dtype)
-        if self.impl == "bsr_kernel":
-            from ..kernels import ops as kops
-
-            return kops.spmv_bsr(self.mat, x, accum_dtype=accum_dtype)  # mat = (val,bcol,n)
         raise ValueError(f"unknown SpMV impl {self.impl!r}")
 
 
@@ -173,8 +163,9 @@ class ChunkedOperator(LinearOperator):
 
     **Compressed staging.**  ``staging="bf16" | "fp8"`` stages ELL chunk
     values quantized to the narrow dtype with per-row-block scales and
-    delta-encoded int16/int32 columns, decompressed inside the Pallas kernel
-    (``kernels/spmv_ell_packed.py``) — 2-4x the effective staging bandwidth.
+    delta-encoded int16/int32 columns (``sparse.formats.pack_ell_chunk``),
+    decompressed inside the SpMV (``SpmvEngine.packed_ell_matvec``) — 2-4x
+    the effective staging bandwidth.
     ``staging="auto"`` packs when the storage dtype is already narrow
     (bf16/f16 policies) and ships plain buffers otherwise.  Byte / bandwidth
     / compression counters accumulate in ``self.staging`` (surfaced by
@@ -187,8 +178,8 @@ class ChunkedOperator(LinearOperator):
 
     With an ELL-format :class:`SpmvEngine` attached, chunks are row ranges
     staged as per-chunk-width ELL tiles (a hub row inflates only its own
-    chunk's padding, not every chunk's) and the partial SpMV runs the Pallas
-    kernel; otherwise the COO ``segment_sum`` reference path streams
+    chunk's padding, not every chunk's) and the partial SpMV runs the
+    engine's ELL body; otherwise the COO ``segment_sum`` reference path streams
     nnz-sized slices (plain staging only).
     """
 
@@ -231,7 +222,7 @@ class ChunkedOperator(LinearOperator):
             itemsize = jnp.dtype(dtype).itemsize
             staging = "bf16" if itemsize == 2 else ("fp8" if itemsize == 1 else "f32")
         if staging != "f32" and self.spmv_format != "ell":
-            staging = "f32"  # packed staging is an ELL-kernel path
+            staging = "f32"  # packed staging is an ELL path
         self.staging_mode = staging
         self.mesh = mesh
         self._axis = axis
@@ -325,7 +316,7 @@ class ChunkedOperator(LinearOperator):
         self.num_chunks = len(self._bounds)
         self._n_out_pad = n_out_pad
 
-        # Jitted per-chunk kernel SpMV; static over the engine (hashable) so a
+        # Jitted per-chunk SpMV; static over the engine (hashable) so a
         # different accum dtype retraces once per distinct chunk width, not
         # per chunk per call.
         @partial(jax.jit, static_argnames=("eng",))
@@ -402,8 +393,6 @@ class ChunkedOperator(LinearOperator):
                 np_dtype
             )
             return (val, col)
-        from ..kernels.spmv_ell_packed import pack_ell_chunk
-
         val = np.zeros((rows_pad, width), dtype=np.float32)
         val[rix, pos] = self._csr.data[lo:hi]
         return pack_ell_chunk(val, col, self.staging_mode)
@@ -555,7 +544,7 @@ class ChunkedOperator(LinearOperator):
     # ------------------------- sharded partial dispatch -------------------------
 
     def _shard_fn(self, eng, packed: bool):
-        """shard_map-wrapped per-chunk partial SpMV: the kernel runs on each
+        """shard_map-wrapped per-chunk partial SpMV: it runs on each
         device's row slice of the staged chunk, with ``x`` replicated — the
         composition of out-of-core staging and the paper's multi-device
         partition.  Cached per (engine, kind) since shard_map closures are
@@ -693,7 +682,7 @@ def make_operator(
     """Build a solver operator for an explicit sparse matrix.
 
     With an :class:`SpmvEngine`, the engine's chosen format drives the device
-    container and the kernel tile parameters (``impl`` is ignored); otherwise
+    container and its padding (``impl`` is ignored); otherwise
     ``impl`` picks the legacy fixed path.
     """
     if engine is not None:
@@ -717,12 +706,8 @@ def make_operator(
         return SparseOperator(mat, impl="engine", engine=engine)
     if impl == "coo":
         return SparseOperator(to_device_coo(csr, dtype=dtype), impl="coo")
-    if impl in ("ell", "ell_kernel"):
+    if impl == "ell":
         return SparseOperator(to_device_ell(csr, dtype=dtype), impl=impl)
-    if impl == "bsr_kernel":
-        from ..kernels.spmv_bsr import blocked_ell_from_csr
-
-        return SparseOperator(blocked_ell_from_csr(csr, dtype=dtype), impl=impl)
     if impl == "chunked":
         return ChunkedOperator(csr, dtype=dtype)
     raise ValueError(f"unknown operator impl {impl!r}")

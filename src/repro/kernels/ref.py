@@ -1,6 +1,5 @@
-"""Pure-jnp oracles for every Pallas kernel in this package.
-
-Each function is the semantic ground truth the kernels must match
+"""Pure-jnp references: the ELL SpMV body the engine runs
+(``spmv_ell_ref``) and the oracles of the vector Pallas kernels
 (``tests/test_kernels.py`` sweeps shapes/dtypes and asserts allclose).
 """
 

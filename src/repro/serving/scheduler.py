@@ -25,7 +25,7 @@ that layer:
   bounded queue rejects overload with :class:`QueueFullError` instead of
   buffering without limit.
 * **Warm restarts**: with a :class:`~repro.serving.store.SessionStore`
-  attached, ``add_matrix`` restores persisted device layouts + tuned tiles
+  attached, ``add_matrix`` restores persisted device layouts
   keyed by matrix fingerprint — zero conversions, counter-verified — and
   persists cold-built sessions for the next process.
 * **Metrics**: queue depth, batch occupancy, coalesce rate, warm-start

@@ -1,13 +1,12 @@
 """Cross-process session persistence: the serving warm-start store.
 
-A served matrix's expensive state — converted device layouts, tuned SpMV
-tiles, per-policy plan configuration — is a pure function of (matrix bytes,
+A served matrix's expensive state — converted device layouts and
+per-policy plan configuration — is a pure function of (matrix bytes,
 layout config, repro version).  ``SessionStore`` persists exactly that
-state next to the SpMV tune cache, keyed by matrix fingerprint + layout
-fingerprint, so a restarted server warms instantly: reloading rebuilds the
-device containers from the saved arrays with the *plain constructors*
-(``conversion_count()`` does not move) and injects the saved tiles
-(``tuner_probe_count()`` does not move either).
+state, keyed by matrix fingerprint + layout fingerprint, so a restarted
+server warms instantly: reloading rebuilds the device containers from the
+saved arrays with the *plain constructors* (``conversion_count()`` does not
+move) and injects the saved layout padding.
 
 Layout on disk (one directory per (matrix, layout) pair)::
 
@@ -47,15 +46,17 @@ _PLANS = "plans.npz"
 _CKPT_SCHEMA = 1
 
 
+# The per-user cache directory the default store and checkpoint roots sit in.
+_CACHE_DIR = os.path.join(os.path.expanduser("~"), ".cache", "repro")
+
+
 def default_store_root() -> str:
-    """Default store location: ``REPRO_SERVING_STORE`` if set, else a
-    ``serving_store`` directory next to the SpMV tune cache."""
+    """Default store location: ``REPRO_SERVING_STORE`` if set, else
+    ``~/.cache/repro/serving_store``."""
     env = envcfg.get_str("REPRO_SERVING_STORE")
     if env:
         return env
-    from ..kernels.engine import DEFAULT_TUNE_CACHE
-
-    return os.path.join(os.path.dirname(DEFAULT_TUNE_CACHE), "serving_store")
+    return os.path.join(_CACHE_DIR, "serving_store")
 
 
 class SessionStore:
@@ -206,13 +207,11 @@ class SessionStore:
 
 def default_checkpoint_root() -> str:
     """Default checkpoint location: ``REPRO_SOLVE_CHECKPOINTS`` if set, else
-    a ``solve_checkpoints`` directory next to the SpMV tune cache."""
+    ``~/.cache/repro/solve_checkpoints``."""
     env = envcfg.get_str("REPRO_SOLVE_CHECKPOINTS")
     if env:
         return env
-    from ..kernels.engine import DEFAULT_TUNE_CACHE
-
-    return os.path.join(os.path.dirname(DEFAULT_TUNE_CACHE), "solve_checkpoints")
+    return os.path.join(_CACHE_DIR, "solve_checkpoints")
 
 
 class SolveCheckpoint:

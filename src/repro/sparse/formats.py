@@ -4,13 +4,14 @@ Host-side construction is NumPy (CSR); device-side compute formats are:
 
 * ``DeviceCOO``  — (row, col, val) triplets, the pure-jnp ``segment_sum`` SpMV
   reference path; also the per-shard format of the distributed solver.
-* ``DeviceELL``  — row-tiled ELLPACK (uniform width, padded), the layout the
-  Pallas TPU kernel consumes (DESIGN.md §4).
-* ``DeviceBSR``  — blocked-ELL (uniform block-slots per block-row, padded),
-  the MXU-native layout of ``kernels/spmv_bsr.py``.
+* ``DeviceELL``  — row-tiled ELLPACK (uniform width, padded), a gather and a
+  row sum (DESIGN.md §4); ``pack_ell_chunk`` compresses host ELL chunks for
+  out-of-core staging.
+* ``DeviceBSR``  — blocked-ELL (uniform block-slots per block-row, padded):
+  a gather of ``x`` blocks and small dense products.
 * ``DeviceHybrid`` — hub-row split: ELL capped at a quantile of the row
-  lengths (Pallas kernel part) plus a COO overflow tail (``segment_sum``),
-  so power-law matrices reach the kernel path without padding blowup.
+  lengths plus a COO overflow tail (``segment_sum``), so power-law matrices
+  keep bounded padding.
 * ``DeviceSELL`` — row-length-bucketed ELL: rows cut into pieces of at most
   ``ROW_BLOCK`` entries, sorted by length into width classes, each padded
   only to its own width and stored slot-major in flat 1-D arrays; the SpMV
@@ -20,9 +21,10 @@ Host-side construction is NumPy (CSR); device-side compute formats are:
 
 All device containers are registered pytrees so they can cross ``jit`` /
 ``shard_map`` boundaries.  The ``shard_to_*`` converters build *shard-local*
-kernel layouts (uniform shapes across shards, columns remapped to the
+layouts (uniform shapes across shards, columns remapped to the
 padded-global coordinates of ``core/partition.py``) so the distributed
-engine's hot loop runs the Pallas kernels instead of ``segment_sum``.
+engine's hot loop runs the ELL / BSR / hybrid bodies instead of
+``segment_sum``.
 """
 
 from __future__ import annotations
@@ -32,6 +34,7 @@ from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import ml_dtypes
 import numpy as np
 
 __all__ = [
@@ -45,6 +48,10 @@ __all__ = [
     "to_device_coo",
     "to_device_ell",
     "to_device_bsr",
+    "blocked_ell_from_csr",
+    "PACKED_VALUE_DTYPES",
+    "SCALE_BLOCK_ROWS",
+    "pack_ell_chunk",
     "to_device_hybrid",
     "to_device_sell",
     "sell_classes",
@@ -191,8 +198,8 @@ class DeviceELL:
 
     ``val[r, s]`` / ``col[r, s]``: s-th stored entry of row r.  Padding slots
     have ``val == 0`` and ``col == 0`` (they contribute 0).  Rows are padded to
-    a multiple of ``row_tile`` and the width to a multiple of ``slot_tile`` so
-    the Pallas kernel's BlockSpec grid divides evenly.
+    a multiple of ``row_tile`` and the width to a multiple of ``slot_tile``
+    (the engine's :class:`~repro.kernels.engine.TileConfig`).
     """
 
     val: jax.Array  # (rows_padded, width) storage dtype
@@ -244,7 +251,7 @@ def to_device_coo(csr: CSR, dtype=jnp.float32) -> DeviceCOO:
 def to_device_ell(
     csr: CSR, dtype=jnp.float32, row_tile: int = 8, slot_tile: int = 128
 ) -> DeviceELL:
-    """Convert CSR to uniform-width padded ELL (kernel layout)."""
+    """Convert CSR to uniform-width padded ELL."""
     n = csr.n
     count_conversions()
     nnz_per_row = csr.row_nnz()
@@ -272,8 +279,8 @@ class DeviceHybrid:
 
     Every row stores its first ``width`` entries in the uniform ELL arrays
     (``val == 0`` / ``col == 0`` on padding slots); entries past the cap —
-    the hub rows' overflow — live as COO triplets.  SpMV is the Pallas ELL
-    kernel over the bounded part plus one ``segment_sum`` over the tail, so
+    the hub rows' overflow — live as COO triplets.  SpMV is the ELL body over
+    the bounded part plus one ``segment_sum`` over the tail, so
     the padding cost is ``n * width_cap`` instead of ``n * max_row_nnz``.
     Tail arrays are zero-padded (row 0, col 0, val 0 contributes nothing).
     """
@@ -303,7 +310,7 @@ class DeviceHybrid:
         return int(self.tail_val.shape[0])
 
     def matvec(self, x: jax.Array, accum_dtype=None) -> jax.Array:
-        """jnp reference SpMV (the Pallas path lives in ``kernels/engine.py``)."""
+        """jnp reference SpMV (the engine's body is ``kernels/engine.py``)."""
         acc = accum_dtype or self.ell_val.dtype
         gathered = jnp.take(x, self.ell_col).astype(acc)
         y = (self.ell_val.astype(acc) * gathered).sum(axis=1)[: self.n_rows]
@@ -327,7 +334,7 @@ def to_device_hybrid(
     (``kernels.engine.hybrid_width_cap`` — env-tunable via
     ``REPRO_SPMV_HYBRID_Q``).  ``slot_tile`` aligns the capped width (kept
     small by default: a 128-lane pad would reinflate exactly the padding the
-    split exists to avoid; the kernel shrinks its width tile to match).
+    split exists to avoid).
     """
     from ..kernels.engine import hybrid_width_cap  # lazy: sparse sits below kernels
 
@@ -547,7 +554,7 @@ class DeviceBSR:
 
     ``val[i, s]`` is the s-th stored block of block-row i; ``bcol[i, s]`` its
     block-column (0 on padding slots — the zero block makes padding inert).
-    This is exactly the layout ``kernels/spmv_bsr.py`` consumes.
+    ``SpmvEngine.bsr_matvec`` runs it (one gather of blocks of ``x``).
     """
 
     val: jax.Array  # (n_block_rows, slots, BS, BS) storage dtype
@@ -572,7 +579,7 @@ class DeviceBSR:
         return int(self.val.shape[1])
 
     def matvec(self, x: jax.Array, accum_dtype=None) -> jax.Array:
-        """jnp reference SpMV (the Pallas path lives in ``kernels/engine.py``)."""
+        """jnp reference SpMV (the engine's body is ``kernels/engine.py``)."""
         acc = accum_dtype or self.val.dtype
         nbr, slots, bs, _ = self.val.shape
         if x.shape[0] % bs:
@@ -642,12 +649,65 @@ def blocked_ell_from_triplets(
 
 
 def to_device_bsr(csr: CSR, block_size: int = 8, dtype=jnp.float32) -> DeviceBSR:
-    """Convert CSR to the blocked-ELL/BSR kernel layout."""
+    """Convert CSR to the blocked-ELL/BSR layout."""
     count_conversions()
     rows = np.repeat(np.arange(csr.n, dtype=np.int64), csr.row_nnz())
     return blocked_ell_from_triplets(
         rows, csr.indices, csr.data, csr.n, csr.n, block_size=block_size, dtype=dtype
     )
+
+
+def blocked_ell_from_csr(csr: CSR, block_size: int = 8, dtype=jnp.float32):
+    """CSR -> ``(val, bcol, n_rows)``: the :class:`DeviceBSR` arrays as a tuple."""
+    bsr = to_device_bsr(csr, block_size=block_size, dtype=dtype)
+    return bsr.val, bsr.bcol, bsr.n_rows
+
+
+# Compressed out-of-core staging: staging-mode name -> narrow storage dtype
+# of the packed ELL values (``SpmvEngine.packed_ell_matvec`` runs them).
+PACKED_VALUE_DTYPES = {
+    "bf16": np.dtype(ml_dtypes.bfloat16),
+    "fp8": np.dtype(ml_dtypes.float8_e4m3fn),
+}
+# Rows sharing one quantization scale (the "per-row-block" granularity).
+SCALE_BLOCK_ROWS = 8
+
+
+def pack_ell_chunk(val: np.ndarray, col: np.ndarray, mode: str):
+    """Quantize + delta-encode one host-side ELL chunk.
+
+    Returns host arrays ``(val_packed, scale, base, dcol)``: values in the
+    mode's narrow dtype, ``scale`` (rows, 1) f32 computed over row blocks of
+    ``SCALE_BLOCK_ROWS`` rows (max-abs mapped to the dtype's finite range
+    for fp8; bf16 shares f32's exponent range so its scale is 1) and
+    expanded per row, ``base`` (rows, 1) int32 first stored column of each
+    row, and ``dcol`` the column deltas (``dcol[r, 0] == 0``), narrowed to
+    int16 when every delta fits, else int32.  The columns are recovered as
+    ``base + cumsum(dcol, axis=1)``.
+    """
+    vdt = PACKED_VALUE_DTYPES.get(mode)
+    if vdt is None:
+        raise ValueError(
+            f"unknown packed staging mode {mode!r}; expected {tuple(PACKED_VALUE_DTYPES)}"
+        )
+    rows, width = val.shape
+    if rows % SCALE_BLOCK_ROWS:
+        raise ValueError(
+            f"packed chunk rows {rows} must be a multiple of {SCALE_BLOCK_ROWS}"
+        )
+    v64 = np.asarray(val, dtype=np.float64)
+    if mode == "fp8":
+        absmax = np.abs(v64).reshape(rows // SCALE_BLOCK_ROWS, -1).max(axis=1)
+        fmax = float(ml_dtypes.finfo(vdt).max)
+        block_scale = np.where(absmax > 0, absmax / fmax, 1.0)
+    else:
+        block_scale = np.ones(rows // SCALE_BLOCK_ROWS, dtype=np.float64)
+    scale = np.repeat(block_scale, SCALE_BLOCK_ROWS).astype(np.float32).reshape(rows, 1)
+    val_packed = (v64 / scale).astype(vdt)
+    base = np.ascontiguousarray(col[:, :1], dtype=np.int32)
+    dcol32 = np.diff(col.astype(np.int64), axis=1, prepend=base.astype(np.int64))
+    idt = np.int16 if np.abs(dcol32).max(initial=0) < (1 << 15) else np.int32
+    return val_packed, scale, base, dcol32.astype(idt)
 
 
 def padded_col_map(splits: np.ndarray, n_pad: int, n: int) -> np.ndarray:

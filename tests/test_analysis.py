@@ -250,10 +250,10 @@ class S:
 def test_e001_red_raw_env_read():
     src = """
 import os
-a = os.environ.get("REPRO_SPMV_TUNE")
+a = os.environ.get("REPRO_PRECISION_MEASURE")
 b = os.getenv("REPRO_FAULT")
-c = os.environ["REPRO_ITER_UPDATE"]
-os.environ["REPRO_SPMV_TUNE"] = "1"      # write: allowed
+c = os.environ["REPRO_FUSED_LANCZOS"]
+os.environ["REPRO_PRECISION_MEASURE"] = "1"      # write: allowed
 os.environ.setdefault("REPRO_FAULT", "") # write: allowed
 d = os.environ.get("HOME")               # not a knob: allowed
 """
@@ -275,12 +275,12 @@ def test_env_registry_contract():
 
     with pytest.raises(KeyError):
         envcfg.knob("REPRO_NOT_A_KNOB")
-    assert envcfg.get_bool("REPRO_SPMV_TUNE") is False
-    os.environ["REPRO_SPMV_TUNE"] = "on"
+    assert envcfg.get_bool("REPRO_PRECISION_MEASURE") is False
+    os.environ["REPRO_PRECISION_MEASURE"] = "on"
     try:
-        assert envcfg.get_bool("REPRO_SPMV_TUNE") is True
+        assert envcfg.get_bool("REPRO_PRECISION_MEASURE") is True
     finally:
-        del os.environ["REPRO_SPMV_TUNE"]
+        del os.environ["REPRO_PRECISION_MEASURE"]
     assert envcfg.get_float("REPRO_ANALYSIS_VMEM_MB") == 16.0
 
 

@@ -177,15 +177,14 @@ def test_fused_update_policy_gating():
 
 
 def test_update_mode_table_default(monkeypatch):
-    """With no plan and no env pins, interpret mode defaults to the unfused
-    update (measured: the Pallas interpreter loses on per-step overhead)."""
+    """With no pin, interpret mode defaults to the unfused update (measured:
+    the Pallas interpreter loses on per-step overhead)."""
     from repro.core.lanczos import make_local_ops, resolve_update_mode
 
     monkeypatch.delenv("REPRO_FUSED_LANCZOS", raising=False)
-    monkeypatch.delenv("REPRO_ITER_UPDATE", raising=False)
     assert resolve_update_mode(FFF.effective()) == "unfused"
     ops = make_local_ops(lambda x: x, FFF)
-    assert ops.fused_update is None and ops.fused_iteration is None
+    assert ops.fused_update is None
 
 
 def test_fused_update_kill_switch(monkeypatch):
@@ -199,7 +198,7 @@ def test_fused_update_kill_switch(monkeypatch):
 @pytest.mark.parametrize(
     "reorth, matrix", [("none", "norm_csr"), ("half", "web_csr"), ("full", "web_csr")]
 )
-def test_fused_lanczos_matches_reference_loop(request, reorth, matrix, monkeypatch):
+def test_fused_lanczos_matches_reference_loop(request, reorth, matrix):
     """Loop parity: the fused-kernel recurrence (and, with reorth='none',
     its fused norm) reproduces the unfused reference loop.  The kernel sums
     the norm lane by lane, in another order than ``jnp.sum``; only the
@@ -209,13 +208,20 @@ def test_fused_lanczos_matches_reference_loop(request, reorth, matrix, monkeypat
     normalized matrix), so that case runs on the normalized matrix.  With
     reorthogonalization the norm is recomputed, and the badly scaled unit
     adjacency stays."""
-    from repro.api import eigsh
+    from repro.core import make_operator, solve_fixed
+    from repro.core.lanczos import ops_for_operator
+    from repro.kernels.engine import make_engine
 
     csr = request.getfixturevalue(matrix)
-    monkeypatch.setenv("REPRO_FUSED_LANCZOS", "1")  # force the fused update
-    r_fused = eigsh(csr, 4, num_iters=12, policy="FFF", reorth=reorth, seed=3)
-    monkeypatch.setenv("REPRO_FUSED_LANCZOS", "0")
-    r_ref = eigsh(csr, 4, num_iters=12, policy="FFF", reorth=reorth, seed=3)
+    op = make_operator(csr, dtype=jnp.float32, engine=make_engine(csr))
+    pol = FFF.effective()
+
+    def solve(fused):
+        ops = ops_for_operator(op, pol, fused=fused)
+        assert (ops.fused_update is not None) == fused
+        return solve_fixed(op, 4, pol, num_iters=12, reorth=reorth, seed=3, ops=ops)
+
+    r_fused, r_ref = solve(True), solve(False)
     np.testing.assert_allclose(
         np.asarray(r_fused.eigenvalues), np.asarray(r_ref.eigenvalues),
         rtol=2e-5, atol=2e-5,
@@ -226,7 +232,7 @@ def test_fused_update_wired_into_loop(monkeypatch):
     """The jitted loop actually calls the kernel wrapper when permitted (the
     call is observed at trace time) and skips it when the policy forbids."""
     from repro.core import FCF
-    from repro.core.lanczos import lanczos_tridiag
+    from repro.core.lanczos import lanczos_tridiag, make_local_ops
     from repro.kernels import ops as kops
 
     calls = []
@@ -237,12 +243,17 @@ def test_fused_update_wired_into_loop(monkeypatch):
         return real(*a, **k)
 
     monkeypatch.setattr(kops, "lanczos_update", spy)
-    monkeypatch.setenv("REPRO_FUSED_LANCZOS", "1")  # force-enable: no plan here
     a = np.diag(np.arange(1.0, 17.0))
     mv = lambda x: jnp.asarray(a, x.dtype) @ x  # noqa: E731
     v1 = jnp.ones((16,), jnp.float32)
-    lanczos_tridiag(mv, v1, 4, FFF, reorth="half", jit=False)
+
+    def run(pol):
+        pol = pol.effective()
+        ops = make_local_ops(mv, pol, fused=True)  # pin: the interpreter's table is unfused
+        lanczos_tridiag(mv, v1, 4, pol, reorth="half", jit=False, ops=ops)
+
+    run(FFF)
     assert calls  # fused path traced/executed
     calls.clear()
-    lanczos_tridiag(mv, v1, 4, FCF, reorth="half", jit=False)
+    run(FCF)
     assert not calls  # compensated policy keeps the reference recurrence

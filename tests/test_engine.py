@@ -163,24 +163,15 @@ def test_make_engine_validates_format():
 
 
 def test_tile_table_scales_with_shape():
-    small = select_tiles(512, 64, interpret=False)
-    large = select_tiles(1 << 20, 4096, interpret=False)
+    small = select_tiles(512, 64)
+    large = select_tiles(1 << 20, 4096)
     assert large.block_r >= small.block_r
     assert large.block_w >= small.block_w
 
 
 def test_tile_table_16bit_sublane_minimum():
-    t = select_tiles(512, 64, dtype=jnp.bfloat16, interpret=False)
+    t = select_tiles(512, 64, dtype=jnp.bfloat16)
     assert t.block_r >= 16
-
-
-def test_tile_env_override(monkeypatch):
-    monkeypatch.setenv("REPRO_SPMV_TILES", "64,256,16")
-    t = select_tiles(1 << 20, 4096, interpret=False)
-    assert t == TileConfig(block_r=64, block_w=256, block_size=16)
-    monkeypatch.setenv("REPRO_SPMV_TILES", "not,numbers")
-    with pytest.raises(ValueError):
-        select_tiles(64, 64)
 
 
 # --------------------- cross-format SpMV agreement ---------------------------
@@ -271,7 +262,7 @@ def test_shard_to_blocked_ell_requires_alignment():
 
 
 def test_to_device_bsr_matches_legacy_tuple():
-    from repro.kernels.spmv_bsr import blocked_ell_from_csr
+    from repro.sparse.formats import blocked_ell_from_csr
 
     csr = generate("road", 484, 3.0, seed=11, values="uniform")
     bsr = to_device_bsr(csr, block_size=8, dtype=jnp.float32)
@@ -484,116 +475,6 @@ def test_chunked_rejects_hybrid():
         ChunkedOperator(csr, engine=engine)
 
 
-# ------------------------------ tile autotuner -------------------------------
-
-
-@pytest.fixture
-def tune_env(tmp_path, monkeypatch):
-    """Isolated tuner: fresh JSON cache path + enabled tuning."""
-    import repro.kernels.engine as eng_mod
-
-    cache = tmp_path / "spmv_tune.json"
-    monkeypatch.setenv("REPRO_SPMV_TUNE_CACHE", str(cache))
-    monkeypatch.setenv("REPRO_SPMV_TUNE", "1")
-    monkeypatch.setenv("REPRO_SPMV_TUNE_BUDGET", "2")
-    eng_mod._TUNER = None
-    yield cache
-    eng_mod._TUNER = None
-
-
-def test_autotuner_disabled_is_static_table(monkeypatch):
-    """Cold start with tuning off: behavior identical to the static table
-    (interpret-mode large tiles preserved), provenance 'table'."""
-    monkeypatch.delenv("REPRO_SPMV_TUNE", raising=False)
-    csr = banded_csr(256)
-    e = make_engine(csr, "ell")
-    assert e.tiles_from == "table"
-    assert e.tiles == TileConfig(block_r=512, block_w=2048)  # interpret tiles
-    assert e.describe()["tiles_from"] == "table"
-
-
-def test_autotuner_tunes_caches_and_persists(tune_env):
-    import json
-
-    import repro.kernels.engine as eng_mod
-
-    csr = banded_csr(256)
-    e1 = make_engine(csr, "ell")
-    tuner = eng_mod.get_tuner()
-    assert e1.tiles_from == "tuned"
-    # two probe passes: SpMV tiles + the whole-iteration plan
-    assert tuner.measure_count == 2
-    assert tune_env.exists()
-    payload = json.loads(tune_env.read_text())
-    assert payload["version"] == 2 and len(payload["entries"]) == 2
-    rec = next(r for r in payload["entries"].values() if r.get("kind") != "iteration")
-    assert rec["block_r"] == e1.tiles.block_r and rec["block_w"] == e1.tiles.block_w
-    assert rec["grid"] == eng_mod.grid_fingerprint()
-    # same shape bucket: memoized, no second measurement
-    e2 = make_engine(csr, "ell")
-    assert tuner.measure_count == 2 and e2.tiles == e1.tiles
-
-
-def test_autotuner_frozen_cache_is_deterministic(tune_env, monkeypatch):
-    """A pre-written cache is authoritative: no measurement runs (probes are
-    poisoned) and the pinned tiles come back verbatim."""
-    import json
-
-    import repro.kernels.engine as eng_mod
-
-    # width is the *layout* width the engine probes: banded max_row 5 pads
-    # to the 128-lane ELL tile.  Entries carry the live grid fingerprint —
-    # unstamped or stale entries are (correctly) dropped and re-measured.
-    key = eng_mod._tune_key("ell", jnp.float32, 256, 128, interpret=True)
-    fp = eng_mod.grid_fingerprint()
-    tune_env.write_text(
-        json.dumps(
-            {
-                "version": 2,
-                "entries": {
-                    key: {"block_r": 128, "block_w": 1024, "grid": fp},
-                    "iter|" + key: {
-                        "kind": "iteration",
-                        "update": "unfused",
-                        "block_r": 128,
-                        "block_w": 1024,
-                        "block_size": 8,
-                        "grid": fp,
-                    },
-                },
-            }
-        )
-    )
-
-    def _poisoned(*a, **k):
-        raise AssertionError("a frozen tune cache must not re-measure")
-
-    monkeypatch.setattr(eng_mod, "_measure_ell_tiles", _poisoned)
-    monkeypatch.setattr(eng_mod, "_measure_iteration", _poisoned)
-    e = make_engine(banded_csr(256), "ell")
-    assert e.tiles_from == "tuned"
-    assert (e.tiles.block_r, e.tiles.block_w) == (128, 1024)
-    assert e.iteration_plan.update == "unfused" and e.iteration_plan.source == "tuned"
-
-
-def test_autotuner_override_wins(tune_env, monkeypatch):
-    monkeypatch.setenv("REPRO_SPMV_TILES", "64,256")
-    e = make_engine(banded_csr(256), "ell")
-    assert e.tiles_from == "override"
-    assert (e.tiles.block_r, e.tiles.block_w) == (64, 256)
-
-
-def test_autotuner_solve_end_to_end(tune_env):
-    """A tuned engine still solves correctly and surfaces provenance."""
-    road = generate("road", 400, 3.0, seed=3, values="normalized")
-    r_t = eigsh(road, 3, num_iters=9, format="ell")
-    assert r_t.spmv_format == "ell"
-    r_ref = eigsh(road, 3, num_iters=9, format="coo")
-    np.testing.assert_allclose(
-        np.asarray(r_t.eigenvalues), np.asarray(r_ref.eigenvalues), rtol=1e-4
-    )
-
-
 # ------------------------- chunked double buffering --------------------------
 
 
@@ -699,13 +580,12 @@ def test_shard_stats_use_remapped_block_coordinates():
     [
         # compiled (TPU): the SpMV is an XLA gather, the update a Mosaic kernel
         ("ell", False, "fused", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
-        ("hybrid", False, "fused_spmv", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
+        ("hybrid", False, "fused", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
         ("coo", False, "fused", jnp.float32, {"spmv": "xla", "update": "mosaic"}),
         ("bsr", False, "unfused", jnp.float32, {"spmv": "xla", "update": "xla"}),
         ("ell", False, "fused", jnp.float64, {"spmv": "xla", "update": "xla"}),
-        # interpreter (CPU): every kernel runs interpreted
-        ("ell", True, "fused", jnp.float32,
-         {"spmv": "pallas_interpret", "update": "pallas_interpret"}),
+        # interpreter (CPU): the same SpMV body, the update kernel interpreted
+        ("ell", True, "fused", jnp.float32, {"spmv": "xla", "update": "pallas_interpret"}),
         ("coo", True, "unfused", jnp.float32, {"spmv": "xla", "update": "xla"}),
     ],
 )
@@ -732,11 +612,121 @@ def test_phase_executors_report_what_gathered_sells_x(interpret, gather):
 )
 def test_partition_reports_phase_executors(norm_csr, monkeypatch, pin, tol, want_update):
     """partition["spmv"]["kernels"] reports what ran; the restarted engine
-    (any tol) runs its own jnp update whatever the plan says."""
+    (any tol) runs its own jnp update whatever the mode table says.  The
+    table is pinned to ``pin`` (``"fused"`` is what compiled execution
+    picks, here run by the interpreter)."""
+    import repro.kernels.engine as eng_mod
     from repro.api import session_cache_clear
 
-    monkeypatch.setenv("REPRO_ITER_UPDATE", pin)
+    monkeypatch.setattr(eng_mod, "table_update_mode", lambda interpret: pin)
     session_cache_clear()
     r = eigsh(norm_csr, 4, format="ell", num_iters=24, tol=tol, policy="FFF", seed=1)
     session_cache_clear()
-    assert r.partition["spmv"]["kernels"] == {"spmv": "pallas_interpret", "update": want_update}
+    assert r.partition["spmv"]["kernels"] == {"spmv": "xla", "update": want_update}
+
+
+@pytest.mark.parametrize("mode", ["fused"])
+def test_update_modes_bit_identical_eigenvalues(mode, monkeypatch):
+    """Routing is a pure performance decision: for a uniform policy the
+    fused update returns the *same bits* as the unfused one."""
+    import repro.kernels.engine as eng_mod
+    from repro.api import session_cache_clear
+
+    csr = generate("web", 512, 6.0, seed=5, values="normalized")
+    monkeypatch.delenv("REPRO_FUSED_LANCZOS", raising=False)
+    vals = {}
+    for m in ("unfused", mode):
+        monkeypatch.setattr(eng_mod, "table_update_mode", lambda interpret, m=m: m)
+        session_cache_clear()
+        r = eigsh(csr, 4, num_iters=16, policy="FFF", reorth="full", seed=7)
+        assert r.partition["spmv"]["kernels"]["update"] == (
+            "xla" if m == "unfused" else "pallas_interpret"
+        )
+        vals[m] = np.asarray(r.eigenvalues)
+    session_cache_clear()
+    np.testing.assert_array_equal(vals["unfused"], vals[mode])
+
+
+@pytest.mark.parametrize("interpret", [True, False], ids=["interpret", "compiled"])
+@pytest.mark.parametrize("gate", ["open", "kill_switch", "compensated"])
+@pytest.mark.parametrize("pin", [None, True, False], ids=["no_pin", "pin_fused", "pin_unfused"])
+def test_resolve_update_mode_rule(pin, gate, interpret, monkeypatch):
+    """The update mode: a ``fused=`` pin, within the policy gate (the
+    compensated policy, the ``REPRO_FUSED_LANCZOS=0`` kill switch), else the
+    execution mode (compiled -> fused, interpreter -> unfused)."""
+    from repro.core import FCF, FFF
+    from repro.core.lanczos import resolve_update_mode
+
+    monkeypatch.delenv("REPRO_FUSED_LANCZOS", raising=False)
+    if gate == "kill_switch":
+        monkeypatch.setenv("REPRO_FUSED_LANCZOS", "0")
+    pol = (FCF if gate == "compensated" else FFF).effective()
+    got = resolve_update_mode(pol, fused=pin, interpret=interpret)
+    if gate != "open" or pin is False:
+        want = "unfused"
+    elif pin is True:
+        want = "fused"
+    else:
+        want = "unfused" if interpret else "fused"
+    assert got == want
+
+
+@pytest.mark.parametrize("wide", [False, True], ids=["int16", "int32"])
+@pytest.mark.parametrize("mode", ["bf16", "fp8"])
+def test_packed_ell_matvec_matches_dense(mode, wide):
+    """Compressed staging: ``pack_ell_chunk`` then ``packed_ell_matvec``
+    equals the float64 product of the dequantised values, with the columns
+    delta-decoded exactly (int16 deltas where they fit, int32 where a row
+    spans more than 2^15 columns)."""
+    from repro.sparse.formats import PACKED_VALUE_DTYPES, pack_ell_chunk
+
+    rows, width = 64, 8
+    n = 70_000 if wide else 4096
+    rng = np.random.default_rng(11)
+    col = np.sort(rng.choice(n, size=(rows, width)), axis=1).astype(np.int32)
+    if wide:
+        col[:, -1] = n - 1 - rng.integers(0, 8, rows)  # every row spans > 2^15
+        col[:, 0] = rng.integers(0, 8, rows)
+    val = rng.standard_normal((rows, width)).astype(np.float32)
+    val[-3:, -2:] = 0.0  # padding slots contribute nothing
+    packed, scale, base, dcol = pack_ell_chunk(val, col, mode)
+    assert packed.dtype == PACKED_VALUE_DTYPES[mode]
+    assert dcol.dtype == (np.int32 if wide else np.int16)
+    x = rng.standard_normal(n).astype(np.float32)
+    engine = make_engine(banded_csr(64), "ell", accum_dtype=jnp.float32)
+    y = engine.packed_ell_matvec(
+        jnp.asarray(packed), jnp.asarray(scale), jnp.asarray(base), jnp.asarray(dcol),
+        jnp.asarray(x),
+    )
+    dense = np.zeros((rows, n))
+    deq = packed.astype(np.float64) * scale.astype(np.float64)
+    np.add.at(dense, (np.repeat(np.arange(rows), width), col.reshape(-1)), deq.reshape(-1))
+    np.testing.assert_allclose(np.asarray(y, np.float64), dense @ x, rtol=1e-5, atol=1e-5)
+
+
+def test_plan_with_old_tuner_keys_imports_and_solves_bit_equal():
+    """A plan exported before the tuners were removed carries
+    ``iteration_plan`` / ``tiles_from`` in its engine record: the layout is
+    the same, so the import ignores them and the solve is bit-equal."""
+    from repro.api import EigenSession, SolverConfig
+
+    csr = banded_csr(256, bandwidth=2, seed=1)
+    cfg = SolverConfig(backend="single", format="ell", reorth="full", policy="FFF")
+    s1 = EigenSession(csr, cfg)
+    s1.warmup()
+    state = s1.export_state()
+    r1 = s1.eigsh(k=3, num_iters=12)
+    for plan in state["plans"]:
+        eng = plan["engine"]
+        assert "iteration_plan" not in eng and "tiles_from" not in eng
+        eng["tiles_from"] = "tuned"
+        eng["iteration_plan"] = {
+            "update": "fused", "block_r": 512, "block_w": 2048, "block_size": 8,
+            "source": "tuned",
+        }
+    s2 = EigenSession(csr, cfg)
+    assert s2.import_plans(state) == len(state["plans"])
+    r2 = s2.eigsh(k=3, num_iters=12)
+    assert r2.session_reuse and r2.partition["spmv"]["conversions"] == 0
+    assert r2.partition["spmv"]["block_r"] == TileConfig(**state["plans"][0]["engine"]["tiles"]).block_r
+    np.testing.assert_array_equal(np.asarray(r2.eigenvalues), np.asarray(r1.eigenvalues))

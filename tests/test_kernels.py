@@ -1,46 +1,61 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret mode) vs pure-jnp oracle."""
+"""The vector Pallas kernels (interpret mode) vs their pure-jnp oracles, and
+the SpMV bodies the engines run, in both modes, vs a float64 SciPy product."""
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.kernels import ops, ref
+from repro.kernels.engine import TileConfig, make_engine
 from repro.kernels.lanczos_update import lanczos_update_kernel_call
 from repro.kernels.mixed_dot import mixed_dot_kernel_call
-from repro.kernels.spmv_ell import spmv_ell_kernel_call
 from repro.sparse import generate, to_device_ell
+from repro.sparse.formats import blocked_ell_from_csr
 
 DTYPES = [jnp.float32, jnp.bfloat16]
 TOL = {jnp.float32: 2e-5, jnp.bfloat16: 2e-2}
+
+
+def _scipy_product(csr, x, dtype):
+    """float64 SciPy product of the matrix and ``x`` as they are stored."""
+    a = csr.to_scipy().astype(np.float64)
+    a.data = np.asarray(jnp.asarray(a.data, dtype), np.float64)
+    return a @ np.asarray(x, np.float64)
 
 
 @pytest.mark.parametrize("n", [512, 2048])
 @pytest.mark.parametrize("deg", [2.0, 10.0])
 @pytest.mark.parametrize("dtype", DTYPES)
 def test_spmv_ell_kernel_sweep(n, deg, dtype):
+    """``SpmvEngine.ell_matvec`` over the padded layout, f32 accumulation."""
     csr = generate("urand", n, deg, seed=int(deg) + n, values="uniform")
     ell = to_device_ell(csr, dtype=dtype)
-    x = jnp.asarray(np.random.default_rng(0).standard_normal(ell.val.shape[0]), dtype=dtype)
-    # note: cols index into [0, n) but x padded len == rows_pad >= n: slice ok
-    y_k = spmv_ell_kernel_call(ell.val, ell.col, x, interpret=True)
-    y_r = ref.spmv_ell_ref(ell.val, ell.col, x)
+    engine = make_engine(csr, "ell", accum_dtype=jnp.float32)
+    x = jnp.asarray(np.random.default_rng(0).standard_normal(csr.n), dtype=dtype)
+    y = engine.ell_matvec(ell.val, ell.col, x)[: csr.n]
     np.testing.assert_allclose(
-        np.asarray(y_k, np.float64), np.asarray(y_r, np.float64),
-        rtol=TOL[dtype], atol=TOL[dtype] * 10,
+        np.asarray(y, np.float64), _scipy_product(csr, x, dtype),
+        rtol=TOL[jnp.float32], atol=TOL[jnp.float32] * 10,
     )
 
 
 @pytest.mark.parametrize("block_r,block_w", [(8, 128), (8, 512), (16, 256)])
 def test_spmv_ell_block_shapes(block_r, block_w):
+    """The layout padding of a ``TileConfig`` changes the shape, not the
+    product: rows padded to ``block_r``, widths to ``block_w``."""
     csr = generate("web", 1024, 6.0, seed=1, values="uniform")
-    ell = to_device_ell(csr, dtype=jnp.float32, row_tile=16, slot_tile=512)
-    x = jnp.asarray(np.random.default_rng(1).standard_normal(ell.val.shape[0]), jnp.float32)
-    y_k = spmv_ell_kernel_call(
-        ell.val, ell.col, x, block_r=block_r, block_w=block_w, interpret=True
+    ell = to_device_ell(csr, dtype=jnp.float32, row_tile=block_r, slot_tile=block_w)
+    assert ell.val.shape[0] % block_r == 0 and ell.val.shape[1] % block_w == 0
+    engine = make_engine(
+        csr, "ell", tiles=TileConfig(block_r=block_r, block_w=block_w)
     )
-    y_r = ref.spmv_ell_ref(ell.val, ell.col, x)
-    np.testing.assert_allclose(np.asarray(y_k), np.asarray(y_r), rtol=2e-5, atol=1e-4)
+    x = jnp.asarray(np.random.default_rng(1).standard_normal(csr.n), jnp.float32)
+    y = engine.ell_matvec(ell.val, ell.col, x)
+    assert y.shape == (ell.val.shape[0],)
+    np.testing.assert_allclose(
+        np.asarray(y, np.float64)[: csr.n], _scipy_product(csr, x, jnp.float32),
+        rtol=2e-5, atol=1e-4,
+    )
 
 
 @pytest.mark.parametrize("n", [1024, 16384])
@@ -88,46 +103,46 @@ def test_lanczos_update_kernel_sweep(n, dtype):
 
 def test_ops_wrappers_dispatch(web_csr):
     """ops.py wrappers: kernel path (f32) and jnp fallback (f64) both correct."""
-    ell = to_device_ell(web_csr, dtype=jnp.float32)
-    x = jnp.asarray(np.random.default_rng(3).standard_normal(ell.val.shape[0]), jnp.float32)
-    y32 = ops.spmv_ell(ell, x, accum_dtype=jnp.float32)
-    y64 = ops.spmv_ell(ell, x[: ell.n_rows], accum_dtype=jnp.float64)
-    np.testing.assert_allclose(
-        np.asarray(y32, np.float64),
-        np.asarray(y64, np.float64)[: y32.shape[0]],
-        rtol=1e-4,
-        atol=1e-4,
-    )
+    rng = np.random.default_rng(3)
+    a, b = (jnp.asarray(rng.standard_normal(web_csr.n), jnp.float32) for _ in range(2))
+    d32 = ops.mixed_dot(a, b, accum_dtype=jnp.float32)
+    d64 = ops.mixed_dot(a, b, accum_dtype=jnp.float64)
+    assert d32.dtype == jnp.float32 and d64.dtype == jnp.float64
+    np.testing.assert_allclose(float(d32), float(d64), rtol=1e-4, atol=1e-4)
+    alpha, beta = jnp.float32(0.37), jnp.float32(1.21)
+    u32, n32 = ops.lanczos_update(a, b, a, alpha, beta, accum_dtype=jnp.float32)
+    u64, n64 = ops.lanczos_update(a, b, a, alpha, beta, accum_dtype=jnp.float64)
+    np.testing.assert_allclose(np.asarray(u32, np.float64), np.asarray(u64, np.float64),
+                               rtol=1e-5, atol=1e-5)
+    np.testing.assert_allclose(float(n32), float(n64), rtol=1e-4)
 
 
 @pytest.mark.parametrize("bs", [4, 8])
 @pytest.mark.parametrize("kind", ["road", "urand"])
 def test_spmv_bsr_kernel(bs, kind):
-    from repro.kernels import ops
-    from repro.kernels.spmv_bsr import blocked_ell_from_csr
-
+    """``SpmvEngine.bsr_matvec`` over the blocked-ELL layout."""
     csr = generate(kind, 512, 3.0, seed=bs, values="uniform")
-    blocked = blocked_ell_from_csr(csr, block_size=bs, dtype=jnp.float32)
+    val, bcol, n_rows = blocked_ell_from_csr(csr, block_size=bs, dtype=jnp.float32)
+    engine = make_engine(csr, "bsr", block_size=bs)
     x = jnp.asarray(np.random.default_rng(7).standard_normal(csr.n), jnp.float32)
-    y_k = ops.spmv_bsr(blocked, x, accum_dtype=jnp.float32, interpret=True)
-    y_ref = csr.to_scipy() @ np.asarray(x, dtype=np.float64)
-    np.testing.assert_allclose(np.asarray(y_k, np.float64), y_ref, rtol=2e-5, atol=1e-4)
+    y = engine.bsr_matvec(val, bcol, x)[:n_rows]
+    np.testing.assert_allclose(
+        np.asarray(y, np.float64), _scipy_product(csr, x, jnp.float32), rtol=2e-5, atol=1e-4
+    )
 
 
 def test_spmv_bsr_eigensolver_path():
-    """Full Top-K solve through the MXU blocked-ELL SpMV engine.
+    """Full Top-K solve through the blocked-ELL SpMV engine.
 
     Uses a road-lattice matrix: block-local structure keeps the slot count
-    (and hence the interpret-mode grid) small — the regime BSR targets.
+    small — the regime BSR targets.
     """
-    from repro.core import FFF, make_operator, topk_eigs
+    from repro.api import eigsh
 
     csr = generate("road", 484, 3.0, seed=11, values="normalized")
-    v1 = jnp.ones((csr.n,), jnp.float64)
-    r_coo = topk_eigs(make_operator(csr, "coo"), 3, policy=FFF, reorth="full",
-                      num_iters=9, v1=v1)
-    r_bsr = topk_eigs(make_operator(csr, "bsr_kernel"), 3, policy=FFF, reorth="full",
-                      num_iters=9, v1=v1)
+    r_coo = eigsh(csr, 3, policy="FFF", reorth="full", num_iters=9, seed=0, format="coo")
+    r_bsr = eigsh(csr, 3, policy="FFF", reorth="full", num_iters=9, seed=0, format="bsr")
+    assert r_bsr.spmv_format == "bsr"
     np.testing.assert_allclose(
         np.asarray(r_coo.eigenvalues), np.asarray(r_bsr.eigenvalues), rtol=1e-4
     )

@@ -9,11 +9,11 @@ topology is described inside a module fixture, never at import, so every
 test worker collects the same tests and only the one that runs this file
 loads the TPU compiler.
 
-The last tests trace (no compile) a TPU-mode engine — ``interpret=False`` —
-through every format and update mode, and check that it dispatches only the
-kernels Mosaic accepts (``lanczos_update``, ``mixed_dot``, and
-``sell_gather`` for the ``"sell"`` layout): the ELL / BSR SpMV kernels
-gather from a VMEM-resident ``x`` and run only under the interpreter.
+The last tests trace (no compile) an engine, compiled (``interpret=False``)
+and interpreted, through every format and update mode, and check that it
+dispatches only the kernels Mosaic accepts (``lanczos_update``,
+``mixed_dot``, and ``sell_gather`` for the ``"sell"`` layout), the same in
+both modes but for the interpret flag.
 """
 
 import functools
@@ -30,7 +30,7 @@ from repro.core.lanczos import _lanczos_loop, ops_for_operator
 from repro.core.operators import SparseOperator, make_operator
 from repro.core.precision import FFF
 from repro.kernels import ops as kops
-from repro.kernels.engine import FORMATS, ITER_UPDATE_MODES, make_engine
+from repro.kernels.engine import FORMATS, ITER_UPDATE_MODES, make_engine, table_update_mode
 from repro.sparse import generate
 from repro.sparse.formats import (
     DeviceHybrid,
@@ -103,7 +103,7 @@ def test_lanczos_sweep_compiles_at_wk_width(one_chip):
     engine = make_engine(
         generate("web", 4096, 12.6, seed=0), "hybrid", interpret=False
     )
-    assert engine.iteration_plan.update == "fused"
+    assert table_update_mode(engine.interpret) == "fused"
     pol = FFF.effective()
     rows_pad = -(-WK_N // engine.tiles.block_r) * engine.tiles.block_r
 
@@ -233,7 +233,7 @@ def test_sell_sums_compile_alike_with_either_gather(one_chip, monkeypatch):
     assert kernel == xla
 
 
-# ------------------------------------------- what a TPU-mode engine dispatches
+# ---------------------------------------------------- what an engine dispatches
 
 
 def _kernels_in(fn, *args):
@@ -244,34 +244,36 @@ def _kernels_in(fn, *args):
     }
 
 
+@pytest.mark.parametrize("interpret", [False, True], ids=["compiled", "interpret"])
 @pytest.mark.parametrize("update", ITER_UPDATE_MODES)
 @pytest.mark.parametrize("fmt", FORMATS)
-def test_tpu_engine_dispatches_only_mosaic_kernels(fmt, update, monkeypatch):
-    monkeypatch.setenv("REPRO_ITER_UPDATE", update)  # pin, even fused_spmv
+def test_tpu_engine_dispatches_only_mosaic_kernels(fmt, update, interpret):
     csr = generate("web", 512, 6.0, seed=3, values="normalized")
-    engine = make_engine(csr, fmt, interpret=False)
+    engine = make_engine(csr, fmt, interpret=interpret)
     op = make_operator(csr, engine=engine)
-    ops = ops_for_operator(op, FFF.effective())
+    ops = ops_for_operator(op, FFF.effective(), fused=update == "fused")
     v1 = jnp.ones((csr.n,), jnp.float32)
     found = _kernels_in(lambda v: _lanczos_loop(v, ops, 8, FFF.effective(), "half").alpha, v1)
     assert {name for name, _ in found} <= MOSAIC_KERNELS, found
-    assert not any(interp for _, interp in found), found
-    if update != "unfused":  # the compiled update kernel is really used
-        assert ("lanczos_update", False) in found
-    # the "sell" SpMV gathers its float32 x with the compiled kernel
-    assert (("sell_gather", False) in found) == (fmt == "sell")
+    # one mode or the other, never a mix: only the interpret flag differs
+    assert {interp for _, interp in found} <= {interpret}, found
+    if update != "unfused":  # the update kernel is really used
+        assert ("lanczos_update", interpret) in found
+    # the "sell" SpMV gathers its float32 x with the kernel; no other SpMV
+    # dispatches one
+    assert (("sell_gather", interpret) in found) == (fmt == "sell")
 
 
 def test_tpu_engine_spmv_layouts_run_as_xla():
-    """Every raw-array SpMV entry of a TPU-mode engine traces to no kernel,
-    while the same engine in interpret mode does reach its kernel."""
+    """Every raw-array SpMV entry of an engine traces to no kernel, compiled
+    or interpreted."""
     csr = generate("web", 512, 6.0, seed=3, values="normalized")
     ell = to_device_ell(csr, row_tile=32)
     x = jnp.ones((csr.n,), jnp.float32)
     tpu = make_engine(csr, "ell", interpret=False)
     cpu = make_engine(csr, "ell", interpret=True)
     assert _kernels_in(lambda v: tpu.ell_matvec(ell.val, ell.col, v), x) == set()
-    assert _kernels_in(lambda v: cpu.ell_matvec(ell.val, ell.col, v), x) == {("spmv_ell", True)}
+    assert _kernels_in(lambda v: cpu.ell_matvec(ell.val, ell.col, v), x) == set()
     rows, width = ell.val.shape
     scale = jnp.ones((rows, 1), jnp.float32)
     base = jnp.zeros((rows, 1), jnp.int32)

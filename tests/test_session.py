@@ -28,7 +28,6 @@ from repro.api import coerce
 from repro.api.session import policy_key
 from repro.core import FDF, POLICIES
 from repro.core.metrics import eigsh_reference
-from repro.kernels.engine import get_tuner, tuner_probe_count
 from repro.sparse import generate
 from repro.sparse.formats import CSR, conversion_count
 
@@ -237,29 +236,13 @@ def test_byte_identical_recall_is_zero_conversion(small_csr):
     r1 = eigsh(small_csr, K, policy="FDF", num_iters=ITERS)
     assert not r1.session_reuse
     assert r1.partition["spmv"]["conversions"] >= 1
-    c0, p0 = conversion_count(), tuner_probe_count()
+    c0 = conversion_count()
     r2 = eigsh(small_csr, K, policy="FDF", num_iters=ITERS)
     assert r2.session_reuse
     assert conversion_count() == c0  # zero format conversions
-    assert tuner_probe_count() == p0  # zero tuner probes
     assert r2.partition["spmv"]["conversions"] == 0
-    assert r2.partition["spmv"]["tuner_probes"] == 0
     assert r2.timings["prepare_s"] == 0.0
     np.testing.assert_array_equal(np.asarray(r1.eigenvalues), np.asarray(r2.eigenvalues))
-
-
-def test_tuned_session_reuses_probes(small_csr, tmp_path, monkeypatch):
-    """With the measured autotuner on, the second call must not re-probe."""
-    monkeypatch.setenv("REPRO_SPMV_TUNE", "1")
-    monkeypatch.setenv("REPRO_SPMV_TUNE_BUDGET", "2")
-    monkeypatch.setenv("REPRO_SPMV_TUNE_CACHE", str(tmp_path / "tune.json"))
-    r1 = eigsh(small_csr, K, policy="FFF", format="ell", num_iters=ITERS)
-    assert r1.partition["spmv"]["tiles_from"] in ("tuned", "table")
-    probes = get_tuner().measure_count
-    r2 = eigsh(small_csr, K, policy="FFF", format="ell", num_iters=ITERS)
-    assert r2.session_reuse
-    assert get_tuner().measure_count == probes
-    assert r2.partition["spmv"]["tuner_probes"] == 0
 
 
 def test_mutation_invalidates_session(small_csr):
