@@ -25,8 +25,10 @@ times one jitted SpMV per layout and accumulation dtype (median of
 ``sell-gather`` times the ``"sell"`` layout's gather of a random float32
 ``x`` alone, ``jnp.take(x, col)`` and the ``sell_gather`` kernel (compiled
 on a TPU, interpreted elsewhere), in ns a slot, and checks that their bits
-are equal; then the whole jitted ``"sell"`` SpMV with each gather, whose
-bits must be equal too.  ``--matrix`` takes a benchmark configuration's name
+are equal, beside the seconds the kernel takes to trace and lower
+(``kernel_lower_s``, paid in every process's set-up); then the whole
+jitted ``"sell"`` SpMV with each gather, whose bits must be equal too.
+``--matrix`` takes a benchmark configuration's name
 (``bench/configs/<name>.json``, graph made by ``bench.gen`` with seed 1) in
 place of WK.
 
@@ -118,6 +120,10 @@ def probe_sell_gather(csr, reps: int) -> None:
     out.update(take_ms=sec * 1e3, take_ns_slot=sec / col.shape[0] * 1e9)
     if how != "xla":
         kernel = jax.jit(lambda v, c: sg.sell_gather(v, c, interpret=how == "pallas_interpret"))
+        jax.clear_caches()  # a cold trace, as in a process's first request
+        t0 = time.perf_counter()
+        kernel.lower(x, col)
+        out.update(kernel_lower_s=time.perf_counter() - t0)
         sec = _median_seconds(kernel, x, col, reps=reps)
         want = np.asarray(take(x, col)).view(np.uint32)
         got = np.asarray(kernel(x, col)).view(np.uint32)

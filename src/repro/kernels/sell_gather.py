@@ -22,12 +22,13 @@ loads:
     ``INT32_MIN`` and an integer ``max`` over sublanes: a selection, with
     no multiply and no ``dot``.
 
-The unrolled slot loop is what makes it fast (1.47 ns a slot on a v5e
-against ``jnp.take``'s 7.55, PERF.md §6): its chunk is 1,024 slots
-compiled, and one group of 128 under the interpreter.  The chunk body is
-written once and picks its SMEM buffer at run time: one body for each
-buffer ran at 1.16 ns, but tracing and lowering it took 5.4 s of every
-process's set-up on the chip's host.
+The unrolled slot loop is what makes it fast: a loop iteration takes a
+pair of chunks, the first read from SMEM buffer 0 and the second from
+buffer 1, so every SMEM read in it has a static address and a slot costs
+one scalar load and one row address (PERF.md §5).  A chunk is 512 slots
+compiled, so an iteration unrolls 1,024 slot loads (twice that took twice
+as long to trace and lower, in every process's set-up); under the
+interpreter a chunk is one group of 128.
 ``executor`` decides from ``x``'s length and dtype whether the kernel may
 run; callers fall back to ``jnp.take`` otherwise.
 """
@@ -49,14 +50,16 @@ __all__ = ["executor", "footprint_bytes", "sell_gather", "vmem_capacity_bytes"]
 V5E_VMEM_BYTES = 128 << 20
 # VMEM left to Mosaic's own scratch, above the kernel's buffers.
 VMEM_MARGIN_BYTES = 16 << 20
-# Chunks of slots per grid step (at least two: two SMEM buffers).
-BLOCK_CHUNKS = 16
 _FILL = -(2**31)  # INT32_MIN: any 32-bit pattern wins the max against it
 
 
-def _chunk_groups(interpret: bool) -> int:
-    """128-slot groups per chunk: the unrolled scalar loop's length."""
-    return 1 if interpret else 8
+def _geometry(interpret: bool) -> tuple[int, int]:
+    """(groups, block_rows): 128-slot groups per chunk and 128-slot rows
+    per grid step.  A loop iteration runs a pair of chunks, one from each
+    SMEM buffer, so it unrolls ``2 * groups * 128`` slot loads compiled
+    (1,024) and a step holds an even number of chunks (16 pairs of 512
+    slots compiled; under the interpreter 8 pairs of one group)."""
+    return (1, 16) if interpret else (4, 128)
 
 
 def _x_rows(n: int) -> int:
@@ -67,7 +70,7 @@ def footprint_bytes(n: int, interpret: bool = False) -> int:
     """VMEM the kernel holds for an ``n``-long ``x``: ``x`` once, the
     double-buffered ``col`` and output blocks, the shifted rows of a block
     and the ``(128, 128)`` row scratch."""
-    block = BLOCK_CHUNKS * _chunk_groups(interpret) * LANES * 4  # (rows, 128) int32
+    block = _geometry(interpret)[1] * LANES * 4  # (rows, 128) int32
     return _x_rows(n) * LANES * 4 + 5 * block + LANES * LANES * 4
 
 
@@ -111,21 +114,26 @@ def _each_slot(body, unroll: bool) -> None:
         )
 
 
-def _kernel(col_ref, x_ref, out_ref, rows_ref, rsh_ref, smem, sem, *, groups, chunks, unroll):
+def _kernel(col_ref, x_ref, out_ref, rows_ref, rsh_ref, smem, sem, *, groups, unroll):
     # rsh: each slot's x row, clamped: a partial last block holds stale
     # indices past the end, whose loads must stay inside x.
     rsh_ref[...] = jnp.minimum(jnp.maximum(col_ref[...] >> 7, 0), x_ref.shape[0] - 1)
+    chunks = rsh_ref.shape[0] // groups
+    assert chunks % 2 == 0, chunks
 
     def copy(k, b):
         # int32 offsets and buffer indices: under x64 Python ints lower as i64
         rows = pl.ds(jnp.int32(k) * groups, groups)
-        b = jnp.asarray(b, jnp.int32)
+        b = jnp.int32(b)
         return pltpu.make_async_copy(rsh_ref.at[rows], smem.at[b], sem.at[b])
 
     copy(0, 0).start()
     copy(1, 1).start()
 
     def chunk(k, b):
+        """Chunk ``k`` from SMEM buffer ``b``, a Python int: every SMEM read
+        of the unrolled loop has a static address."""
+        copy(k, b).wait()
         for gg in range(groups):
 
             def load_row(slot, gg=gg):
@@ -139,18 +147,16 @@ def _kernel(col_ref, x_ref, out_ref, rows_ref, rsh_ref, smem, sem, *, groups, ch
             pick = jnp.where(sub == lane, picked, jnp.int32(_FILL))
             out_ref[pl.ds(g, 1), :] = jnp.max(pick, axis=0, keepdims=True)
 
-    def step(k, carry):
-        b = jax.lax.rem(k, jnp.int32(2))
-        copy(k, b).wait()
-        chunk(k, b)
-
         @pl.when(k + 2 < chunks)
         def _():
             copy(k + 2, b).start()
 
+    def pair(p, carry):
+        chunk(2 * p, 0)
+        chunk(2 * p + 1, 1)
         return carry
 
-    jax.lax.fori_loop(jnp.int32(0), jnp.int32(chunks), step, jnp.int32(0))
+    jax.lax.fori_loop(jnp.int32(0), jnp.int32(chunks // 2), pair, jnp.int32(0))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -165,13 +171,12 @@ def sell_gather(x: jax.Array, col: jax.Array, *, interpret: bool = False) -> jax
         raise ValueError(f"sell_gather: x must be 32-bit, got {x.dtype}")
     n = x.shape[0]
     rows = _x_rows(n)
-    groups = _chunk_groups(interpret)
-    block_rows = BLOCK_CHUNKS * groups
+    groups, block_rows = _geometry(interpret)
     xi = jax.lax.bitcast_convert_type(x, jnp.int32)
     if rows * LANES != n:
         xi = jnp.pad(xi, (0, rows * LANES - n))
     out = pl.pallas_call(
-        functools.partial(_kernel, groups=groups, chunks=BLOCK_CHUNKS, unroll=not interpret),
+        functools.partial(_kernel, groups=groups, unroll=not interpret),
         grid=(pl.cdiv(slots // LANES, block_rows),),
         in_specs=[
             pl.BlockSpec((block_rows, LANES), row_block),

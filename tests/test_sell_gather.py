@@ -28,8 +28,11 @@ from repro.kernels import sell_gather as sg  # noqa: E402
 from repro.sparse import CSR  # noqa: E402
 from repro.sparse.formats import to_device_sell  # noqa: E402
 
-# Slots of one interpreted grid step: a count past it leaves a partial block.
-BLOCK_SLOTS = sg.BLOCK_CHUNKS * 128
+# Slots of one interpreted chunk and grid step: the interpreter runs the
+# compiled pair structure (a loop iteration takes two chunks, one from each
+# SMEM buffer) with one 128-slot group a chunk.
+CHUNK_SLOTS = sg._geometry(True)[0] * 128
+BLOCK_SLOTS = sg._geometry(True)[1] * 128
 
 
 def _bits(a) -> np.ndarray:
@@ -47,6 +50,10 @@ def _case(name: str, rng):
         "partial_block": (500, BLOCK_SLOTS + 128 * 3),
         "below_one_block": (500, 128),
         "non_finite": (300, 128 * 8),
+        "odd_chunks_in_one_block": (700, CHUNK_SLOTS * 7),
+        "block_less_one_chunk": (900, BLOCK_SLOTS - CHUNK_SLOTS),
+        "partial_last_pair_and_block": (1500, 2 * BLOCK_SLOTS + CHUNK_SLOTS * 9),
+        "whole_blocks": (600, 2 * BLOCK_SLOTS),
     }[name]
     x = rng.standard_normal(n).astype(np.float32)
     col = rng.integers(0, n, slots)
@@ -72,6 +79,10 @@ CASES = (
     "partial_block",
     "below_one_block",
     "non_finite",
+    "odd_chunks_in_one_block",
+    "block_less_one_chunk",
+    "partial_last_pair_and_block",
+    "whole_blocks",
 )
 
 
@@ -104,6 +115,35 @@ def test_kernel_body_holds_no_dot(interpret):
     used = _primitives(eqn.params["jaxpr"])
     assert "dot_general" not in used
     assert {"get", "swap", "select_n", "reduce_max"} <= used  # loads, stores, the pick
+
+
+def test_compiled_slot_loop_reads_smem_at_static_addresses():
+    """The compiled loop over pairs of chunks: each chunk reads its own SMEM
+    buffer, so every SMEM read of the unrolled slot loads has literal
+    indices (no address computed a slot), and one iteration unrolls 1,024
+    row loads of ``x``, the size the kernel lowers at."""
+    x = jnp.zeros((5000,), jnp.float32)
+    col = jnp.zeros((128 * 256,), jnp.int32)
+    traced = jax.make_jaxpr(lambda a, b: sg.sell_gather(a, b, interpret=False))(x, col)
+    (eqn,) = pallas_eqns(traced)
+    kernel = eqn.params["jaxpr"]
+    x_ref = kernel.invars[1]
+    (smem,) = [v for v in kernel.invars if str(getattr(v.aval, "memory_space", "")) == "smem"]
+    (loop,) = [e for e in kernel.eqns if e.primitive.name == "while"]
+    body = loop.params["body_jaxpr"].jaxpr
+    first = loop.params["cond_nconsts"]
+    outer = loop.invars[first : first + loop.params["body_nconsts"]]
+
+    def inner(ref):  # the loop body's name for a kernel ref
+        (i,) = [i for i, v in enumerate(outer) if v is ref]
+        return body.invars[i]
+
+    gets = [e for e in body.eqns if e.primitive.name == "get"]
+    smem_reads = [e for e in gets if e.invars[0] is inner(smem)]
+    row_loads = [e for e in gets if e.invars[0] is inner(x_ref)]
+    assert len(smem_reads) == len(row_loads) == 1024
+    for e in smem_reads:
+        assert all(isinstance(i, jex_core.Literal) for i in e.invars[1:]), e
 
 
 def _primitives(jaxpr) -> set:
